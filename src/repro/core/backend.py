@@ -163,6 +163,11 @@ class MttkrpBackend(abc.ABC):
         "f32" — the configured-precision paths stay bitwise otherwise)."""
         return compute_cast(x, self.precision)
 
+    def route(self, b, R: int) -> str:
+        """Name of the implementation that runs bucket ``b``'s slab-streaming
+        stages at rank ``R`` (the per-bucket route the drivers report)."""
+        return self.name
+
     # -- uniform sharding ---------------------------------------------------
     @staticmethod
     def shard_subjects(x: Optional[jax.Array]) -> Optional[jax.Array]:
@@ -418,6 +423,11 @@ class SparseBackend(MttkrpBackend):
         super().__init__(precision)
         self._inner = inner if inner is not None else JnpBackend(precision)
 
+    def route(self, b, R: int) -> str:
+        if isinstance(b, SparseBucket):
+            return self.name
+        return self._inner.route(b, R)
+
     # -- array-level CC contract: delegate wholesale ------------------------
     def ykv(self, Yc, Vg):
         return self._inner.ykv(Yc, Vg)
@@ -502,6 +512,11 @@ class FusedBackend(MttkrpBackend):
         super().__init__(precision)
         self._jnp = JnpBackend(precision)
         self._sparse = SparseBackend(inner=self._jnp, precision=precision)
+
+    def route(self, b, R: int) -> str:
+        if isinstance(b, SparseBucket):
+            return self._sparse.route(b, R)
+        return self.name
 
     @staticmethod
     def _interp() -> bool:
@@ -611,6 +626,13 @@ class AutoBackend(MttkrpBackend):
                 and jax.default_backend() == "tpu"
                 and b.vals.dtype != jnp.float64
                 and R % 8 == 0 and b.c_pad % 128 == 0)
+
+    def route(self, b, R: int) -> str:
+        if isinstance(b, SparseBucket):
+            return self._sparse.route(b, R)
+        if self._fused_ok(b, R):
+            return self._fused.route(b, R)
+        return self._jnp.route(b, R)
 
     # -- bucket-level: SCOO -> native sparse; friendly CC on TPU -> fused ---
     def xkv_bucket(self, b, V, Vg=None):

@@ -49,13 +49,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    # newer jax: top-level; the experimental home was removed
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 
 from repro.core import parafac2 as p2
 from repro.dist import sharding as dsh
@@ -73,7 +68,7 @@ ENGINES = ("host", "scan", "mesh")
 def _default_mesh() -> Mesh:
     """All local devices as a 1-D data mesh (when no mesh is installed)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return dsh.make_mesh((n,), ("data",))
 
 
 def _n_shards(mesh: Mesh, axes: Tuple[str, ...]) -> int:
@@ -175,7 +170,7 @@ def mesh_wrap(fn: Callable, data, state, mesh: Optional[Mesh] = None,
     out_flat = state_flat + [P()] * (len(flat) - n_state)
     out_specs = jax.tree_util.tree_unflatten(treedef, out_flat)
     return shard_map(mapped_body, mesh=mesh, in_specs=(d_specs, s_specs),
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -239,13 +234,11 @@ def _compile(fn, data, opts, *, donate: Optional[bool]) -> Callable:
     """jit (and, for the mesh engine, shard_map) a (data, state) -> ... body;
     returns a state-only callable with `data` bound.
 
-    The scan engine CLOSES OVER the data, exactly like the host loop's
-    ``jax.jit(lambda s: als_step(data, s, opts))`` — constants vs runtime
-    parameters change XLA's fusion decisions, and closing over keeps the
-    scanned step bitwise identical to the host step. The mesh engine must
-    pass the data as an argument instead (shard_map splits it via in_specs;
-    a closed-over constant would be replicated per shard, double-counting
-    every psum)."""
+    Every engine passes the data as a runtime ARGUMENT, like the host loop's
+    ``jax.jit(lambda d, s: als_step(d, s, opts))``: a closed-over dataset
+    would be embedded in the compiled program as constants (gigabytes at
+    cohort scale, held on the device twice), and under shard_map it would
+    be replicated per shard, double-counting every psum."""
     if opts.engine == "mesh":
         mapped = None
 
@@ -258,9 +251,8 @@ def _compile(fn, data, opts, *, donate: Optional[bool]) -> Callable:
 
         return lambda s: call(data, s)
 
-    jitted = jax.jit(lambda s: fn(data, s),
-                     donate_argnums=_donate(donate, argnum=0))
-    return lambda s: jitted(s)
+    jitted = jax.jit(fn, donate_argnums=_donate(donate, argnum=1))
+    return lambda s: jitted(data, s)
 
 
 def make_subject_update(opts: "p2.Parafac2Options", *, smooth_lam: float = 0.0,

@@ -29,6 +29,7 @@ format="auto")`` dataset mixes CC and SCOO buckets. See docs/ARCHITECTURE.md
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Mapping, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -261,6 +262,22 @@ def _procrustes_project(
     return proj, XkV, Q
 
 
+def _f32_matmuls(fn):
+    """Trace ``fn`` with f32 matmul precision. A TPU runs an f32 matmul as a
+    single bf16 pass by default; at that precision the Procrustes Gram
+    spectra and the fit identity (which assumes Q_k^T Q_k = I) break down,
+    and on a v5e the fit read 1.0 from the first iteration. Sub-f32
+    ``opts.precision`` casts its operands, which this leaves as they are."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
+@_f32_matmuls
 def als_step(
     data: Bucketed,
     state: Parafac2State,
@@ -405,11 +422,11 @@ def fit(
                                   seed=seed, verbose=verbose, state=state)
     if state is None:
         state = init_state(data, opts, seed)
-    step = jax.jit(lambda s: als_step(data, s, opts))
+    step = jax.jit(lambda d, s: als_step(d, s, opts))
     history: List[float] = []
     prev = -np.inf
     for it in range(max_iters):
-        state = step(state)
+        state = step(data, state)
         f = float(state.fit)
         history.append(f)
         if verbose:
@@ -420,6 +437,7 @@ def fit(
     return state, history
 
 
+@_f32_matmuls
 def update_subjects(
     batch: Bucketed,
     H: jax.Array,

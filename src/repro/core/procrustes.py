@@ -34,13 +34,18 @@ def polar_gram_eigh(B: jax.Array, *, eps: float = 1e-12) -> jax.Array:
 
     B = Q P with P = (B^T B)^{1/2};  Q = B P^{-1} = B E diag(1/sqrt(lam)) E^T.
     Rank-deficient directions get a zero inverse root (pseudo-polar), which is
-    the correct limit for padded/empty subjects.
+    the correct limit for padded/empty subjects. A direction counts as
+    rank-deficient below ``max(eps, R * roundoff of B's dtype) * lam_max``:
+    a Gram formed in floating point resolves its eigenvalues no finer, and
+    the inverse root of a noise eigenvalue (a subject with fewer rows than R
+    has R - I of them) would put noise of order sqrt(roundoff) into Q.
     """
+    R = B.shape[-1]
     G = jnp.einsum("kir,kil->krl", B, B)                     # [Kb, R, R]
     lam, E = jnp.linalg.eigh(G)                               # ascending eigs
     scale = jnp.maximum(lam, 0.0)
     max_lam = jnp.max(scale, axis=-1, keepdims=True)
-    tol = max_lam * eps
+    tol = max_lam * max(eps, R * float(jnp.finfo(B.dtype).eps))
     inv_root = jnp.where(scale > tol, 1.0 / jnp.sqrt(jnp.maximum(scale, tol)), 0.0)
     P_inv = jnp.einsum("krl,kl,kml->krm", E, inv_root, E)     # E diag E^T
     return jnp.einsum("kir,krm->kim", B, P_inv)

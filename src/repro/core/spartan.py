@@ -91,33 +91,29 @@ def mode2_scatter(A: jax.Array, cols: jax.Array, J: int) -> jax.Array:
     """Scatter-add compact results into M2 [J, R]. Padded entries are zero so
     scattering them to column id 0 (or any segment) is harmless.
 
-    When ``cols`` is a trace-time CONSTANT — the host/scan/while engines jit
-    ``als_step`` with the bucket closed over, so the kept-column metadata is a
-    concrete array during tracing — the column order is presorted once with
-    numpy at trace time and the XLA scatter-add (scalar-serialized on CPU,
-    ~2.5x the cost of this path at benchmark scale) is replaced by a
-    permutation gather + cumsum-diff segment sum over the sorted rows.
-    Under shard_map (mesh engine) or AOT lowering with the data as a runtime
-    argument ``cols`` is a tracer and the plain scatter-add runs instead —
-    a [Kb*C]-flat global sort cannot be sharded over subjects.
+    No XLA scatter-add (scalar-serialized on CPU, ~2.5x the cost of this
+    path at benchmark scale; compiled for v5e it also needs more temporary
+    HBM than this path): the flat column ids are stably sorted, and M2 is a
+    permutation gather + cumsum-diff segment sum over the sorted rows. The
+    sort depends only on the data, but the compiled engine loops still run
+    it every iteration. Under shard_map each shard sorts its own subjects
+    and the partial M2 is psum'd like any other.
     """
     Kb, C, R = A.shape
     flat_cols = cols.reshape(-1)                               # [Kb*C]
     flat_A = A.reshape(-1, R)
-    if not isinstance(flat_cols, jax.core.Tracer):
-        cnp = np.asarray(flat_cols)
-        perm = np.argsort(cnp, kind="stable")
-        ends = np.searchsorted(cnp[perm], np.arange(1, J + 1))
-        # accumulate in f64 when x64 is on (canonicalized back to f32
-        # otherwise) — the running cumsum spans every kept column, so give
-        # the partial sums the wider accumulator when one is available
-        acc = jnp.result_type(A.dtype, jnp.float64)
-        g = flat_A[jnp.asarray(perm)].astype(acc)
-        cs = jnp.concatenate([jnp.zeros((1, R), acc), jnp.cumsum(g, 0)], 0)
-        seg = cs[jnp.asarray(ends)]                            # [J, R]
-        return jnp.diff(seg, axis=0,
-                        prepend=jnp.zeros((1, R), acc)).astype(A.dtype)
-    return jnp.zeros((J, R), A.dtype).at[flat_cols].add(flat_A)
+    perm = jnp.argsort(flat_cols, stable=True)
+    ends = jnp.searchsorted(flat_cols[perm],
+                            jnp.arange(1, J + 1, dtype=flat_cols.dtype))
+    # accumulate in f64 when x64 is on (canonicalized back to f32 otherwise)
+    # — the running cumsum spans every kept column, so give the partial sums
+    # the wider accumulator when one is available
+    acc = jnp.result_type(A.dtype, jnp.float64)
+    g = flat_A[perm].astype(acc)
+    cs = jnp.concatenate([jnp.zeros((1, R), acc), jnp.cumsum(g, 0)], 0)
+    seg = cs[ends]                                             # [J, R]
+    return jnp.diff(seg, axis=0,
+                    prepend=jnp.zeros((1, R), acc)).astype(A.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -45,7 +45,7 @@ import threading
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "LM_RULES",
@@ -55,6 +55,7 @@ __all__ = [
     "current_rules",
     "enforce_divisible",
     "logical_spec",
+    "make_mesh",
     "param_shardings",
     "param_spec",
     "barrier",
@@ -93,6 +94,16 @@ LM_RULES: Rules = {
 # Sequence-parallel variant: the residual stream's seq axis also shards over
 # "model" between blocks (attention/mlp still gather seq internally).
 SP_RULES: Rules = {**LM_RULES, "seq_res": "model"}
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``. The rule tables resolve
+    through GSPMD ``with_sharding_constraint``, which refuses the
+    ``Explicit`` axes ``jax.make_mesh`` builds by default."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 # ---------------------------------------------------------------------------

@@ -18,8 +18,8 @@ boundary:
    the newest ``checkpoint/ckpt.py`` checkpoint (written every
    ``ckpt_every`` chunks; globally-unsharded arrays, so a write-on-N
    restores on M devices) is loaded, the fit history rewound to its step,
-   and the chunks replayed. Replay is bitwise: the scan chunk closes over
-   the data, so the carried ``Parafac2State`` is the only state.
+   and the chunks replayed. Replay is bitwise: the data is a fixed argument
+   of the scan chunk, so the carried ``Parafac2State`` is the only state.
 3. **rollback** — a numerical-health sentinel checks every chunk's fit
    values on the host sync: non-finite fits, or a fit regression below the
    best seen (ALS fit is monotone), roll the state back to the last good

@@ -123,7 +123,7 @@ def _procrustes_b_kernel(vals_hbm, vg_ref, wb_ref, h_ref, xkv_ref, b_ref,
     xkv_ref[0] = xkv
     # B_k = (X_k V * w_k) H^T in the same dispatch — XkV never leaves VMEM
     # before its second use.
-    w = wb_ref[0].astype(acc)
+    w = wb_ref[0, 0].astype(acc)
     b_ref[0] = jnp.dot(xkv * w[None, :], h_ref[...].astype(acc).T,
                        preferred_element_type=acc)
 
@@ -154,9 +154,9 @@ def fused_procrustes_b(
         functools.partial(_procrustes_b_kernel, nc=nc, bc=bc, acc=acc),
         grid=(K,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # vals: manual DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # vals: manual DMA
             pl.BlockSpec((1, nc * bc, R), lambda k: (k, 0, 0)),
-            pl.BlockSpec((1, R), lambda k: (k, 0)),
+            pl.BlockSpec((1, 1, R), lambda k: (k, 0, 0)),
             pl.BlockSpec((R, R), lambda k: (0, 0)),
         ],
         out_specs=[
@@ -172,7 +172,7 @@ def fused_procrustes_b(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(vals, Vg, Wb, H)
+    )(vals, Vg, Wb[:, None, :], H)
     return out[0], out[1]
 
 
@@ -189,7 +189,7 @@ def _mode1_xkv_kernel(q_ref, xkv_ref, wb_ref, out_ref, *, acc):
 
     ykv = jnp.dot(q_ref[0].astype(acc).T, xkv_ref[0].astype(acc),
                   preferred_element_type=acc)             # [R, R]
-    out_ref[...] += ykv * wb_ref[0].astype(acc)[None, :]
+    out_ref[...] += ykv * wb_ref[0, 0].astype(acc)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -214,12 +214,12 @@ def fused_mode1_xkv(
         in_specs=[
             pl.BlockSpec((1, I, R), lambda k: (k, 0, 0)),
             pl.BlockSpec((1, I, R), lambda k: (k, 0, 0)),
-            pl.BlockSpec((1, R), lambda k: (k, 0)),
+            pl.BlockSpec((1, 1, R), lambda k: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((R, R), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, R), acc),
         interpret=interpret,
-    )(Q, XkV, Wb)
+    )(Q, XkV, Wb[:, None, :])
 
 
 # ---------------------------------------------------------------------------
@@ -237,7 +237,7 @@ def _mode2_kernel(vals_hbm, q_ref, h_ref, wb_ref, cm_ref, out_ref,
     dma(0, 0).start()
     q = q_ref[0].astype(acc)                              # [I, R]
     h = h_ref[...].astype(acc)                            # [R, R]
-    w = wb_ref[0].astype(acc)                             # [R]
+    w = wb_ref[0, 0].astype(acc)                          # [R]
 
     def step(c, _):
         slot = jax.lax.rem(c, 2)
@@ -250,7 +250,7 @@ def _mode2_kernel(vals_hbm, q_ref, h_ref, wb_ref, cm_ref, out_ref,
         # Yc tile transposed: (vals_chunk^T Q) = (Q^T vals_chunk)^T  [bc, R]
         ycT = jnp.dot(vbuf[slot].T, q, preferred_element_type=acc)
         a = jnp.dot(ycT, h, preferred_element_type=acc)   # (Y_k^T H) tile
-        cm = cm_ref[0, pl.ds(c * bc, bc)].astype(acc)
+        cm = cm_ref[0, 0, pl.ds(c * bc, bc)].astype(acc)
         out_ref[0, pl.ds(c * bc, bc), :] = a * w[None, :] * cm[:, None]
         return 0
 
@@ -285,11 +285,11 @@ def fused_mode2_compact(
         functools.partial(_mode2_kernel, nc=nc, bc=bc, acc=acc),
         grid=(K,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # vals: manual DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # vals: manual DMA
             pl.BlockSpec((1, I, R), lambda k: (k, 0, 0)),
             pl.BlockSpec((R, R), lambda k: (0, 0)),
-            pl.BlockSpec((1, R), lambda k: (k, 0)),
-            pl.BlockSpec((1, C_pad), lambda k: (k, 0)),
+            pl.BlockSpec((1, 1, R), lambda k: (k, 0, 0)),
+            pl.BlockSpec((1, 1, C_pad), lambda k: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, C_pad, R), lambda k: (k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((K, C_pad, R), acc),
@@ -298,7 +298,7 @@ def fused_mode2_compact(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(vals, Q, H, Wb, col_mask)
+    )(vals, Q, H, Wb[:, None, :], col_mask[:, None, :])
     return out[:, :C, :]
 
 
@@ -358,7 +358,7 @@ def fused_ykv(
         functools.partial(_ykv_kernel, nc=nc, bc=bc, acc=acc),
         grid=(K,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # vals: manual DMA
+            pl.BlockSpec(memory_space=pl.ANY),            # vals: manual DMA
             pl.BlockSpec((1, I, R), lambda k: (k, 0, 0)),
             pl.BlockSpec((1, nc * bc, R), lambda k: (k, 0, 0)),
         ],

@@ -42,7 +42,7 @@ def _kernel(yc_ref, vg_ref, wb_ref, out_ref, *, acc):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     yv = jnp.dot(yc_ref[0], vg_ref[0], preferred_element_type=acc)  # [R, R]
-    out_ref[...] += yv * wb_ref[0].astype(acc)[None, :]
+    out_ref[...] += yv * wb_ref[0, 0].astype(acc)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -75,12 +75,12 @@ def mode1_pallas(
         in_specs=[
             pl.BlockSpec((1, R, bc), lambda k, c: (k, 0, c)),
             pl.BlockSpec((1, bc, R), lambda k, c: (k, c, 0)),
-            pl.BlockSpec((1, R), lambda k, c: (k, 0)),
+            pl.BlockSpec((1, 1, R), lambda k, c: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((R, R), lambda k, c: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, R), acc),
         interpret=interpret,
-    )(Yc, Vg, Wb)
+    )(Yc, Vg, Wb[:, None, :])
 
 
 def _reuse_kernel(ykv_ref, wb_ref, out_ref, *, acc):
@@ -91,7 +91,7 @@ def _reuse_kernel(ykv_ref, wb_ref, out_ref, *, acc):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     ykv = ykv_ref[0].astype(acc)
-    out_ref[...] += ykv * wb_ref[0].astype(acc)[None, :]
+    out_ref[...] += ykv * wb_ref[0, 0].astype(acc)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -114,9 +114,9 @@ def mode1_reuse_pallas(
         grid=(K,),
         in_specs=[
             pl.BlockSpec((1, R, R), lambda k: (k, 0, 0)),
-            pl.BlockSpec((1, R), lambda k: (k, 0)),
+            pl.BlockSpec((1, 1, R), lambda k: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((R, R), lambda k: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((R, R), acc),
         interpret=interpret,
-    )(YkV, Wb)
+    )(YkV, Wb[:, None, :])

@@ -26,9 +26,10 @@ __all__ = ["mode2_compact_pallas"]
 
 
 def _kernel(yc_ref, h_ref, wb_ref, cm_ref, out_ref, *, acc):
-    # yc [1, R, bc]; h [R, R]; wb [1, R]; cm [1, bc]; out [1, bc, R]
+    # yc [1, R, bc]; h [R, R]; wb [1, 1, R]; cm [1, 1, bc]; out [1, bc, R]
     ytH = jnp.dot(yc_ref[0].T, h_ref[...], preferred_element_type=acc)
-    out_ref[0] = ytH * wb_ref[0].astype(acc)[None, :] * cm_ref[0].astype(acc)[:, None]
+    out_ref[0] = (ytH * wb_ref[0, 0].astype(acc)[None, :]
+                  * cm_ref[0, 0].astype(acc)[:, None])
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -64,11 +65,11 @@ def mode2_compact_pallas(
         in_specs=[
             pl.BlockSpec((1, R, bc), lambda k, c: (k, 0, c)),
             pl.BlockSpec((R, R), lambda k, c: (0, 0)),
-            pl.BlockSpec((1, R), lambda k, c: (k, 0)),
-            pl.BlockSpec((1, bc), lambda k, c: (k, c)),
+            pl.BlockSpec((1, 1, R), lambda k, c: (k, 0, 0)),
+            pl.BlockSpec((1, 1, bc), lambda k, c: (k, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, bc, R), lambda k, c: (k, c, 0)),
         out_shape=jax.ShapeDtypeStruct((K, C_pad, R), acc),
         interpret=interpret,
-    )(Yc, H, Wb, col_mask)
+    )(Yc, H, Wb[:, None, :], col_mask[:, None, :])
     return out[:, :C, :]

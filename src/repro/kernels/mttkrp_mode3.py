@@ -42,7 +42,7 @@ def _kernel(yc_ref, vg_ref, h_ref, out_ref, acc_ref, *, nc: int, acc):
 
     @pl.when(c == nc - 1)
     def _fin():
-        out_ref[0] = jnp.sum(h_ref[...].astype(acc) * acc_ref[...], axis=0)  # coldot
+        out_ref[0, 0] = jnp.sum(h_ref[...].astype(acc) * acc_ref[...], axis=0)  # coldot
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -76,17 +76,17 @@ def mode3_pallas(
             pl.BlockSpec((1, bc, R), lambda k, c: (k, c, 0)),
             pl.BlockSpec((R, R), lambda k, c: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, R), lambda k, c: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, R), acc),
+        out_specs=pl.BlockSpec((1, 1, R), lambda k, c: (k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, 1, R), acc),
         scratch_shapes=[pltpu.VMEM((R, R), acc)],
         interpret=interpret,
     )(Yc, Vg, H)
-    return _mask_rows(out, subject_mask)
+    return _mask_rows(out[:, 0], subject_mask)
 
 
 def _reuse_kernel(ykv_ref, h_ref, out_ref, *, acc):
     ykv = ykv_ref[0].astype(acc)
-    out_ref[0] = jnp.sum(h_ref[...].astype(acc) * ykv, axis=0)
+    out_ref[0, 0] = jnp.sum(h_ref[...].astype(acc) * ykv, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -110,8 +110,8 @@ def mode3_reuse_pallas(
             pl.BlockSpec((1, R, R), lambda k: (k, 0, 0)),
             pl.BlockSpec((R, R), lambda k: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, R), lambda k: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, R), acc),
+        out_specs=pl.BlockSpec((1, 1, R), lambda k: (k, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, 1, R), acc),
         interpret=interpret,
     )(YkV, H)
-    return _mask_rows(out, subject_mask)
+    return _mask_rows(out[:, 0], subject_mask)

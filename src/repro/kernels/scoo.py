@@ -228,9 +228,9 @@ def _xkv_kernel(nnz_ref, vals_ref, rows_ref, lcols_ref, vg_ref, out_ref,
 
     @pl.when(b * block_n < nnz_ref[k])
     def _accum():
-        vals = vals_ref[0].astype(acc)                    # [BN]
-        lc = lcols_ref[0]                                 # [BN] i32
-        r = rows_ref[0]                                   # [BN] i32
+        vals = vals_ref[0, 0].astype(acc)                 # [BN]
+        lc = lcols_ref[0, 0]                              # [BN] i32
+        r = rows_ref[0, 0]                                # [BN] i32
         C = vg_ref.shape[1]
         I = out_ref.shape[1]
         BN = vals.shape[0]
@@ -266,9 +266,9 @@ def xk_times_v_pallas(vals, rows, lcols, Vg, i_pad: int, *, nnz_counts=None,
         num_scalar_prefetch=1,
         grid=(Kb, nb),
         in_specs=[
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
             pl.BlockSpec((1,) + Vg.shape[1:], lambda k, b, nnz: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, i_pad, R), lambda k, b, nnz: (k, 0, 0)),
@@ -278,7 +278,7 @@ def xk_times_v_pallas(vals, rows, lcols, Vg, i_pad: int, *, nnz_counts=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Kb, i_pad, R), acc),
         interpret=interpret,
-    )(nnz, vals, rows, lcols, Vg)
+    )(nnz, vals[:, None], rows[:, None], lcols[:, None], Vg)
 
 
 def _project_kernel(nnz_ref, vals_ref, rows_ref, lcols_ref, q_ref, out_ref,
@@ -291,9 +291,9 @@ def _project_kernel(nnz_ref, vals_ref, rows_ref, lcols_ref, q_ref, out_ref,
 
     @pl.when(b * block_n < nnz_ref[k])
     def _accum():
-        vals = vals_ref[0].astype(acc)                    # [BN]
-        lc = lcols_ref[0]
-        r = rows_ref[0]
+        vals = vals_ref[0, 0].astype(acc)                 # [BN]
+        lc = lcols_ref[0, 0]
+        r = rows_ref[0, 0]
         I = q_ref.shape[1]
         C = out_ref.shape[2]
         BN = vals.shape[0]
@@ -331,9 +331,9 @@ def project_pallas(vals, rows, lcols, Q, c_pad: int, *, nnz_counts=None,
         num_scalar_prefetch=1,
         grid=(Kb, nb),
         in_specs=[
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
-            pl.BlockSpec((1, bn), lambda k, b, nnz: (k, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
+            pl.BlockSpec((1, 1, bn), lambda k, b, nnz: (k, 0, b)),
             pl.BlockSpec((1,) + Q.shape[1:], lambda k, b, nnz: (k, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, R, c_pad), lambda k, b, nnz: (k, 0, 0)),
@@ -343,4 +343,4 @@ def project_pallas(vals, rows, lcols, Q, c_pad: int, *, nnz_counts=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Kb, R, c_pad), acc),
         interpret=interpret,
-    )(nnz, vals, rows, lcols, Q)
+    )(nnz, vals[:, None], rows[:, None], lcols[:, None], Q)

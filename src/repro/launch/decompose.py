@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ENGINES, FORMATS, Parafac2Options, bucketize, fit
+from repro.core.backend import get_backend
 from repro.core.compress import available as available_preprocess
 from repro.core.constraints import (
     available as available_constraints, constraint_summary, parse_constraint_arg)
@@ -51,6 +52,7 @@ from repro.core.interpret import subject_top_phenotypes, top_phenotype_features
 from repro.data import choa_like, movielens_like
 from repro.dist.fault import FaultInjector
 from repro.dist.supervisor import SupervisorConfig, supervised_fit
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.summary import resolved_options, run_summary
 from repro.sparse import plan_buckets, random_irregular, route_formats
 
@@ -89,7 +91,47 @@ def load_dataset(name: str, scale: float, seed: int):
     raise ValueError(name)
 
 
+def build_buckets(data, *, format: str, engine: str, max_buckets: int):
+    """The driver's device layout for ``data``: the quantile bucket plan
+    (nnz-balanced over the subject shards under the mesh engine), the
+    per-bucket format routing, and ``bucketize``. Returns ``(Bucketed,
+    per-bucket stats, shard-balance report or None)``."""
+    # shard_map needs every bucket's subject count to divide the shard count
+    subject_align = len(jax.devices()) if engine == "mesh" else 1
+    rc, ccnt, nnzc = data.row_counts(), data.col_counts(), data.nnz_counts()
+    plan = plan_buckets(rc, ccnt, max_buckets=max_buckets, nnz_counts=nnzc,
+                        sort_by="nnz" if format == "scoo" else "area")
+    shard_balance = None
+    if engine == "mesh" and subject_align > 1:
+        # nnz-balance the subject shards: equal nonzeros per contiguous
+        # shard chunk, not equal subject counts — the quantile planner sorts
+        # members by size, which would put every heavy subject on the last
+        # shard (the straggler the watchdog would then flag forever)
+        naive = plan.shard_imbalance(nnzc, subject_align)
+        plan = plan.balance_for_shards(nnzc, subject_align)
+        shard_balance = {
+            "n_shards": subject_align,
+            "shard_nnz": plan.shard_nnz(nnzc, subject_align),
+            "imbalance_max_over_mean": plan.shard_imbalance(
+                nnzc, subject_align),
+            "imbalance_unbalanced": naive,
+        }
+        print(f"[shard-balance] {subject_align} shards: imbalance "
+              f"{naive:.3f} -> "
+              f"{shard_balance['imbalance_max_over_mean']:.3f} (max/mean nnz)")
+    fmts = route_formats(plan, nnzc, format=format)
+    bt = bucketize(data, dtype=jnp.float32, subject_align=subject_align,
+                   plan=plan, formats=fmts)
+    bucket_stats = plan.stats(rc, ccnt, nnzc, formats=fmts)
+    for rec, b in zip(bucket_stats, bt.buckets):
+        rec["device_bytes"] = int(sum(
+            leaf.size * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(b)))
+    return bt, bucket_stats, shard_balance
+
+
 def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="choa", choices=["choa", "movielens", "synthetic"])
     ap.add_argument("--scale", type=float, default=0.002)
@@ -191,48 +233,22 @@ def main(argv=None) -> dict:
     print(f"[data] K={data.n_subjects} J={data.n_cols} nnz={data.nnz} "
           f"({time.perf_counter()-t0:.1f}s)")
 
-    # shard_map needs every bucket's subject count to divide the shard count
-    subject_align = len(jax.devices()) if args.engine == "mesh" else 1
-    rc, ccnt, nnzc = data.row_counts(), data.col_counts(), data.nnz_counts()
-    plan = plan_buckets(rc, ccnt, max_buckets=args.buckets, nnz_counts=nnzc,
-                        sort_by="nnz" if args.format == "scoo" else "area")
-    shard_balance = None
-    if args.engine == "mesh" and subject_align > 1:
-        # nnz-balance the subject shards: equal nonzeros per contiguous
-        # shard chunk, not equal subject counts — the quantile planner sorts
-        # members by size, which would put every heavy subject on the last
-        # shard (the straggler the watchdog would then flag forever)
-        naive = plan.shard_imbalance(nnzc, subject_align)
-        plan = plan.balance_for_shards(nnzc, subject_align)
-        shard_balance = {
-            "n_shards": subject_align,
-            "shard_nnz": plan.shard_nnz(nnzc, subject_align),
-            "imbalance_max_over_mean": plan.shard_imbalance(
-                nnzc, subject_align),
-            "imbalance_unbalanced": naive,
-        }
-        print(f"[shard-balance] {subject_align} shards: imbalance "
-              f"{naive:.3f} -> "
-              f"{shard_balance['imbalance_max_over_mean']:.3f} (max/mean nnz)")
-    fmts = route_formats(plan, nnzc, format=args.format)
-    bt = bucketize(data, dtype=jnp.float32, subject_align=subject_align,
-                   plan=plan, formats=fmts)
-    bucket_stats = plan.stats(rc, ccnt, nnzc, formats=fmts)
-    for rec, b in zip(bucket_stats, bt.buckets):
-        rec["device_bytes"] = int(sum(
-            leaf.size * leaf.dtype.itemsize
-            for leaf in jax.tree_util.tree_leaves(b)))
+    bt, bucket_stats, shard_balance = build_buckets(
+        data, format=args.format, engine=args.engine, max_buckets=args.buckets)
     device_bytes = sum(rec["device_bytes"] for rec in bucket_stats)
-    print(f"[bucketize] {len(bt.buckets)} buckets ({args.format}): "
-          + ", ".join(f"{r['format']}@{r['density']*100:.1f}%"
-                      for r in bucket_stats)
-          + f"; device bytes {device_bytes/2**20:.1f} MiB")
 
     # raises ValueError listing the registered preprocessors on a bad spec
     opts = Parafac2Options(rank=args.rank, constraints=specs, backend=args.backend,
                            precision=args.precision,
                            engine=args.engine, check_every=args.check_every,
                            compress=args.compress)
+    be = get_backend(opts.backend, opts.precision)
+    for rec, b in zip(bucket_stats, bt.buckets):
+        rec["route"] = be.route(b, args.rank)
+    print(f"[bucketize] {len(bt.buckets)} buckets ({args.format}): "
+          + ", ".join(f"{r['format']}@{r['density']*100:.1f}%->{r['route']}"
+                      for r in bucket_stats)
+          + f"; device bytes {device_bytes/2**20:.1f} MiB")
     t0 = time.perf_counter()
     supervisor_report = None
     if supervise:

@@ -65,6 +65,7 @@ from repro.core.constraints import (
     available as available_constraints, constraint_summary,
     parse_constraint_arg)
 from repro.core.irregular import Bucketed
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.summary import resolved_options, run_summary
 from repro.sparse import (
     IrregularCOO, SubjectCOO, fixed_plan, plan_buckets, route_formats)
@@ -605,6 +606,7 @@ def synthetic_stream(data: IrregularCOO, *, warm_frac: float = 0.6,
 def main(argv=None) -> dict:
     from repro.launch.decompose import load_dataset
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="synthetic",
                     choices=["choa", "movielens", "synthetic"])

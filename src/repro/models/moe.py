@@ -149,8 +149,8 @@ def _moe_block_auto(p: dict, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
 # ---------------------------------------------------------------------------
 
 def _moe_block_manual(p: dict, x: jax.Array, cfg, mesh) -> Tuple[jax.Array, jax.Array]:
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     E, k = cfg.n_experts, cfg.experts_per_token
     d = x.shape[-1]
@@ -233,7 +233,7 @@ def _moe_block_manual(p: dict, x: jax.Array, cfg, mesh) -> Tuple[jax.Array, jax.
         in_specs=(batch_spec, P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, p["router"]["w"], p["experts"]["w_gate"],
                   p["experts"]["w_up"], p["experts"]["w_down"])

@@ -10,8 +10,8 @@ basic round-trip tests in test_runtime.py never pinned down:
     onto an M-device submesh (the "write on 512, resume on 64" path, scaled
     to forced host devices in a subprocess — slow-marked);
   * restore-then-continue under the scan engine is BITWISE identical to the
-    uninterrupted run (scan closes over the data, so the only state is the
-    carried ``Parafac2State`` — if the checkpoint preserves it exactly, the
+    uninterrupted run (the data is a fixed argument of the scan chunk, so the only
+    state is the carried ``Parafac2State`` — if the checkpoint preserves it exactly, the
     trajectory must re-converge exactly).
 """
 import os
@@ -76,7 +76,7 @@ def test_parafac2_state_roundtrip_with_admm_aux(tmp_path):
 def test_restore_then_continue_bitwise_scan(tmp_path):
     """Interrupt/resume under the scan engine reproduces the uninterrupted
     trajectory BITWISE: same chunk boundaries, state round-tripped exactly
-    through disk, data closed over by the compiled chunk."""
+    through disk, the same data passed to the compiled chunk."""
     bt = _bt(seed=1)
     opts = _admm_opts(engine="scan", check_every=4)
 

@@ -206,7 +206,7 @@ def test_expand_q_partial_isometry_and_exact_fit(choa_bt):
 
 def test_engine_parity_on_cores(choa_bt):
     """host / scan / while(check_every=0) are bitwise-identical on the
-    compressed path (same data closed over, same program); mesh agrees to
+    compressed path (same data argument, same program); mesh agrees to
     collective-reduction tolerance."""
     base = Parafac2Options(rank=4, dtype=f64, compress="rsvd:10:6:1")
     s_host, h_host = fit(choa_bt, base, max_iters=8, tol=0.0, seed=0)

@@ -9,8 +9,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.sharding import (
     LM_RULES, SP_RULES, axis_rules, barrier, current_mesh, current_rules,
-    enforce_divisible, logical_spec, param_shardings, param_spec, shard,
-    unroll_active, unroll_loops)
+    enforce_divisible, logical_spec, make_mesh, param_shardings, param_spec,
+    shard, unroll_active, unroll_loops)
 
 
 # ---------------------------------------------------------------------------
@@ -18,7 +18,7 @@ from repro.dist.sharding import (
 # ---------------------------------------------------------------------------
 
 def test_rules_resolve_known_logical_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with axis_rules(LM_RULES, mesh):
         assert logical_spec(("batch", "seq", "mlp")) == P("data", None, "model")
         assert logical_spec(("tokens", "embed")) == P("data", None)
@@ -31,14 +31,14 @@ def test_rules_resolve_known_logical_axes():
 
 
 def test_rules_drop_axes_missing_from_mesh():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with axis_rules(LM_RULES, mesh):
         # "pod" and "model" don't exist on a 1-axis mesh
         assert logical_spec(("batch", "heads")) == P("data", None)
 
 
 def test_sp_rules_shard_residual_seq():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with axis_rules(SP_RULES, mesh):
         assert logical_spec(("batch", "seq_res", "embed")) == P(
             "data", "model", None)
@@ -48,7 +48,7 @@ def test_sp_rules_shard_residual_seq():
 
 
 def test_context_stack_nests_and_restores():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert current_rules() is None and current_mesh() is None
     with axis_rules(LM_RULES, mesh):
         assert current_rules() is LM_RULES and current_mesh() is mesh
@@ -70,7 +70,7 @@ def test_shard_is_noop_outside_mesh():
 
 
 def test_shard_applies_constraint_under_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.arange(8.0).reshape(2, 4)
     with axis_rules(LM_RULES, mesh):
         y = shard(x, ("batch", "mlp"))
@@ -106,7 +106,7 @@ def test_enforce_divisible_keeps_exact_and_drops_uneven():
 # ---------------------------------------------------------------------------
 
 def test_param_shardings_small_pytree():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {
         "embed": {"tokens": jax.ShapeDtypeStruct((8, 4), jnp.float32)},
         "layers": {"groups": {"p0_attn_mlp": {

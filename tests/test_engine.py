@@ -1,7 +1,7 @@
 """Engine-parity suite: host / scan / mesh must walk the same fit trajectory.
 
-The scan engine closes over the data exactly like the host loop's jit, so its
-trajectory is bitwise host's; the mesh engine compiles under shard_map
+The scan engine takes the data as an argument exactly like the host loop's jit,
+so its trajectory is bitwise host's; the mesh engine compiles under shard_map
 (different fusion), so it gets a small epsilon. Both jnp and pallas MTTKRP
 backends are covered (pallas in interpret mode on CPU — tiny cases only).
 """

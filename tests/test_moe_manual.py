@@ -22,10 +22,10 @@ _SRC = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config, reduced
-    from repro.dist.sharding import LM_RULES, axis_rules
+    from repro.dist.sharding import LM_RULES, axis_rules, make_mesh
     from repro.models.moe import init_moe, _moe_block_auto, _moe_block_manual
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = reduced(get_config("phi3.5-moe-42b-a6.6b"))
     # no-drop capacity: local and global dispatch then agree exactly
     cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts * 3))

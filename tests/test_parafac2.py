@@ -1,6 +1,7 @@
 """End-to-end PARAFAC2-ALS behaviour: monotone fit, recovery, option parity."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.sparse import random_parafac2, random_irregular
@@ -125,3 +126,17 @@ def test_reconstruction_error_matches_fit():
     # one, and both agree tightly near convergence.
     assert explicit_fit >= float(state.fit) - 1e-8
     np.testing.assert_allclose(float(state.fit), explicit_fit, atol=1e-3)
+
+
+def test_als_step_asks_for_f32_matmuls():
+    """A TPU runs an f32 matmul as one bf16 pass unless asked for more; at
+    that precision the fit broke down on a v5e. Every matmul of the step
+    must carry HIGHEST precision (the CPU runs it the same either way)."""
+    data, _ = random_parafac2(n_subjects=12, n_cols=16, max_rows=10, rank=3,
+                              density=0.5, seed=0)
+    bt = bucketize(data, max_buckets=2, dtype=jnp.float32)
+    opts = Parafac2Options(rank=3)
+    st = init_state(bt, opts, 0)
+    text = jax.jit(lambda d, s: als_step(d, s, opts)).lower(bt, st).as_text()
+    dots = [l for l in text.splitlines() if "stablehlo.dot_general" in l]
+    assert dots and all("HIGHEST" in l for l in dots)

@@ -62,3 +62,15 @@ def test_property_gram_eigh_orthonormal(seed, kb, i, r):
     Q = polar_gram_eigh(B)
     G = np.einsum("kir,kil->krl", np.asarray(Q), np.asarray(Q))
     np.testing.assert_allclose(G, np.broadcast_to(np.eye(r), G.shape), atol=1e-6)
+
+
+def test_gram_eigh_rank_deficient_f32_matches_svd():
+    """A subject with fewer rows than R has R - I null directions in its
+    Gram. In f32 their eigenvalues are roundoff noise; giving them an inverse
+    root put noise of order sqrt(roundoff) into Q, so the pseudo-polar
+    factor must cut them off at the dtype's resolution."""
+    rng = np.random.default_rng(0)
+    B = rng.standard_normal((16, 3, 8))                    # I=3 < R=8
+    ref = np.asarray(polar_svd(jnp.asarray(B)))            # f64
+    Q = np.asarray(polar_gram_eigh(jnp.asarray(B, jnp.float32)))
+    np.testing.assert_allclose(Q, ref, atol=1e-5)

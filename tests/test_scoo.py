@@ -176,8 +176,8 @@ def test_fit_parity_admm_constraint(choa_small):
 
 @pytest.mark.parametrize("engine,atol", [("scan", 0.0), ("mesh", 1e-8)])
 def test_engine_parity_scoo(choa_small, engine, atol):
-    """host vs scan bitwise on SCOO (scan closes over the data like the host
-    jit); mesh to eps (shard_map compiles the step differently)."""
+    """host vs scan bitwise on SCOO (scan takes the data as an argument like the
+    host jit); mesh to eps (shard_map compiles the step differently)."""
     _, sc = _pair(choa_small, max_buckets=2, col_align=128,
                   subject_align=len(jax.devices()))
     kw = dict(rank=3, dtype=jnp.float64, backend="auto",

@@ -16,7 +16,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (
-    LM_RULES, axis_rules, enforce_divisible, logical_spec, param_spec)
+    LM_RULES, axis_rules, enforce_divisible, logical_spec, make_mesh,
+    param_spec)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -26,7 +27,7 @@ def test_logical_spec_no_rules_is_empty():
 
 
 def test_logical_spec_drops_missing_pod_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with axis_rules(LM_RULES, mesh):
         spec = logical_spec(("batch", "seq", "heads"), mesh)
     # batch -> ("pod","data") but mesh has no "pod": reduced to "data"
@@ -34,7 +35,7 @@ def test_logical_spec_drops_missing_pod_axis():
 
 
 def test_enforce_divisible_replicates_uneven():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # fake 16-wide axes by building the spec directly
     spec = P("data", "model")
     out = enforce_divisible(spec, (7, 8), mesh)   # axes are size 1 -> fine
@@ -42,7 +43,7 @@ def test_enforce_divisible_replicates_uneven():
 
 
 def test_param_spec_paths():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with axis_rules(LM_RULES, mesh):
         # attention projections: last dim on model
         assert param_spec("layers/groups/p0_attn_mlp/attn/wq", 2, stacked=False)[-1] == "model"
@@ -65,12 +66,13 @@ _SUBPROCESS_SRC = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config, reduced, ShapeSpec
-    from repro.dist.sharding import LM_RULES, axis_rules, param_shardings
+    from repro.dist.sharding import (
+        LM_RULES, axis_rules, make_mesh, param_shardings)
     from repro.models import build
     from repro.analysis.hlo import collective_bytes
     from repro.analysis.roofline import roofline_terms
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     out = {}
     for arch in ["qwen3-0.6b", "mamba2-780m", "phi3.5-moe-42b-a6.6b"]:
         cfg = reduced(get_config(arch), d_model=64, n_heads=4, n_kv_heads=2,
@@ -133,11 +135,12 @@ def test_elastic_checkpoint_restore_onto_mesh(tmp_path):
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import checkpoint as ckpt
+        from repro.dist.sharding import make_mesh
 
         tree = {{"w": jnp.arange(32.0).reshape(8, 4), "b": jnp.ones((4,))}}
         ckpt.save({str(tmp_path)!r}, 3, tree)
 
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         shardings = {{"w": NamedSharding(mesh, P("data")),
                       "b": NamedSharding(mesh, P())}}
         restored, step, _ = ckpt.restore({str(tmp_path)!r}, tree,
@@ -163,7 +166,8 @@ def test_hlo_collective_parser_on_psum():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.analysis.hlo import collective_bytes
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.dist.sharding import make_mesh
+        mesh = make_mesh((4,), ("data",))
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         def f(a):
             return jax.lax.with_sharding_constraint(

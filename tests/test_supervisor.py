@@ -3,8 +3,8 @@
 The contract under test: a fit with injected faults — a transient blip
 (in-place retry), a retry-exhausting fault (checkpoint-restore + rewind),
 and an injected NaN (health-sentinel rollback) — converges to the SAME
-final factors as an unfaulted run: bitwise under the scan engine (the chunk
-closes over the data, so the carried state is the only state), ≤1e-8 under
+final factors as an unfaulted run: bitwise under the scan engine (the data
+is a fixed argument of the chunk, so the carried state is the only state), ≤1e-8 under
 mesh. Plus the satellites: StepWatchdog behaviour under the supervisor
 (straggler flags never consume the retry budget, compile chunks never
 flagged, window bounding on 1000+ chunk histories), the nnz-balanced shard
