@@ -70,8 +70,6 @@ and benchmarks. See docs/ARCHITECTURE.md (stage 4½ and the SCOO stage).
 from __future__ import annotations
 
 import abc
-import collections
-import contextlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -79,6 +77,7 @@ import jax.numpy as jnp
 
 from repro.core import spartan
 from repro.core.irregular import SparseBucket
+from repro.core.spans import _tick, dispatch_tally
 from repro.dist.sharding import shard
 from repro.kernels.common import (PRECISIONS, accum_dtype, compute_cast,
                                   fold_subject_mask)
@@ -94,44 +93,6 @@ __all__ = [
     "get_backend",
     "dispatch_tally",
 ]
-
-
-# ---------------------------------------------------------------------------
-# Dispatch tally: how many per-bucket stage launches stream large operands
-# ---------------------------------------------------------------------------
-
-_TALLY: Optional[collections.Counter] = None
-
-
-@contextlib.contextmanager
-def dispatch_tally():
-    """Count the per-bucket backend stage launches that stream I-/C-sized
-    operands (the slab, XkV/Q, or Yc) — the launches the fused megakernel
-    route collapses. Stages that only touch [Kb,R,R]-and-smaller tiles
-    (mode-1/mode-3 from a cached YkV) are not counted.
-
-    Counting happens when the backend methods RUN (eagerly or at jit trace
-    time), so wrap one untraced/tracing ``als_step`` evaluation::
-
-        with dispatch_tally() as t:
-            jax.eval_shape(lambda s: als_step(data, s, opts), state)
-        per_bucket = sum(t.values()) / len(data.buckets)
-
-    The staged CC path tallies 5 per bucket per iteration (procrustes_b,
-    project, mode1, mode2, ykv); the fused route tallies 4 — the standalone
-    projection pass disappears (``project_bucket`` carries Q).
-    """
-    global _TALLY
-    prev, _TALLY = _TALLY, collections.Counter()
-    try:
-        yield _TALLY
-    finally:
-        _TALLY = prev
-
-
-def _tick(stage: str) -> None:
-    if _TALLY is not None:
-        _TALLY[stage] += 1
 
 
 class MttkrpBackend(abc.ABC):

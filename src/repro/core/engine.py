@@ -53,6 +53,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import parafac2 as p2
+from repro.core.spans import span
 from repro.dist import sharding as dsh
 
 __all__ = ["ENGINES", "als_chunk_fn", "fit_device", "make_als_chunk",
@@ -312,9 +313,11 @@ def fit_device(
     if opts.check_every <= 0:
         # while_loop variant: one dispatch, on-device convergence
         run = make_als_while(data, opts, max_iters, tol)
-        state, hist, n = run(state)
-        n = int(n)
-        history = [float(f) for f in np.asarray(hist[:n])]
+        with span("fit.dispatch"):
+            state, hist, n = run(state)
+        with span("fit.sync"):
+            n = int(n)
+            history = [float(f) for f in np.asarray(hist[:n])]
         if verbose:
             print(f"[engine:{opts.engine}/while] {n} iters in one dispatch, "
                   f"fit={history[-1] if history else float('nan'):.6f}")
@@ -331,8 +334,10 @@ def fit_device(
         n = min(opts.check_every, max_iters - len(history))
         if n not in chunks:
             chunks[n] = make_als_chunk(data, opts, n)
-        state, fits = chunks[n](state)
-        fits = np.asarray(fits)            # ONE device sync per chunk
+        with span("fit.dispatch"):
+            state, fits = chunks[n](state)
+        with span("fit.sync"):
+            fits = np.asarray(fits)        # ONE device sync per chunk
         for f in fits:
             history.append(float(f))
             if len(history) > 1 and abs(f - prev) < tol:

@@ -44,6 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import span
 from repro.sparse.coo import IrregularCOO
 from repro.sparse.bucketing import BucketPlan, plan_buckets, route_formats
 from repro.sparse.bucketing import SCOO_DENSITY_THRESHOLD
@@ -402,87 +403,89 @@ def bucketize(
     stage = _staging_dtype(dtype)
     buckets: List = []
     for bi, ((i_pad, c_pad), members) in enumerate(zip(plan.shapes, plan.members)):
-        kb = _pad_to(len(members), subject_align)
-        fmt = formats[bi]
-        cols = np.zeros((kb, c_pad), dtype=np.int32)
-        cmask = np.zeros((kb, c_pad), dtype=stage)
-        sids = np.zeros((kb,), dtype=np.int32)
-        smask = np.zeros((kb,), dtype=stage)
-        rows_n = np.zeros((kb,), dtype=np.int32)
-        if fmt == "cc":
-            vals = np.zeros((kb, i_pad, c_pad), dtype=stage)
-        elif fmt == "scoo":
-            if plan.nnz_pads is not None:
-                n_pad = plan.nnz_pads[bi]
-            else:
-                n_pad = _pad_to(int(max((nnzc[k] for k in members),
-                                        default=1)), nnz_align)
-            vals = np.zeros((kb, n_pad), dtype=stage)
-            trip_rows = np.zeros((kb, n_pad), dtype=np.int32)
-            trip_lcols = np.zeros((kb, n_pad), dtype=np.int32)
-            row_ends = np.zeros((kb, i_pad), dtype=np.int32)
-            # pads keep identity slots at the tail of the col-sorted view;
-            # their value is 0 and every col_end is <= nnz, so they never
-            # land in a segment
-            cperm = np.tile(np.arange(n_pad, dtype=np.int32), (kb, 1))
-            col_ends = np.zeros((kb, c_pad), dtype=np.int32)
-            nnz_n = np.zeros((kb,), dtype=np.int32)
-        else:
-            raise ValueError(f"unknown bucket format {fmt!r}")
-        for slot, k in enumerate(members):
-            s = data.subjects[k]
-            kept = s.nonzero_cols()
-            remap = {int(c): i for i, c in enumerate(kept)}
-            local_c = np.asarray([remap[int(c)] for c in s.cols], dtype=np.int32)
+        with span("layout.pack"):
+            kb = _pad_to(len(members), subject_align)
+            fmt = formats[bi]
+            cols = np.zeros((kb, c_pad), dtype=np.int32)
+            cmask = np.zeros((kb, c_pad), dtype=stage)
+            sids = np.zeros((kb,), dtype=np.int32)
+            smask = np.zeros((kb,), dtype=stage)
+            rows_n = np.zeros((kb,), dtype=np.int32)
             if fmt == "cc":
-                vals[slot, s.rows, local_c] = s.vals
+                vals = np.zeros((kb, i_pad, c_pad), dtype=stage)
+            elif fmt == "scoo":
+                if plan.nnz_pads is not None:
+                    n_pad = plan.nnz_pads[bi]
+                else:
+                    n_pad = _pad_to(int(max((nnzc[k] for k in members),
+                                            default=1)), nnz_align)
+                vals = np.zeros((kb, n_pad), dtype=stage)
+                trip_rows = np.zeros((kb, n_pad), dtype=np.int32)
+                trip_lcols = np.zeros((kb, n_pad), dtype=np.int32)
+                row_ends = np.zeros((kb, i_pad), dtype=np.int32)
+                # pads keep identity slots at the tail of the col-sorted view;
+                # their value is 0 and every col_end is <= nnz, so they never
+                # land in a segment
+                cperm = np.tile(np.arange(n_pad, dtype=np.int32), (kb, 1))
+                col_ends = np.zeros((kb, c_pad), dtype=np.int32)
+                nnz_n = np.zeros((kb,), dtype=np.int32)
             else:
-                # sorted flat COO: row-major (row, local col) order gives the
-                # segment-sums contiguous destination runs
-                order = np.lexsort((local_c, s.rows))
-                nz = s.nnz
-                if nz > vals.shape[1]:
-                    raise ValueError(
-                        f"subject {k} has {nz} nonzeros > bucket N_pad "
-                        f"{vals.shape[1]} (stale plan?)")
-                rr, lc = s.rows[order], local_c[order]
-                vals[slot, :nz] = s.vals[order]
-                trip_rows[slot, :nz] = rr
-                trip_lcols[slot, :nz] = lc
-                # CSR/CSC-style boundaries for the scatter-free segment-sums
-                row_ends[slot] = np.searchsorted(rr, np.arange(i_pad),
-                                                 side="right")
-                corder = np.lexsort((rr, lc)).astype(np.int32)
-                cperm[slot, :nz] = corder
-                col_ends[slot] = np.searchsorted(lc[corder], np.arange(c_pad),
-                                                 side="right")
-                nnz_n[slot] = nz
-            cols[slot, : kept.size] = kept
-            cmask[slot, : kept.size] = 1.0
-            sids[slot] = k
-            smask[slot] = 1.0
-            rows_n[slot] = s.n_rows
-        common = dict(
-            cols=jnp.asarray(cols),
-            col_mask=jnp.asarray(cmask, dtype=dtype),
-            subject_ids=jnp.asarray(sids),
-            subject_mask=jnp.asarray(smask, dtype=dtype),
-            row_counts=jnp.asarray(rows_n),
-        )
-        if fmt == "cc":
-            buckets.append(Bucket(vals=jnp.asarray(vals, dtype=dtype), **common))
-        else:
-            buckets.append(SparseBucket(
-                vals=jnp.asarray(vals, dtype=dtype),
-                rows=jnp.asarray(trip_rows),
-                lcols=jnp.asarray(trip_lcols),
-                row_ends=jnp.asarray(row_ends),
-                cperm=jnp.asarray(cperm),
-                col_ends=jnp.asarray(col_ends),
-                nnz_counts=jnp.asarray(nnz_n),
-                n_rows_pad=i_pad,
-                **common,
-            ))
+                raise ValueError(f"unknown bucket format {fmt!r}")
+            for slot, k in enumerate(members):
+                s = data.subjects[k]
+                kept = s.nonzero_cols()
+                remap = {int(c): i for i, c in enumerate(kept)}
+                local_c = np.asarray([remap[int(c)] for c in s.cols], dtype=np.int32)
+                if fmt == "cc":
+                    vals[slot, s.rows, local_c] = s.vals
+                else:
+                    # sorted flat COO: row-major (row, local col) order gives the
+                    # segment-sums contiguous destination runs
+                    order = np.lexsort((local_c, s.rows))
+                    nz = s.nnz
+                    if nz > vals.shape[1]:
+                        raise ValueError(
+                            f"subject {k} has {nz} nonzeros > bucket N_pad "
+                            f"{vals.shape[1]} (stale plan?)")
+                    rr, lc = s.rows[order], local_c[order]
+                    vals[slot, :nz] = s.vals[order]
+                    trip_rows[slot, :nz] = rr
+                    trip_lcols[slot, :nz] = lc
+                    # CSR/CSC-style boundaries for the scatter-free segment-sums
+                    row_ends[slot] = np.searchsorted(rr, np.arange(i_pad),
+                                                     side="right")
+                    corder = np.lexsort((rr, lc)).astype(np.int32)
+                    cperm[slot, :nz] = corder
+                    col_ends[slot] = np.searchsorted(lc[corder], np.arange(c_pad),
+                                                     side="right")
+                    nnz_n[slot] = nz
+                cols[slot, : kept.size] = kept
+                cmask[slot, : kept.size] = 1.0
+                sids[slot] = k
+                smask[slot] = 1.0
+                rows_n[slot] = s.n_rows
+        with span("layout.to_device"):
+            common = dict(
+                cols=jnp.asarray(cols),
+                col_mask=jnp.asarray(cmask, dtype=dtype),
+                subject_ids=jnp.asarray(sids),
+                subject_mask=jnp.asarray(smask, dtype=dtype),
+                row_counts=jnp.asarray(rows_n),
+            )
+            if fmt == "cc":
+                buckets.append(Bucket(vals=jnp.asarray(vals, dtype=dtype), **common))
+            else:
+                buckets.append(SparseBucket(
+                    vals=jnp.asarray(vals, dtype=dtype),
+                    rows=jnp.asarray(trip_rows),
+                    lcols=jnp.asarray(trip_lcols),
+                    row_ends=jnp.asarray(row_ends),
+                    cperm=jnp.asarray(cperm),
+                    col_ends=jnp.asarray(col_ends),
+                    nnz_counts=jnp.asarray(nnz_n),
+                    n_rows_pad=i_pad,
+                    **common,
+                ))
     return Bucketed(
         buckets=buckets,
         n_subjects=data.n_subjects,

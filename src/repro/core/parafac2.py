@@ -42,10 +42,21 @@ from repro.core import compress as _compress
 from repro.core import constraints as cst
 from repro.core.cp import normalize_columns
 from repro.core.procrustes import solve_q
+from repro.core.spans import span
 from repro.dist.sharding import psum_subjects
 
-__all__ = ["Parafac2State", "Parafac2Options", "constraints_for", "init_state",
-           "als_step", "fit", "reconstruct_uk", "update_subjects", "w_global"]
+__all__ = ["Parafac2State", "Parafac2Options", "STAGES", "constraints_for",
+           "init_state", "als_step", "fit", "reconstruct_uk", "update_subjects",
+           "w_global"]
+
+# The ALS stages, in the order ``als_step`` runs them. Each is a
+# ``jax.named_scope`` inside the step's own ``als`` scope, so every op of the
+# compiled step carries ``.../als/<stage>/...`` in its HLO ``op_name``
+# metadata and a profiler trace can be summed per stage. The stages that
+# ``dispatch_tally`` counts are spelled as it spells them. Names stay at 12
+# characters or fewer, so that an op's name with its scope stays short.
+STAGES = ("procrustes_b", "polar", "project", "mode1", "h_solve", "mode2",
+          "scatter", "v_solve", "ykv", "mode3", "w_solve", "fit")
 
 
 class Parafac2State(NamedTuple):
@@ -251,14 +262,17 @@ def _procrustes_project(
     backend.
     """
     be = get_backend(opts.backend, opts.precision) if be is None else be
-    Vg = b.gather_v(V)                                   # [Kb, C, R]
-    Wb = _w_rows(W, b, i)                                # [Kb, R]
-    # B_k = X_k V S_k H^T  == (XkV * w_k) @ H^T — one fused slab pass on the
-    # fused route, xkv + a small einsum on the staged ones
-    XkV, B = be.procrustes_b_bucket(b, H, Wb, V, Vg)     # [Kb, I, R] x2
-    Q = solve_q(B, opts.procrustes)                      # [Kb, I, R]
-    Q = be.shard_subjects(Q * b.subject_mask[:, None, None])
-    proj = be.project_bucket(b, Q)
+    with jax.named_scope("procrustes_b"):
+        Vg = b.gather_v(V)                                   # [Kb, C, R]
+        Wb = _w_rows(W, b, i)                                # [Kb, R]
+        # B_k = X_k V S_k H^T  == (XkV * w_k) @ H^T — one fused slab pass on
+        # the fused route, xkv + a small einsum on the staged ones
+        XkV, B = be.procrustes_b_bucket(b, H, Wb, V, Vg)     # [Kb, I, R] x2
+    with jax.named_scope("polar"):
+        Q = solve_q(B, opts.procrustes)                      # [Kb, I, R]
+        Q = be.shard_subjects(Q * b.subject_mask[:, None, None])
+    with jax.named_scope("project"):
+        proj = be.project_bucket(b, Q)
     return proj, XkV, Q
 
 
@@ -278,6 +292,7 @@ def _f32_matmuls(fn):
 
 
 @_f32_matmuls
+@jax.named_scope("als")
 def als_step(
     data: Bucketed,
     state: Parafac2State,
@@ -288,6 +303,8 @@ def als_step(
     Every factor update routes through the per-mode constraint bundle
     (:func:`constraints_for`); ADMM-routed constraints read and write their
     dual state in ``state.aux`` — the engines carry it like any other leaf.
+    Each stage runs under its name in :data:`STAGES`, inside one ``als``
+    scope; each ``psum_subjects`` sits in the stage whose sum it reduces.
     """
     H, V, W = state.H, state.V, state.W
     R, J, K = opts.rank, data.n_cols, data.n_subjects
@@ -308,85 +325,102 @@ def als_step(
                   for i, b in enumerate(data.buckets)]
 
     # ---- 3a: H update (mode-1 MTTKRP) --------------------------------------
-    M1 = jnp.zeros((R, R), opts.dtype)
-    for i, (b, (proj, XkV, Q)) in enumerate(zip(data.buckets, per_bucket)):
-        Wb = _w_rows(W, b, i)
-        if opts.mode1_reuse:
-            # Y_k V = Q_k^T (X_k V): skip the gather+matmul on sparse data
-            # (fused backends reduce M1 in the same dispatch that forms YkV)
-            M1 = M1 + be.mode1_xkv_bucket(b, Q, XkV, Wb)
-        else:
-            M1 = M1 + be.mode1_bucket(b, proj, Wb, V)
-    M1 = psum_subjects(M1)
-    H_new, aux_h = cons["h"].update(M1, _ridged(_w_gram(W) * (V.T @ V), opts),
-                                    H, aux["h"], **solve_kw)
-    aux_w = aux["w"]
-    if not cons["h"].penalized:
-        # absorb scale into W (model-invariant for indicator constraints;
-        # penalized modes keep their natural scale — see Constraint.penalized)
-        H_new, h_norms = normalize_columns(H_new)
-        aux_h = cst.scale_aux(aux_h, 1.0 / jnp.maximum(h_norms, 1e-12))
-        W = scale_w(W, h_norms)
-        aux_w = cst.scale_aux(aux_w, h_norms)
+    with jax.named_scope("mode1"):
+        M1 = jnp.zeros((R, R), opts.dtype)
+        for i, (b, (proj, XkV, Q)) in enumerate(zip(data.buckets, per_bucket)):
+            Wb = _w_rows(W, b, i)
+            if opts.mode1_reuse:
+                # Y_k V = Q_k^T (X_k V): skip the gather+matmul on sparse
+                # data (fused backends reduce M1 in the same dispatch that
+                # forms YkV)
+                M1 = M1 + be.mode1_xkv_bucket(b, Q, XkV, Wb)
+            else:
+                M1 = M1 + be.mode1_bucket(b, proj, Wb, V)
+        M1 = psum_subjects(M1)
+    with jax.named_scope("h_solve"):
+        H_new, aux_h = cons["h"].update(
+            M1, _ridged(_w_gram(W) * (V.T @ V), opts), H, aux["h"],
+            **solve_kw)
+        aux_w = aux["w"]
+        if not cons["h"].penalized:
+            # absorb scale into W (model-invariant for indicator constraints;
+            # penalized modes keep their natural scale — see
+            # Constraint.penalized)
+            H_new, h_norms = normalize_columns(H_new)
+            aux_h = cst.scale_aux(aux_h, 1.0 / jnp.maximum(h_norms, 1e-12))
+            W = scale_w(W, h_norms)
+            aux_w = cst.scale_aux(aux_w, h_norms)
 
     # ---- 3b: V update (mode-2 MTTKRP) --------------------------------------
     M2 = jnp.zeros((J, R), opts.dtype)
     for i, (b, (proj, _, _)) in enumerate(zip(data.buckets, per_bucket)):
-        Wb = _w_rows(W, b, i)
-        A = be.mode2_bucket(b, proj, H_new, Wb)
-        M2 = M2 + be.mode2_scatter(A, b.cols, J).astype(M2.dtype)
-    M2 = psum_subjects(M2)
-    V_new, aux_v = cons["v"].update(
-        M2, _ridged(_w_gram(W) * (H_new.T @ H_new), opts), V,
-        aux["v"], **solve_kw)
-    if not cons["v"].penalized:
-        V_new, v_norms = normalize_columns(V_new)
-        aux_v = cst.scale_aux(aux_v, 1.0 / jnp.maximum(v_norms, 1e-12))
-        W = scale_w(W, v_norms)
-        aux_w = cst.scale_aux(aux_w, v_norms)
+        with jax.named_scope("mode2"):
+            Wb = _w_rows(W, b, i)
+            A = be.mode2_bucket(b, proj, H_new, Wb)
+        with jax.named_scope("scatter"):
+            M2 = M2 + be.mode2_scatter(A, b.cols, J).astype(M2.dtype)
+    with jax.named_scope("scatter"):
+        M2 = psum_subjects(M2)
+    with jax.named_scope("v_solve"):
+        V_new, aux_v = cons["v"].update(
+            M2, _ridged(_w_gram(W) * (H_new.T @ H_new), opts), V,
+            aux["v"], **solve_kw)
+        if not cons["v"].penalized:
+            V_new, v_norms = normalize_columns(V_new)
+            aux_v = cst.scale_aux(aux_v, 1.0 / jnp.maximum(v_norms, 1e-12))
+            W = scale_w(W, v_norms)
+            aux_w = cst.scale_aux(aux_w, v_norms)
 
     # ---- 3c: W update (mode-3 MTTKRP) --------------------------------------
-    VtV = V_new.T @ V_new
-    gram3 = _ridged(VtV * (H_new.T @ H_new), opts)
+    with jax.named_scope("w_solve"):
+        VtV = V_new.T @ V_new
+        gram3 = _ridged(VtV * (H_new.T @ H_new), opts)
     rows_per_bucket = []
     Gs = []   # G_k = Y_k V_new per bucket, shared with the fit computation
     for b, (proj, _, _) in zip(data.buckets, per_bucket):
-        G = be.ykv_bucket(b, proj, V_new)
+        with jax.named_scope("ykv"):
+            G = be.ykv_bucket(b, proj, V_new)
         Gs.append(G)
-        rows_per_bucket.append(
-            be.mode3_bucket(b, proj, H_new, YkV=G))
+        with jax.named_scope("mode3"):
+            rows_per_bucket.append(
+                be.mode3_bucket(b, proj, H_new, YkV=G))
     if bucketed:
         # per-bucket W rows update in place — no K-wide scatter, no gathers;
         # per-bucket aux rides in a list aligned with the buckets
-        aux_w_list = (aux_w if isinstance(aux_w, list)
-                      else [() for _ in data.buckets])
-        upd = [cons["w"].update(rows.astype(wb.dtype), gram3, wb, awb,
-                                **solve_kw)
-               for rows, wb, awb in zip(rows_per_bucket, W, aux_w_list)]
-        W_new = tuple(wn * b.subject_mask[:, None]
-                      for (wn, _), b in zip(upd, data.buckets))
-        aux_w = [a for _, a in upd] if cons["w"].admm else ()
+        with jax.named_scope("w_solve"):
+            aux_w_list = (aux_w if isinstance(aux_w, list)
+                          else [() for _ in data.buckets])
+            upd = [cons["w"].update(rows.astype(wb.dtype), gram3, wb, awb,
+                                    **solve_kw)
+                   for rows, wb, awb in zip(rows_per_bucket, W, aux_w_list)]
+            W_new = tuple(wn * b.subject_mask[:, None]
+                          for (wn, _), b in zip(upd, data.buckets))
+            aux_w = [a for _, a in upd] if cons["w"].admm else ()
     else:
-        M3 = jnp.zeros((K, R), opts.dtype)
-        for b, rows in zip(data.buckets, rows_per_bucket):
-            M3 = M3.at[b.subject_ids].add(rows.astype(M3.dtype))
-        M3 = psum_subjects(M3)
-        W_new, aux_w = cons["w"].update(M3, gram3, W, aux_w, **solve_kw)
+        with jax.named_scope("mode3"):
+            M3 = jnp.zeros((K, R), opts.dtype)
+            for b, rows in zip(data.buckets, rows_per_bucket):
+                M3 = M3.at[b.subject_ids].add(rows.astype(M3.dtype))
+            M3 = psum_subjects(M3)
+        with jax.named_scope("w_solve"):
+            W_new, aux_w = cons["w"].update(M3, gram3, W, aux_w, **solve_kw)
 
     # ---- 4: fit ------------------------------------------------------------
     # ||X_k - Q_k H S_k V^T||^2 = ||X||^2 - 2 tr(S H^T G_k) + tr(S Φ S V^T V),
     # with G_k = Y_k V_new and Φ = H^T H — all R x R algebra.
-    Phi = H_new.T @ H_new
-    delta = jnp.zeros((), opts.dtype)
-    for i, b in enumerate(data.buckets):
-        G = Gs[i]                                              # [Kb, R, R]
-        Wb = _w_rows(W_new, b, i)                              # [Kb, R]
-        cross = jnp.einsum("rl,krl,kl,k->", H_new, G, Wb, b.subject_mask)
-        model = jnp.einsum("rl,rl,kr,kl,k->", Phi, VtV, Wb, Wb, b.subject_mask)
-        delta = delta - 2.0 * cross + model
-    resid = jnp.asarray(data.norm_sq, opts.dtype) + psum_subjects(delta)
-    fit_val = 1.0 - jnp.sqrt(jnp.maximum(resid, 0.0)) / jnp.sqrt(
-        jnp.asarray(data.norm_sq, opts.dtype))
+    with jax.named_scope("fit"):
+        Phi = H_new.T @ H_new
+        delta = jnp.zeros((), opts.dtype)
+        for i, b in enumerate(data.buckets):
+            G = Gs[i]                                          # [Kb, R, R]
+            Wb = _w_rows(W_new, b, i)                          # [Kb, R]
+            cross = jnp.einsum("rl,krl,kl,k->", H_new, G, Wb, b.subject_mask)
+            model = jnp.einsum("rl,rl,kr,kl,k->", Phi, VtV, Wb, Wb,
+                               b.subject_mask)
+            delta = delta - 2.0 * cross + model
+        resid = jnp.asarray(data.norm_sq, opts.dtype) + psum_subjects(delta)
+        fit_val = 1.0 - jnp.sqrt(jnp.maximum(resid, 0.0)) / jnp.sqrt(
+            jnp.asarray(data.norm_sq, opts.dtype))
 
     return Parafac2State(H=H_new, V=V_new, W=W_new, fit=fit_val,
                          aux={"h": aux_h, "v": aux_v, "w": aux_w})
@@ -426,8 +460,10 @@ def fit(
     history: List[float] = []
     prev = -np.inf
     for it in range(max_iters):
-        state = step(data, state)
-        f = float(state.fit)
+        with span("fit.dispatch"):
+            state = step(data, state)
+        with span("fit.sync"):
+            f = float(state.fit)
         history.append(f)
         if verbose:
             print(f"iter {it:3d}  fit={f:.6f}")

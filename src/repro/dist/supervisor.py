@@ -145,6 +145,7 @@ def supervised_fit(
     # engine import must not run at repro.dist import time
     from repro.core import engine as _engine
     from repro.core import parafac2 as p2
+    from repro.core.spans import span
     from repro import checkpoint as ckpt
 
     cfg = config or SupervisorConfig()
@@ -234,8 +235,10 @@ def supervised_fit(
             if injector is not None:
                 injector.check(chunk_idx)
             t0 = cfg.clock()
-            s2, fits = chunks[n](s)
-            fits = np.asarray(fits)        # the chunk's one device sync
+            with span("fit.dispatch"):
+                s2, fits = chunks[n](s)
+            with span("fit.sync"):
+                fits = np.asarray(fits)    # the chunk's one device sync
             timing["dt"] = cfg.clock() - t0
             return s2, fits
 

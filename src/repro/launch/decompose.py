@@ -16,7 +16,8 @@ small cores and expands exactly at the end — the DPar2-style decoupling of
 iteration count from data size); ``--json`` writes the machine-readable run
 summary CI and the benchmarks consume (the unified ``schema_version`` +
 ``resolved_options`` layout of repro.launch.summary), including the resolved
-constraint/compress blocks and the per-bucket format/density decisions.
+constraint/compress blocks, the per-bucket format/density decisions and the
+``spans`` block of host-span totals (repro.core.spans).
 
 Fault tolerance (repro.dist.supervisor; scan/mesh engines): ``--ckpt-dir``
 checkpoints every ``--ckpt-every`` chunks and ``--resume`` continues from
@@ -49,6 +50,7 @@ from repro.core.compress import available as available_preprocess
 from repro.core.constraints import (
     available as available_constraints, constraint_summary, parse_constraint_arg)
 from repro.core.interpret import subject_top_phenotypes, top_phenotype_features
+from repro.core.spans import reset_spans, span, span_totals
 from repro.data import choa_like, movielens_like
 from repro.dist.fault import FaultInjector
 from repro.dist.supervisor import SupervisorConfig, supervised_fit
@@ -91,35 +93,44 @@ def load_dataset(name: str, scale: float, seed: int):
     raise ValueError(name)
 
 
+@span("layout")
 def build_buckets(data, *, format: str, engine: str, max_buckets: int):
     """The driver's device layout for ``data``: the quantile bucket plan
     (nnz-balanced over the subject shards under the mesh engine), the
     per-bucket format routing, and ``bucketize``. Returns ``(Bucketed,
-    per-bucket stats, shard-balance report or None)``."""
+    per-bucket stats, shard-balance report or None)``. Runs under the host
+    span ``layout``, with ``layout.plan`` and ``layout.route`` here and
+    ``layout.pack`` and ``layout.to_device`` in ``bucketize``."""
     # shard_map needs every bucket's subject count to divide the shard count
     subject_align = len(jax.devices()) if engine == "mesh" else 1
-    rc, ccnt, nnzc = data.row_counts(), data.col_counts(), data.nnz_counts()
-    plan = plan_buckets(rc, ccnt, max_buckets=max_buckets, nnz_counts=nnzc,
-                        sort_by="nnz" if format == "scoo" else "area")
-    shard_balance = None
-    if engine == "mesh" and subject_align > 1:
-        # nnz-balance the subject shards: equal nonzeros per contiguous
-        # shard chunk, not equal subject counts — the quantile planner sorts
-        # members by size, which would put every heavy subject on the last
-        # shard (the straggler the watchdog would then flag forever)
-        naive = plan.shard_imbalance(nnzc, subject_align)
-        plan = plan.balance_for_shards(nnzc, subject_align)
-        shard_balance = {
-            "n_shards": subject_align,
-            "shard_nnz": plan.shard_nnz(nnzc, subject_align),
-            "imbalance_max_over_mean": plan.shard_imbalance(
-                nnzc, subject_align),
-            "imbalance_unbalanced": naive,
-        }
-        print(f"[shard-balance] {subject_align} shards: imbalance "
-              f"{naive:.3f} -> "
-              f"{shard_balance['imbalance_max_over_mean']:.3f} (max/mean nnz)")
-    fmts = route_formats(plan, nnzc, format=format)
+    with span("layout.plan"):
+        rc, ccnt, nnzc = (data.row_counts(), data.col_counts(),
+                          data.nnz_counts())
+        plan = plan_buckets(rc, ccnt, max_buckets=max_buckets,
+                            nnz_counts=nnzc,
+                            sort_by="nnz" if format == "scoo" else "area")
+        shard_balance = None
+        if engine == "mesh" and subject_align > 1:
+            # nnz-balance the subject shards: equal nonzeros per contiguous
+            # shard chunk, not equal subject counts — the quantile planner
+            # sorts members by size, which would put every heavy subject on
+            # the last shard (the straggler the watchdog would then flag
+            # forever)
+            naive = plan.shard_imbalance(nnzc, subject_align)
+            plan = plan.balance_for_shards(nnzc, subject_align)
+            shard_balance = {
+                "n_shards": subject_align,
+                "shard_nnz": plan.shard_nnz(nnzc, subject_align),
+                "imbalance_max_over_mean": plan.shard_imbalance(
+                    nnzc, subject_align),
+                "imbalance_unbalanced": naive,
+            }
+            print(f"[shard-balance] {subject_align} shards: imbalance "
+                  f"{naive:.3f} -> "
+                  f"{shard_balance['imbalance_max_over_mean']:.3f} "
+                  f"(max/mean nnz)")
+    with span("layout.route"):
+        fmts = route_formats(plan, nnzc, format=format)
     bt = bucketize(data, dtype=jnp.float32, subject_align=subject_align,
                    plan=plan, formats=fmts)
     bucket_stats = plan.stats(rc, ccnt, nnzc, formats=fmts)
@@ -132,6 +143,7 @@ def build_buckets(data, *, format: str, engine: str, max_buckets: int):
 
 def main(argv=None) -> dict:
     enable_compile_cache()
+    reset_spans()       # the summary's ``spans`` block covers this run
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="choa", choices=["choa", "movielens", "synthetic"])
     ap.add_argument("--scale", type=float, default=0.002)
@@ -309,6 +321,9 @@ def main(argv=None) -> dict:
         # engine's per-shard nnz balance
         supervisor=supervisor_report,
         shard_balance=shard_balance,
+        # host spans of this run (repro.core.spans): layout.* and
+        # fit.dispatch / fit.sync, each {count, s, self_s}
+        spans=span_totals(),
     )
     if args.json:
         with open(args.json, "w") as f:

@@ -20,7 +20,13 @@ decompose additions: ``supervisor`` — the fault-tolerant fit's
 (retry/restore/rollback counts, straggler chunk ids, checkpoints written,
 resume step, final ridge) or ``None`` for a bare fit — and
 ``shard_balance`` — the nnz-balanced shard planner's before/after
-max-over-mean imbalance under ``engine="mesh"`` or ``None``.
+max-over-mean imbalance under ``engine="mesh"`` or ``None`` — and
+``spans`` — the run's host-span totals,
+:func:`repro.core.spans.span_totals`: ``{name: {"count", "s", "self_s"}}``
+for the device layout (``layout`` and its children ``layout.plan``,
+``layout.route``, ``layout.pack``, ``layout.to_device``) and the fitting
+loop (``fit.dispatch`` around each chunk or step call, including the
+first call's compile; ``fit.sync`` around its one host read).
 """
 from __future__ import annotations
 
