@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.core import Parafac2Options, als_step, bucketize, init_state
 from repro.core.backend import dispatch_tally, get_backend
+from repro.core.spans import launches
 from repro.core.baseline import baseline_mode1, baseline_mode2, baseline_mode3, dense_y
 from repro.sparse import random_irregular
 from benchmarks.common import calibrate, emit, time_call
@@ -173,7 +174,7 @@ def main(argv=None):
         s0 = init_state(bt_cc, opts, seed=0)
         with dispatch_tally() as tally:
             jax.eval_shape(lambda s: als_step(bt_cc, s, opts), s0)
-        per_iter = int(sum(tally.values()))
+        per_iter = launches(tally)
         per_bucket = per_iter / max(len(bt_cc.buckets), 1)
         emit(f"mttkrp/dispatches_per_iter/{bname}", 0.0,
              f"total={per_iter} per_bucket={per_bucket:.1f}")

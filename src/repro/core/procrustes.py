@@ -6,12 +6,20 @@ B_k = X_k V S_k H^T (I_k x R), and Q_k is then exactly the **orthogonal polar
 factor** of B_k. Three batched solvers, trading generality for MXU-friendliness:
 
 * ``polar_svd``          — jnp.linalg.svd of B_k (reference; O(I R^2) but LAPACK-style)
-* ``polar_gram_eigh``    — eigh of the R x R Gram B^T B (default; O(I R^2) matmul
-                           + O(R^3) eigh, batched, TPU-native)
+* ``polar_gram_eigh``    — eigh of the smaller Gram of B (default; O(I R min(I,R))
+                           matmul + O(min(I,R)^3) eigh, batched, TPU-native)
 * ``polar_newton_schulz``— pure-matmul Newton–Schulz iteration (no eigh at all)
 
 All accept B of shape [Kb, I, R] and return Q of the same shape with
-Q^T Q = I_R per subject (rows of padding are zero and stay zero in gram/NS).
+Q^T Q = I_R per subject where B has full column rank (rows of padding are
+zero and stay zero in gram/NS).
+
+The polar factor is a spectral function of the Gram on either side,
+Q = B (B^T B)^{+1/2} = (B B^T)^{+1/2} B, and both Grams have the same nonzero
+eigenvalues. ``polar_gram_eigh`` picks the side from B's static shape: the
+I x I row Gram when I < R (a bucket of short subjects), the R x R column Gram
+otherwise. One cutoff relative to the largest eigenvalue keeps the same
+directions on both sides.
 """
 from __future__ import annotations
 
@@ -19,6 +27,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.spans import _tick
 
 __all__ = ["polar_svd", "polar_gram_eigh", "polar_newton_schulz", "solve_q"]
 
@@ -30,25 +40,56 @@ def polar_svd(B: jax.Array) -> jax.Array:
 
 
 def polar_gram_eigh(B: jax.Array, *, eps: float = 1e-12) -> jax.Array:
-    """Polar factor via eigendecomposition of the R x R Gram matrix.
+    """Polar factor via eigendecomposition of the smaller Gram of B.
 
-    B = Q P with P = (B^T B)^{1/2};  Q = B P^{-1} = B E diag(1/sqrt(lam)) E^T.
+    Column side (I >= R): Q = B (B^T B)^{+1/2} = B E diag(1/sqrt(lam)) E^T.
+    Row side (I < R, read from B's static shape): Q = (B B^T)^{+1/2} B, an
+    I x I eigh in place of an R x R one. Each side runs under its own
+    ``jax.named_scope`` (``gram_rows``, ``gram_cols``) and ticks
+    ``polar.gram_rows`` or ``polar.gram_cols`` in
+    :func:`repro.core.spans.dispatch_tally`.
+
     Rank-deficient directions get a zero inverse root (pseudo-polar), which is
-    the correct limit for padded/empty subjects. A direction counts as
-    rank-deficient below ``max(eps, R * roundoff of B's dtype) * lam_max``:
-    a Gram formed in floating point resolves its eigenvalues no finer, and
-    the inverse root of a noise eigenvalue (a subject with fewer rows than R
-    has R - I of them) would put noise of order sqrt(roundoff) into Q.
+    the correct limit for padded/empty subjects. On both sides a direction
+    counts as rank-deficient below ``max(eps, R * roundoff of B's dtype) *
+    lam_max``, R being B's column count, so both keep the same directions: a
+    Gram formed in floating point resolves its eigenvalues no finer, and the
+    inverse root of a noise eigenvalue (a column Gram of a subject with fewer
+    rows than R has R - I of them) would put noise of order sqrt(roundoff)
+    into Q. A zero (padded) row of B gives a zero row of Q on both sides.
     """
-    R = B.shape[-1]
-    G = jnp.einsum("kir,kil->krl", B, B)                     # [Kb, R, R]
+    I, R = B.shape[-2:]
+    rel_tol = max(eps, R * float(jnp.finfo(B.dtype).eps))
+    if I < R:
+        _tick("polar.gram_rows")
+        return _polar_rows(B, rel_tol)
+    _tick("polar.gram_cols")
+    return _polar_cols(B, rel_tol)
+
+
+def _pinv_root(G: jax.Array, rel_tol: float) -> jax.Array:
+    """Pseudo-inverse square root of a batch of PSD Grams: eigenvalues at or
+    below ``rel_tol * lam_max`` get a zero inverse root."""
     lam, E = jnp.linalg.eigh(G)                               # ascending eigs
     scale = jnp.maximum(lam, 0.0)
     max_lam = jnp.max(scale, axis=-1, keepdims=True)
-    tol = max_lam * max(eps, R * float(jnp.finfo(B.dtype).eps))
+    tol = max_lam * rel_tol
     inv_root = jnp.where(scale > tol, 1.0 / jnp.sqrt(jnp.maximum(scale, tol)), 0.0)
-    P_inv = jnp.einsum("krl,kl,kml->krm", E, inv_root, E)     # E diag E^T
-    return jnp.einsum("kir,krm->kim", B, P_inv)
+    return jnp.einsum("krl,kl,kml->krm", E, inv_root, E)      # E diag E^T
+
+
+@jax.named_scope("gram_rows")
+def _polar_rows(B: jax.Array, rel_tol: float) -> jax.Array:
+    """Q = (B B^T)^{+1/2} B, from the I x I row Gram."""
+    G = jnp.einsum("kir,kjr->kij", B, B)                     # [Kb, I, I]
+    return jnp.einsum("kij,kjr->kir", _pinv_root(G, rel_tol), B)
+
+
+@jax.named_scope("gram_cols")
+def _polar_cols(B: jax.Array, rel_tol: float) -> jax.Array:
+    """Q = B (B^T B)^{+1/2}, from the R x R column Gram."""
+    G = jnp.einsum("kir,kil->krl", B, B)                     # [Kb, R, R]
+    return jnp.einsum("kir,krm->kim", B, _pinv_root(G, rel_tol))
 
 
 def polar_newton_schulz(B: jax.Array, *, iters: int = 12) -> jax.Array:

@@ -5,7 +5,12 @@
     I-/C-sized operands (see :func:`dispatch_tally`). Its stage names are
     the ALS stage names of ``core/parafac2.py::STAGES``, which also name the
     ``jax.named_scope`` of each stage inside the compiled step, so a
-    profiler trace and the tally speak of the same stages.
+    profiler trace and the tally speak of the same stages. A key
+    ``<stage>.<choice>`` counts a trace-time choice made inside a stage,
+    not a launch: ``polar.gram_rows`` and ``polar.gram_cols`` count the
+    buckets whose polar factor eigendecomposes the row or the column Gram
+    (``core/procrustes.py::polar_gram_eigh``). :func:`launches` sums the
+    launches alone.
 
 ``span(name)``
     A host span around a phase of the driver loops: the device layout
@@ -31,7 +36,7 @@ from typing import Dict, Iterator, List, Optional
 
 import jax
 
-__all__ = ["dispatch_tally", "reset_spans", "span", "span_totals"]
+__all__ = ["dispatch_tally", "launches", "reset_spans", "span", "span_totals"]
 
 
 # ---------------------------------------------------------------------------
@@ -53,11 +58,12 @@ def dispatch_tally():
 
         with dispatch_tally() as t:
             jax.eval_shape(lambda s: als_step(data, s, opts), state)
-        per_bucket = sum(t.values()) / len(data.buckets)
+        per_bucket = launches(t) / len(data.buckets)
 
     The staged CC path tallies 5 per bucket per iteration (procrustes_b,
     project, mode1, mode2, ykv); the fused route tallies 4 — the standalone
-    projection pass disappears (``project_bucket`` carries Q).
+    projection pass disappears (``project_bucket`` carries Q). Keys with a
+    dot (``polar.gram_rows``) count choices, not launches.
     """
     global _TALLY
     prev, _TALLY = _TALLY, collections.Counter()
@@ -70,6 +76,12 @@ def dispatch_tally():
 def _tick(stage: str) -> None:
     if _TALLY is not None:
         _TALLY[stage] += 1
+
+
+def launches(tally: collections.Counter) -> int:
+    """The stage launches of a tally: its counts less the ``<stage>.<choice>``
+    keys."""
+    return sum(n for k, n in tally.items() if "." not in k)
 
 
 # ---------------------------------------------------------------------------

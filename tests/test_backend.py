@@ -14,6 +14,7 @@ from repro.core import Parafac2Options, bucketize, fit, init_state, als_step
 from repro.core.backend import (
     AutoBackend, BACKENDS, FusedBackend, JnpBackend, PallasBackend,
     dispatch_tally, get_backend)
+from repro.core.spans import launches
 
 JNP = get_backend("jnp")
 PAL = get_backend("pallas")
@@ -299,7 +300,7 @@ def test_dispatch_tally_per_iteration(backend, per_bucket):
     s0 = init_state(bt, opts, seed=0)
     with dispatch_tally() as tally:
         jax.eval_shape(lambda s: als_step(bt, s, opts), s0)
-    assert sum(tally.values()) / len(bt.buckets) == per_bucket
+    assert launches(tally) / len(bt.buckets) == per_bucket
     if backend == "fused":
         # the separate projection dispatch is gone: Q is carried, Yc never
         # materialized

@@ -1,10 +1,13 @@
 """Batched Procrustes/polar solvers: orthonormality + cross-method agreement."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
-from repro.core.procrustes import polar_gram_eigh, polar_newton_schulz, polar_svd, solve_q
+from repro.core.procrustes import (_polar_cols, _polar_rows, polar_gram_eigh,
+                                   polar_newton_schulz, polar_svd, solve_q)
+from repro.core.spans import dispatch_tally
 
 
 def _rand_b(seed, kb=6, i=20, r=5):
@@ -74,3 +77,89 @@ def test_gram_eigh_rank_deficient_f32_matches_svd():
     ref = np.asarray(polar_svd(jnp.asarray(B)))            # f64
     Q = np.asarray(polar_gram_eigh(jnp.asarray(B, jnp.float32)))
     np.testing.assert_allclose(Q, ref, atol=1e-5)
+
+
+# -- the Gram side: I x I row Gram when I < R, R x R column Gram otherwise --
+
+def _padded_batch(seed, kb, i, r):
+    """A bucket of subjects with 1..i real rows and zero-padded rows below:
+    each real block has singular values in [1, 10], so its polar factor is
+    well conditioned; subject 0 is all padding, subject 1 fills every row."""
+    rng = np.random.default_rng(seed)
+    B = np.zeros((kb, i, r))
+    rows = rng.integers(1, i + 1, kb)
+    rows[0], rows[1] = 0, i
+    for k in range(1, kb):
+        n = rows[k]
+        m = min(n, r)
+        U, _ = np.linalg.qr(rng.standard_normal((n, m)))
+        V, _ = np.linalg.qr(rng.standard_normal((r, m)))
+        B[k, :n] = (U * np.exp(rng.uniform(0.0, np.log(10.0), m))) @ V.T
+    return B.astype(np.float32), rows
+
+
+def _svd_pseudo_polar(B, rel_tol):
+    """Float64 pseudo-polar factor keeping singular values with
+    s^2 > rel_tol * s_max^2, the cutoff ``polar_gram_eigh`` puts on its
+    Gram eigenvalues."""
+    U, s, Vt = np.linalg.svd(np.asarray(B, np.float64), full_matrices=False)
+    keep = s ** 2 > rel_tol * s[:, :1] ** 2
+    return np.einsum("kil,kl,klr->kir", U, keep.astype(np.float64), Vt)
+
+
+_SHAPES = [(3, 8), (8, 40), (24, 40), (40, 40), (168, 40)]
+
+
+@pytest.mark.parametrize("i,r", _SHAPES)
+def test_gram_eigh_f32_matches_f64_svd_pseudo_polar(i, r):
+    B, rows = _padded_batch(i * 100 + r, 32, i, r)
+    ref = _svd_pseudo_polar(B, r * float(np.finfo(np.float32).eps))
+    Q = np.asarray(polar_gram_eigh(jnp.asarray(B)))
+    assert Q.shape == B.shape and Q.dtype == np.float32
+    np.testing.assert_allclose(Q, ref, atol=2e-5)
+    padded = np.arange(i)[None, :] >= rows[:, None]
+    assert np.abs(Q[padded]).max() == 0.0      # padded rows, and subject 0
+    assert np.abs(Q[0]).max() == 0.0
+
+
+@pytest.mark.parametrize("i,r", [s for s in _SHAPES if s[0] < s[1]])
+def test_gram_sides_agree(i, r):
+    """The row side and the column side are one polar factor: the column
+    path run on a batch whose I < R gives what the row path gives."""
+    B, _ = _padded_batch(7 * i + r, 32, i, r)
+    rel_tol = r * float(np.finfo(np.float32).eps)
+    rows = np.asarray(_polar_rows(jnp.asarray(B), rel_tol))
+    cols = np.asarray(_polar_cols(jnp.asarray(B), rel_tol))
+    np.testing.assert_allclose(rows, cols, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(polar_gram_eigh(jnp.asarray(B))),
+                               rows, atol=0)
+
+
+def _eigh_operand_shapes(jaxpr):
+    """Shapes of the operands of every ``eigh`` in a jaxpr and its subjaxprs."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "eigh":
+            shapes.append(tuple(eqn.invars[0].aval.shape))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    shapes += _eigh_operand_shapes(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    shapes += _eigh_operand_shapes(sub)
+    return shapes
+
+
+@pytest.mark.parametrize("i,r,side,gram", [
+    (8, 40, "polar.gram_rows", (5, 8, 8)),
+    (24, 40, "polar.gram_rows", (5, 24, 24)),
+    (40, 40, "polar.gram_cols", (5, 40, 40)),
+    (168, 40, "polar.gram_cols", (5, 40, 40))])
+def test_gram_side_tally_and_eigh_operand(i, r, side, gram):
+    """The side is chosen from B's static shape: one tally tick per call,
+    and the eigh runs on the I x I Gram exactly when I < R."""
+    B = jax.ShapeDtypeStruct((5, i, r), jnp.float32)
+    with dispatch_tally() as tally:
+        jaxpr = jax.make_jaxpr(polar_gram_eigh)(B)
+    assert dict(tally) == {side: 1}
+    assert _eigh_operand_shapes(jaxpr.jaxpr) == [gram]
