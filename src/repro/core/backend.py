@@ -58,11 +58,11 @@ The backend layer is also the single place the ``"subjects"`` logical-axis
 sharding constraints (:func:`repro.dist.sharding.shard`) are applied: every
 Kb-leading input and output passes through :meth:`MttkrpBackend.shard_subjects`
 uniformly, instead of ad-hoc ``shard`` calls scattered through the math. The
-memory-bound :meth:`MttkrpBackend.mode2_scatter` (XLA scatter-add into
-J-space) is a shared stage every backend reuses; :meth:`MttkrpBackend.ykv`
-(the Y_k V product the ALS step computes once per bucket and feeds to the
-mode-1/mode-3 reuse entry points and the fit) dispatches per backend like
-the modes do.
+memory-bound :meth:`MttkrpBackend.mode2_scatter` (the sum into J-space of
+each column's compact rows, :mod:`repro.kernels.segsum`) is a shared stage
+every backend reuses; :meth:`MttkrpBackend.ykv` (the Y_k V product the ALS
+step computes once per bucket and feeds to the mode-1/mode-3 reuse entry
+points and the fit) dispatches per backend like the modes do.
 
 Select via ``Parafac2Options(backend=...)`` or ``--backend`` on the launchers
 and benchmarks. See docs/ARCHITECTURE.md (stage 4½ and the SCOO stage).

@@ -26,7 +26,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import _tick
 from repro.kernels.common import accum_dtype
+from repro.kernels.segsum import sorted_segment_sum
 
 __all__ = [
     "mode1_bucket",
@@ -88,32 +90,39 @@ def mode2_bucket_compact(
 
 
 def mode2_scatter(A: jax.Array, cols: jax.Array, J: int) -> jax.Array:
-    """Scatter-add compact results into M2 [J, R]. Padded entries are zero so
-    scattering them to column id 0 (or any segment) is harmless.
+    """Sum the compact rows A [Kb, C, R] into M2 [J, R] by column id ``cols``
+    [Kb, C]. Padded entries are zero, so their landing in column 0 (or any
+    segment) is harmless; a column with no entries gets exact zeros.
 
-    No XLA scatter-add (scalar-serialized on CPU, ~2.5x the cost of this
-    path at benchmark scale; compiled for v5e it also needs more temporary
-    HBM than this path): the flat column ids are stably sorted, and M2 is a
-    permutation gather + cumsum-diff segment sum over the sorted rows. The
-    sort depends only on the data, but the compiled engine loops still run
-    it every iteration. Under shard_map each shard sorts its own subjects
-    and the partial M2 is psum'd like any other.
+    The flat column ids are stably sorted (``sort``), the rows are gathered
+    into that order (``gather``), and each column's run of sorted rows is
+    summed on its own (``segsum``:
+    :func:`repro.kernels.segsum.sorted_segment_sum`, a Pallas segmented
+    prefix sum on a TPU). Each M2 row is accumulated from its own terms
+    only, so its rounding error is bounded by c * eps * sum|its terms| for
+    a small c, however heavy the columns sorted before it. A difference of
+    one running total over all rows would be bounded by eps times that
+    total instead, and on a cohort with Zipf-popular columns a rare
+    column's row would lose most of its digits. On a TPU no XLA
+    scatter-add: on a v5e it made the whole scatter 9-12% slower at the
+    benchmark cohorts' bucket shapes (``benchmarks/segsum_micro.py``).
+
+    The sort depends only on the data, but the compiled engine loops run it
+    every iteration. Under shard_map each shard sorts its own subjects and
+    the partial M2 is psum'd like any other. Ticks ``scatter.segsum`` in
+    :func:`repro.core.spans.dispatch_tally` once per call.
     """
-    Kb, C, R = A.shape
+    R = A.shape[-1]
+    _tick("scatter.segsum")
     flat_cols = cols.reshape(-1)                               # [Kb*C]
-    flat_A = A.reshape(-1, R)
-    perm = jnp.argsort(flat_cols, stable=True)
-    ends = jnp.searchsorted(flat_cols[perm],
-                            jnp.arange(1, J + 1, dtype=flat_cols.dtype))
-    # accumulate in f64 when x64 is on (canonicalized back to f32 otherwise)
-    # — the running cumsum spans every kept column, so give the partial sums
-    # the wider accumulator when one is available
-    acc = jnp.result_type(A.dtype, jnp.float64)
-    g = flat_A[perm].astype(acc)
-    cs = jnp.concatenate([jnp.zeros((1, R), acc), jnp.cumsum(g, 0)], 0)
-    seg = cs[ends]                                             # [J, R]
-    return jnp.diff(seg, axis=0,
-                    prepend=jnp.zeros((1, R), acc)).astype(A.dtype)
+    with jax.named_scope("sort"):
+        perm = jnp.argsort(flat_cols, stable=True)
+        ids = flat_cols[perm]
+    with jax.named_scope("gather"):
+        g = A.reshape(-1, R)[perm].astype(accum_dtype(A))
+    with jax.named_scope("segsum"):
+        M2 = sorted_segment_sum(g, ids, J)                     # [J, R]
+    return M2.astype(A.dtype)
 
 
 # ---------------------------------------------------------------------------

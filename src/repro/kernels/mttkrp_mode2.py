@@ -1,7 +1,7 @@
 """Pallas TPU kernel — SPARTan mode-2 MTTKRP, compact compute stage.
 
 Computes  A[k] = (Y_k^T H) * W(k,:)  for the kept columns only (paper Fig. 3);
-the J-space scatter-add is a separate memory-bound stage handled by XLA
+the J-space sum is a separate memory-bound stage
 (`spartan.mode2_scatter`). The C x R result per subject stays in VMEM;
 C is tiled for large kept-column counts. H (R x R) is small and replicated to
 every grid step (the paper's "size imbalance" property).

@@ -89,6 +89,30 @@ def test_dispatch_tally_counts_stages_by_their_names(fmt, backend):
     assert tally and {k.split(".")[0] for k in tally} <= set(STAGES)
 
 
+@pytest.mark.parametrize("fmt,backend", [("cc", "jnp"), ("scoo", "auto")])
+def test_scatter_segsum_ticks_once_per_bucket(fmt, backend):
+    bt = bucketize(_cohort(), max_buckets=2, format=fmt, dtype=jnp.float32)
+    opts = Parafac2Options(rank=4, backend=backend, dtype=jnp.float32)
+    s0 = init_state(bt, opts, 0)
+    with dispatch_tally() as tally:
+        jax.eval_shape(lambda s: als_step(bt, s, opts), s0)
+    assert len(bt.buckets) == 2
+    assert tally["scatter.segsum"] == len(bt.buckets)
+
+
+def test_scatter_runs_its_sort_gather_and_segsum_in_their_scopes():
+    """The argsort, the permutation gather and the segment sum each lie in
+    their own scope under ``als/scatter``, which ``scatter_ms`` sums."""
+    ops = _hlo_ops(_chunk("scoo", "auto").compile().as_text())
+    subs = {}
+    for op, n in ops:
+        m = re.search(r"als/scatter/(\w+)/", n)
+        if m:
+            subs.setdefault(m.group(1), set()).add(op)
+    assert {"sort", "gather", "segsum"} <= set(subs)
+    assert "sort" in subs["sort"] and "gather" in subs["gather"]
+
+
 def test_span_totals_count_seconds_and_self_time():
     reset_spans()
     with span("outer"):

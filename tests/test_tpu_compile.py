@@ -12,6 +12,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all import
 this file.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import Parafac2Options, als_step, init_state
 from repro.core.irregular import Bucket, Bucketed
-from repro.kernels import fused, scoo
+from repro.kernels import fused, scoo, segsum
 from repro.kernels.mttkrp_mode1 import mode1_pallas, mode1_reuse_pallas
 from repro.kernels.mttkrp_mode2 import mode2_compact_pallas
 from repro.kernels.mttkrp_mode3 import mode3_pallas, mode3_reuse_pallas
@@ -119,6 +121,15 @@ def _kernel_cases():
              [((K, N), f32), ((K, N), i32), ((K, N), i32), ((K, I, R), f32),
               ((K,), i32)]),
         ]
+    for _, C in GEOMS:
+        # the mode-2 segment sum over a bucket's K * C sorted compact rows,
+        # and over a count that leaves the last block short
+        for n in (K * C, K * C + 200):
+            cases.append((
+                f"segmented_cumsum-N{n}",
+                lambda x, s: segsum.segmented_cumsum_pallas(x, s,
+                                                           interpret=False),
+                [((n, R), f32), ((n,), jnp.bool_)]))
     return cases
 
 
@@ -129,6 +140,22 @@ _CASES = _kernel_cases()
 def test_kernel_compiles_for_v5e(name, one_chip):
     _, fn, shapes = next(c for c in _CASES if c[0] == name)
     assert "tpu_custom_call" in _compile_for_chip(fn, shapes, one_chip)
+
+
+def test_mode2_scatter_kernel_compiles_under_f32_matmuls(one_chip,
+                                                         monkeypatch):
+    """``als_step`` traces under f32 matmul precision; the segment-sum
+    kernel's bf16 matmuls must still compile for the chip there."""
+    from repro.core import spartan
+
+    monkeypatch.setattr(segsum, "_on_tpu", lambda: True)
+    monkeypatch.setattr(segsum, "segmented_cumsum_pallas", functools.partial(
+        segsum.segmented_cumsum_pallas, interpret=False))
+    with jax.default_matmul_precision("float32"):
+        text = _compile_for_chip(
+            lambda a, c: spartan.mode2_scatter(a, c, 1_328),
+            [((K, 128, R), f32), ((K, 128), i32)], one_chip)
+    assert "tpu_custom_call" in text
 
 
 def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
