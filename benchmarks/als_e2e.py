@@ -485,8 +485,7 @@ def _xl_probe(args) -> dict:
     data = random_irregular(n_subjects=16_384, n_cols=16_384, max_rows=1000,
                             avg_nnz_per_subject=2048, seed=args.seed)
     plan = plan_buckets(data.row_counts(), data.col_counts(),
-                        nnz_counts=data.nnz_counts(), max_buckets=4,
-                        sort_by="nnz")
+                        nnz_counts=data.nnz_counts(), max_buckets=4)
     # what CC would have to allocate for the same plan (f32 vals alone)
     cc_bytes = sum(len(mem) * ip * cp * 4
                    for (ip, cp), mem in zip(plan.shapes, plan.members))

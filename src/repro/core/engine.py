@@ -236,7 +236,7 @@ def _compile(fn, data, opts, *, donate: Optional[bool]) -> Callable:
     returns a state-only callable with `data` bound.
 
     Every engine passes the data as a runtime ARGUMENT, like the host loop's
-    ``jax.jit(lambda d, s: als_step(d, s, opts))``: a closed-over dataset
+    jitted step (:func:`repro.core.parafac2.fit`): a closed-over dataset
     would be embedded in the compiled program as constants (gigabytes at
     cohort scale, held on the device twice), and under shard_map it would
     be replicated per shard, double-counting every psum."""

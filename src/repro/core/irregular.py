@@ -46,7 +46,7 @@ import jax.numpy as jnp
 
 from repro.core.spans import span
 from repro.sparse.coo import IrregularCOO
-from repro.sparse.bucketing import BucketPlan, plan_buckets, route_formats
+from repro.sparse.bucketing import BucketPlan, plan_layout, route_formats
 from repro.sparse.bucketing import SCOO_DENSITY_THRESHOLD
 
 __all__ = ["Bucket", "SparseBucket", "Bucketed", "bucketize", "bucket_format",
@@ -374,8 +374,8 @@ def bucketize(
 
     ``format`` picks the per-bucket device layout: ``"cc"`` (dense over kept
     columns — the historical default), ``"scoo"`` (flat sorted COO triplets,
-    O(nnz) algebra; the planner pads *nnz*, not area, and quantile-buckets by
-    nnz), or ``"auto"`` (each bucket routed by its measured density through
+    O(nnz) algebra, each bucket padded to its members' largest nnz), or
+    ``"auto"`` (each bucket routed by its measured density through
     :func:`repro.sparse.bucketing.route_formats`; below ``density_threshold``
     -> SCOO). ``formats`` overrides the routing with an explicit per-bucket
     list (must match ``plan``'s bucket count).
@@ -390,10 +390,10 @@ def bucketize(
     cc = data.col_counts()
     nnzc = data.nnz_counts()
     if plan is None:
-        plan = plan_buckets(
-            rc, cc, max_buckets=max_buckets, row_align=row_align,
-            col_align=col_align, nnz_counts=nnzc, nnz_align=nnz_align,
-            sort_by="nnz" if format == "scoo" else "area")
+        plan, _ = plan_layout(
+            rc, cc, nnzc, format=format, max_buckets=max_buckets,
+            row_align=row_align, col_align=col_align, nnz_align=nnz_align,
+            density_threshold=density_threshold)
     if formats is None:
         formats = route_formats(plan, nnzc, format=format,
                                 density_threshold=density_threshold)

@@ -456,12 +456,17 @@ def fit(
                                   seed=seed, verbose=verbose, state=state)
     if state is None:
         state = init_state(data, opts, seed)
-    step = jax.jit(lambda d, s: als_step(d, s, opts))
+    # one iteration per dispatch, compiled as the body of a loop whose trip
+    # count is a runtime argument: XLA then compiles the step as it does the
+    # scan and while engines' loop bodies (the data-only work hoisted out of
+    # the body), which keeps the engines' trajectories bitwise equal
+    step = jax.jit(lambda d, s, n: jax.lax.fori_loop(
+        0, n, lambda _, c: als_step(d, c, opts), s))
     history: List[float] = []
     prev = -np.inf
     for it in range(max_iters):
         with span("fit.dispatch"):
-            state = step(data, state)
+            state = step(data, state, 1)
         with span("fit.sync"):
             f = float(state.fit)
         history.append(f)

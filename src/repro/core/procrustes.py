@@ -49,6 +49,13 @@ def polar_gram_eigh(B: jax.Array, *, eps: float = 1e-12) -> jax.Array:
     ``polar.gram_rows`` or ``polar.gram_cols`` in
     :func:`repro.core.spans.dispatch_tally`.
 
+    The Gram squares B's condition number, so the eigh's roundoff reaches
+    Q amplified: Q's singular values miss 1 by up to roundoff x cond(B)^2,
+    and the fit, which takes Q^T Q = I, drifts with them. One Newton–Schulz
+    step, Q <- 1.5 Q - 0.5 Q Q^T Q on the Gram's side, squares that miss
+    (singular values 0 and 1 are its fixed points, so the pseudo-polar's
+    zero directions stay zero).
+
     Rank-deficient directions get a zero inverse root (pseudo-polar), which is
     the correct limit for padded/empty subjects. On both sides a direction
     counts as rank-deficient below ``max(eps, R * roundoff of B's dtype) *
@@ -80,16 +87,22 @@ def _pinv_root(G: jax.Array, rel_tol: float) -> jax.Array:
 
 @jax.named_scope("gram_rows")
 def _polar_rows(B: jax.Array, rel_tol: float) -> jax.Array:
-    """Q = (B B^T)^{+1/2} B, from the I x I row Gram."""
+    """Q = (B B^T)^{+1/2} B, from the I x I row Gram, then one
+    Newton–Schulz step on the same side."""
     G = jnp.einsum("kir,kjr->kij", B, B)                     # [Kb, I, I]
-    return jnp.einsum("kij,kjr->kir", _pinv_root(G, rel_tol), B)
+    Q = jnp.einsum("kij,kjr->kir", _pinv_root(G, rel_tol), B)
+    QQt = jnp.einsum("kir,kjr->kij", Q, Q)
+    return 1.5 * Q - 0.5 * jnp.einsum("kij,kjr->kir", QQt, Q)
 
 
 @jax.named_scope("gram_cols")
 def _polar_cols(B: jax.Array, rel_tol: float) -> jax.Array:
-    """Q = B (B^T B)^{+1/2}, from the R x R column Gram."""
+    """Q = B (B^T B)^{+1/2}, from the R x R column Gram, then one
+    Newton–Schulz step on the same side."""
     G = jnp.einsum("kir,kil->krl", B, B)                     # [Kb, R, R]
-    return jnp.einsum("kir,krm->kim", B, _pinv_root(G, rel_tol))
+    Q = jnp.einsum("kir,krm->kim", B, _pinv_root(G, rel_tol))
+    QtQ = jnp.einsum("kir,kil->krl", Q, Q)
+    return 1.5 * Q - 0.5 * jnp.einsum("kir,krl->kil", Q, QtQ)
 
 
 def polar_newton_schulz(B: jax.Array, *, iters: int = 12) -> jax.Array:

@@ -56,7 +56,7 @@ from repro.dist.fault import FaultInjector
 from repro.dist.supervisor import SupervisorConfig, supervised_fit
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.summary import resolved_options, run_summary
-from repro.sparse import plan_buckets, random_irregular, route_formats
+from repro.sparse import plan_layout, random_irregular, route_formats
 
 
 def parse_fail_spec(spec: str) -> dict:
@@ -95,7 +95,7 @@ def load_dataset(name: str, scale: float, seed: int):
 
 @span("layout")
 def build_buckets(data, *, format: str, engine: str, max_buckets: int):
-    """The driver's device layout for ``data``: the quantile bucket plan
+    """The decompose CLI's device layout for ``data``: the bucket plan
     (nnz-balanced over the subject shards under the mesh engine), the
     per-bucket format routing, and ``bucketize``. Returns ``(Bucketed,
     per-bucket stats, shard-balance report or None)``. Runs under the host
@@ -106,14 +106,13 @@ def build_buckets(data, *, format: str, engine: str, max_buckets: int):
     with span("layout.plan"):
         rc, ccnt, nnzc = (data.row_counts(), data.col_counts(),
                           data.nnz_counts())
-        plan = plan_buckets(rc, ccnt, max_buckets=max_buckets,
-                            nnz_counts=nnzc,
-                            sort_by="nnz" if format == "scoo" else "area")
+        plan, _ = plan_layout(rc, ccnt, nnzc, format=format,
+                              max_buckets=max_buckets)
         shard_balance = None
         if engine == "mesh" and subject_align > 1:
             # nnz-balance the subject shards: equal nonzeros per contiguous
-            # shard chunk, not equal subject counts — the quantile planner
-            # sorts members by size, which would put every heavy subject on
+            # shard chunk, not equal subject counts — the planner sorts
+            # members by size, which would put every heavy subject on
             # the last shard (the straggler the watchdog would then flag
             # forever)
             naive = plan.shard_imbalance(nnzc, subject_align)

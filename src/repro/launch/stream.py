@@ -68,7 +68,7 @@ from repro.core.irregular import Bucketed
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.summary import resolved_options, run_summary
 from repro.sparse import (
-    IrregularCOO, SubjectCOO, fixed_plan, plan_buckets, route_formats)
+    IrregularCOO, SubjectCOO, fixed_plan, plan_layout)
 from repro.sparse.bucketing import SCOO_DENSITY_THRESHOLD
 
 __all__ = ["AppendResult", "StreamService", "synthetic_stream",
@@ -336,10 +336,8 @@ class StreamService:
         identical to what ``launch/decompose.py`` would build for the same
         data/format, which is what makes the cold-refit parity exact."""
         rc, ccnt, nnzc = data.row_counts(), data.col_counts(), data.nnz_counts()
-        plan = plan_buckets(rc, ccnt, max_buckets=self.max_buckets,
-                            nnz_counts=nnzc,
-                            sort_by="nnz" if self.fmt == "scoo" else "area")
-        fmts = route_formats(plan, nnzc, format=self.fmt)
+        plan, fmts = plan_layout(rc, ccnt, nnzc, format=self.fmt,
+                                 max_buckets=self.max_buckets)
         return bucketize(data, dtype=self.opts.dtype, plan=plan, formats=fmts)
 
     def _adopt(self, bt: Bucketed, H, V, W) -> None:

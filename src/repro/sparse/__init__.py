@@ -10,6 +10,7 @@ from repro.sparse.bucketing import (
     BucketPlan,
     fixed_plan,
     plan_buckets,
+    plan_layout,
     route_formats,
 )
 
@@ -22,6 +23,7 @@ __all__ = [
     "BucketPlan",
     "fixed_plan",
     "plan_buckets",
+    "plan_layout",
     "route_formats",
     "SCOO_DENSITY_THRESHOLD",
 ]

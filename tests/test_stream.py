@@ -23,8 +23,7 @@ import pytest
 from repro.core import Parafac2Options, bucketize, fit, update_subjects
 from repro.core.nnls import hals_nnls
 from repro.sparse import (
-    IrregularCOO, plan_buckets, random_irregular, random_parafac2,
-    route_formats)
+    IrregularCOO, plan_layout, random_irregular, random_parafac2)
 from repro.launch.stream import (
     StreamService, synthetic_stream, validate_payload)
 
@@ -55,9 +54,7 @@ def _dense(s):
 def _bucketize_like_service(data, opts, fmt):
     """The exact batch-path bucketization StreamService uses for (re)fits."""
     rc, cc, nz = data.row_counts(), data.col_counts(), data.nnz_counts()
-    plan = plan_buckets(rc, cc, max_buckets=4, nnz_counts=nz,
-                        sort_by="nnz" if fmt == "scoo" else "area")
-    fmts = route_formats(plan, nz, format=fmt)
+    plan, fmts = plan_layout(rc, cc, nz, format=fmt, max_buckets=4)
     return bucketize(data, dtype=opts.dtype, plan=plan, formats=fmts)
 
 
