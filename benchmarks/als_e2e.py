@@ -6,7 +6,7 @@ the MTTKRP micro benchmark (`mttkrp_micro.py`) cannot see the per-iteration
 host dispatch + `float(state.fit)` sync the host loop pays, which at small
 ranks IS the wall-clock floor. This benchmark times `iters` ALS iterations
 through each execution engine (host | scan | mesh — repro.core.engine),
-backend (jnp | pallas), device data format (cc | scoo | auto —
+backend (jnp | auto), device data format (cc | scoo | auto —
 repro.core.irregular; SCOO is the O(nnz) sparse path, and the low-density
 ``synthsparse`` dataset is the regime where CC's densified rectangles burn
 ~100x the FLOPs) and constraint route (none | nonneg | nonneg_admm |
@@ -145,13 +145,7 @@ def main(argv=None):
     ap.add_argument("--engines", default="host,scan",
                     help="comma list from host,scan,mesh")
     ap.add_argument("--backends", default="jnp",
-                    help="comma list from jnp,pallas,scoo,fused,auto")
-    ap.add_argument("--fused-namespace", action="store_true",
-                    help="additionally run the compact als_fused grid: "
-                         "pallas/f32 vs fused/f32 vs fused/bf16 on the first "
-                         "dataset, host engine, interleaved repeats — rows "
-                         "als_fused/<ds>/<backend>/<precision> with the gated "
-                         "speedup_vs_pallas ratio")
+                    help="comma list from jnp,auto")
     ap.add_argument("--formats", default="cc",
                     help="comma list from cc,scoo,auto (device data format; "
                          "cc rows keep the historical unsuffixed names)")
@@ -336,9 +330,6 @@ def main(argv=None):
                             results[f"{ds}/{engine}/{backend}/{cname}"
                                     f"{suffix}{csuffix}"] = rec
 
-    if args.fused_namespace:
-        results.update(_fused_cases(args))
-
     overhead = None
     if args.supervised_namespace:
         rows, overhead = _supervised_cases(args)
@@ -359,55 +350,6 @@ def main(argv=None):
             f"FAIL: supervisor overhead {overhead:.3f}x exceeds the "
             f"--overhead-gate {args.overhead_gate:.2f}x budget")
     return results
-
-
-def _fused_cases(args) -> dict:
-    """The ``als_fused`` namespace: the fused megakernel route and bf16
-    compute, each timed against the staged pallas backend on identical data
-    (host engine, the paper's nonneg default). Warm-up first, then the timed
-    repeats interleave round-robin so every ratio compares runs from the same
-    noise window. On CPU the fused rows run the interpret-mode DMA emulation
-    — the recorded speedup_vs_pallas is then a correctness-trajectory metric,
-    not a perf claim (the TPU number is the real one)."""
-    ds = [s.strip() for s in args.datasets.split(",") if s.strip()][0]
-    data = _load(ds, args.scale, args.seed)
-    bt = bucketize(data, max_buckets=4, dtype=jnp.float32)
-    cases = [("pallas", "f32"), ("fused", "f32"), ("fused", "bf16")]
-    prepped = []
-    for backend, precision in cases:
-        opts = Parafac2Options(rank=args.rank,
-                               constraints=CONSTRAINT_CASES["nonneg"],
-                               backend=backend, precision=precision,
-                               engine="host", check_every=args.check_every)
-        run = _make_runner(bt, opts, args.iters)
-        final_fit = float("nan")
-        for _ in range(2):   # compile + warm
-            final_fit = run()
-        prepped.append({"backend": backend, "precision": precision,
-                        "run": run, "final_fit": final_fit, "times": []})
-    for _ in range(args.repeats):
-        for case in prepped:
-            t0 = time.perf_counter()
-            case["final_fit"] = case["run"]()
-            case["times"].append(time.perf_counter() - t0)
-    out = {}
-    pallas_per_iter = None
-    for case in prepped:
-        ts = sorted(case["times"])
-        per_iter = ts[len(ts) // 2] / args.iters
-        rec = {"seconds_per_iter": per_iter,
-               "final_fit": case["final_fit"], "iters": args.iters,
-               "n_subjects": data.n_subjects, "nnz": data.nnz}
-        rel = ""
-        if case["backend"] == "pallas":
-            pallas_per_iter = per_iter
-        elif pallas_per_iter:
-            rec["speedup_vs_pallas_per_iter"] = pallas_per_iter / per_iter
-            rel = f"speedup_vs_pallas={rec['speedup_vs_pallas_per_iter']:.2f}x"
-        emit(f"als_fused/{ds}/{case['backend']}/{case['precision']}",
-             per_iter, f"fit={case['final_fit']:.4f} {rel}".strip())
-        out[f"als_fused/{ds}/{case['backend']}/{case['precision']}"] = rec
-    return out
 
 
 def _supervised_cases(args):

@@ -1,19 +1,19 @@
 """Kernel-level micro-benchmark: per-mode SPARTan MTTKRP vs materialized-KRP
 baseline on identical inputs (the paper's core computational claim).
 
-``--backends jnp,pallas`` times every requested MTTKRP backend side by side
+``--backends jnp,auto`` times every requested MTTKRP backend side by side
 in one invocation (rows ``mttkrp/<mode>/<backend>``), each against the shared
 dense baseline; ``--formats cc,scoo`` adds the device-format axis (rows for
-non-CC formats get a ``/<fmt>`` suffix; SCOO stages run the O(nnz)
-segment-sum route of :mod:`repro.kernels.scoo` through the bucket-level
-backend API). The format axis also times the two formation stages the
+non-CC formats get a ``/<fmt>`` suffix; under ``auto`` SCOO stages run the
+O(nnz) segment-sum route of :mod:`repro.kernels.scoo` through the
+bucket-level backend API). The format axis also times the two formation stages the
 whole-iteration cost is dominated by on sparse data — ``xkv`` (X_k V) and
 ``project`` (Y_k = Q_k^T X_k) — which the mode-level rows never see.
 ``--json PATH`` additionally writes the timings as a JSON artifact (the CI
 perf trajectory, BENCH_mttkrp.json), including a ``dispatches_per_iter``
 block per backend — the bucket-stage dispatch count one full ALS iteration
-costs (staged backends: 5/bucket; the fused megakernel route: 4/bucket, the
-exact-parity fusion floor — see repro.kernels.fused).
+costs (the jnp route: 5/bucket; the fused route, which ``auto`` takes only on
+a TPU: 4/bucket, the exact-parity fusion floor — see repro.kernels.fused).
 """
 from __future__ import annotations
 
@@ -38,11 +38,9 @@ def main(argv=None):
     ap.add_argument("--cols", type=int, default=2000)
     ap.add_argument("--rank", type=int, default=40)
     ap.add_argument("--iters", type=int, default=5)
-    ap.add_argument("--backends", default="jnp,pallas",
+    ap.add_argument("--backends", default="jnp,auto",
                     help="comma list of MTTKRP backends to time side by side "
-                         "(jnp,pallas,scoo,fused,auto)")
-    ap.add_argument("--fused", action="store_true",
-                    help="shorthand: append 'fused' to the backends axis")
+                         "(jnp,auto)")
     ap.add_argument("--formats", default="cc",
                     help="comma list of device formats (cc,scoo); non-CC "
                          "rows get a /<fmt> suffix")
@@ -50,8 +48,6 @@ def main(argv=None):
                     help="write per-mode/backend timings to this JSON file")
     args = ap.parse_args(argv)
     backends = [s.strip() for s in args.backends.split(",") if s.strip()]
-    if args.fused and "fused" not in backends:
-        backends.append("fused")
 
     # geometry mirrors the paper's sparse regime: few active columns (c_k)
     # out of many variables J — that is where the reformulation wins.
@@ -101,7 +97,7 @@ def main(argv=None):
             be = get_backend(bname)
             buckets = bt.buckets
             # per-bucket projected representations (untimed, like Ycs): the
-            # dense route materializes Yc, the scoo backend carries Q
+            # jnp route materializes Yc, the scoo route carries Q
             projs = [be.project_bucket(b, Q) for b, Q in zip(buckets, Qs)]
 
             def run_mode1(V, W):
@@ -151,13 +147,11 @@ def main(argv=None):
             t_x, _ = time_call(jax.jit(run_xkv), V, iters=args.iters)
             emit(f"mttkrp/xkv/{bname}{sfx}", t_x, "")
             results[f"xkv/{bname}{sfx}"] = {"us_per_call": t_x * 1e6}
-            # the scoo backend's project_bucket on SCOO buckets — and the
-            # fused backend's on EVERY bucket — is Q pass-through BY DESIGN
-            # (Yc is never materialized; the cost moves into the fused/
-            # triplet contractions timed above) — a timing row for it would
-            # be a meaningless ~0
-            if not (bname == "fused"
-                    or (fmt == "scoo" and bname in ("scoo", "auto"))):
+            # the scoo route's project_bucket (auto on SCOO buckets) is Q
+            # pass-through BY DESIGN (Yc is never materialized; the cost
+            # moves into the triplet contractions timed above) — a timing
+            # row for it would be a meaningless ~0
+            if not (fmt == "scoo" and bname == "auto"):
                 t_p, _ = time_call(jax.jit(run_project), H, iters=args.iters)
                 emit(f"mttkrp/project/{bname}{sfx}", t_p, "")
                 results[f"project/{bname}{sfx}"] = {"us_per_call": t_p * 1e6}
@@ -167,7 +161,7 @@ def main(argv=None):
 
     # bucket-stage dispatch count per full ALS iteration (ticks fire at
     # trace time, so eval_shape counts one als_step without running it):
-    # staged = 5/bucket, fused = 4/bucket (the exact-parity fusion floor)
+    # jnp route = 5/bucket, fused route = 4/bucket (the exact-parity floor)
     bt_cc = bts.get("cc", bt0)
     for bname in backends:
         opts = Parafac2Options(rank=R, dtype=jnp.float32, backend=bname)

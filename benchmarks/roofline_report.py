@@ -5,11 +5,11 @@ SCOO format (``dryrun.py --parafac2 --format scoo``) carries the O(nnz)
 useful-flops model — its MODEL/HLO column is the sparse path's roofline,
 counting only padded triplets instead of the densified CC rectangles — and
 renders with a ``/scoo`` shape tag. Cells lowered through a non-default
-backend/precision (``--backend fused``, ``--precision bf16``) render with
-``/fused`` / ``@bf16`` tags and fill the AI columns: ``AI(hlo)`` is measured
+backend/precision (``--backend auto``, ``--precision bf16``) render with
+``/auto`` / ``@bf16`` tags and fill the AI columns: ``AI(hlo)`` is measured
 flops per HLO byte accessed, ``AI(model)`` the precision-aware streamed-slab
-model (bf16/f16 slabs move 2 bytes per cell, f32 moves 4; the fused route
-drops the Yc round-trip entirely — see launch/dryrun.py).
+model (bf16/f16 slabs move 2 bytes per cell, f32 moves 4 — see
+launch/dryrun.py).
 """
 from __future__ import annotations
 

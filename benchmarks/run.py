@@ -16,9 +16,9 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="larger problem sizes")
     ap.add_argument("--only", default="", help="comma list: table1,fig5,fig6,fig7,micro")
-    ap.add_argument("--backends", default="jnp,pallas",
+    ap.add_argument("--backends", default="jnp,auto",
                     help="comma list of MTTKRP backends for the micro rows "
-                         "(jnp,pallas side by side by default)")
+                         "(jnp,auto side by side by default)")
     ap.add_argument("--bench-json", default="",
                     help="write the micro per-mode/backend timings to this "
                          "JSON file (CI artifact)")

@@ -1,7 +1,7 @@
 """Paper core: PARAFAC2 + SPARTan MTTKRP on bucketed compressed-column data."""
 from repro.core.irregular import (
-    Bucket, Bucketed, BlockBucket, SparseBucket, bucketize, bucket_format,
-    cc_bucket_like, to_block_bucket, FORMATS, LANE)
+    Bucket, Bucketed, SparseBucket, bucketize, bucket_format,
+    cc_bucket_like, FORMATS)
 from repro.core.backend import MttkrpBackend, get_backend
 from repro.core.compress import (
     CompressedBucket,
@@ -52,13 +52,10 @@ __all__ = [
     "make_als_while",
     "Bucket",
     "Bucketed",
-    "BlockBucket",
     "SparseBucket",
     "bucketize",
     "bucket_format",
-    "to_block_bucket",
     "FORMATS",
-    "LANE",
     "MttkrpBackend",
     "get_backend",
     "Parafac2Options",

@@ -1,39 +1,36 @@
-"""Pluggable MTTKRP compute backends for the SPARTan ALS hot loop.
+"""The MTTKRP router of the SPARTan ALS hot loop: one router, three routes.
 
 The ALS algebra (``core/parafac2.py``) never touches a kernel directly: it
-asks an :class:`MttkrpBackend` for the per-bucket SPARTan contractions
-and the shared stages. Four implementations:
+asks a backend for the per-bucket SPARTan contractions and the shared
+stages. ``Parafac2Options.backend`` / ``--backend`` names one of two:
 
-``jnp``
-    The pure-jnp math in :mod:`repro.core.spartan` — the reference path, exact
-    in f64, used by the algebra tests.
-``pallas``
-    Dispatches through :mod:`repro.kernels.ops` — Mosaic kernels on TPU,
-    ``interpret=True`` emulation elsewhere (a correctness tool, not a fast
-    path off-TPU). Outputs are f32 accumulations; f64 inputs are demoted.
-``scoo``
-    The O(nnz) sparse route (:class:`SparseBackend`): on SCOO buckets
-    (:class:`repro.core.irregular.SparseBucket`) every stage contracts the
-    flat COO triplets directly via :mod:`repro.kernels.scoo` and the
-    projected slices Y_k are NEVER materialized — ``project_bucket`` carries
-    Q itself. CC buckets delegate to ``jnp``.
-``fused``
-    The fused ALS megakernel route (:mod:`repro.kernels.fused`): per CC
-    bucket per iteration, four fused launches stream each subject's slab
-    through VMEM with double-buffered DMA and write only the small
-    [I,R]/[R,R]/[C,R] results — the projected Y_k is NEVER materialized
-    (``project_bucket`` carries Q, like the SCOO route). SCOO buckets
-    delegate to ``scoo``. ``dispatch_tally`` measures the collapse from the
-    staged path's five streaming stage launches to four (four, not one,
-    because the Procrustes eigendecomposition and the H-/V-solves are global
-    sync points — see kernels/fused.py).
 ``auto``
-    Per-bucket dispatch: SCOO buckets take the ``scoo`` native route; CC
-    buckets go to ``fused`` on TPU for kernel-friendly geometry (f32/bf16
-    with R a multiple of 8 and C a multiple of 128 — the MXU sublane/lane
-    quanta the ``col_align=128`` bucketizer default produces), ``pallas``
-    for the array-level CC contractions at the same geometry, and ``jnp``
-    everywhere else, including all CPU/GPU runs.
+    :class:`AutoBackend`, the default. It picks one route per bucket from
+    what it can observe: the bucket's format and dtype, the rank R, C_pad
+    and the platform. The decision is made in :meth:`AutoBackend.route`
+    and nowhere else.
+``jnp``
+    :class:`MttkrpBackend` itself: every bucket on the ``jnp`` route. The
+    reference, exact in f64, used by the algebra tests.
+
+The three routes:
+
+``scoo``
+    :class:`SparseBackend`, every SCOO bucket
+    (:class:`repro.core.irregular.SparseBucket`): each stage contracts the
+    flat COO triplets directly via :mod:`repro.kernels.scoo` (jnp gathers
+    and segment sums), and the projected slices Y_k are never materialized
+    (``project_bucket`` carries Q itself).
+``fused``
+    :class:`FusedBackend`, a CC bucket on a TPU at f32/bf16 with R a
+    multiple of 8 and C_pad a multiple of 128 (the MXU sublane/lane quanta
+    the ``col_align=128`` bucketizer default produces): four fused Pallas
+    slab passes per bucket per iteration (:mod:`repro.kernels.fused`), Y_k
+    never materialized. Four dispatches, not one, because the Procrustes
+    eigendecomposition and the H-/V-solves are global sync points.
+``jnp``
+    :class:`MttkrpBackend`, every other CC bucket: the :mod:`repro.core.
+    spartan` math on the materialized compact Yc [Kb, R, C_pad].
 
 Every backend also takes a ``precision`` knob ("f32" | "bf16" | "f16",
 ``Parafac2Options.precision`` / ``get_backend(name, precision)``): below
@@ -45,14 +42,12 @@ inputs loses bits, and the streamed HBM bytes halve. ``precision="f32"``
 is bitwise-identical to the historical paths.
 
 Two API levels. The *bucket-level* stages (``xkv_bucket`` /
-``project_bucket`` / ``ykv_bucket`` / ``mode{1,2,3}_bucket``) are what
-``als_step`` calls: they take the bucket itself, so a backend can pick a
-representation per device format — this is where the CC-vs-SCOO split lives,
-and why a mixed-format ``Bucketed`` (``bucketize(format="auto")``) runs
-every engine/backend/constraint combination unchanged. The *array-level*
-methods (``mode1`` / ``mode2_compact`` / ``mode3`` / ``ykv`` on explicit
-Yc/Vg arrays) remain the CC contraction contract the kernel parity tests
-and micro benchmarks exercise.
+``procrustes_b_bucket`` / ``project_bucket`` / ``ykv_bucket`` /
+``mode1_xkv_bucket`` / ``mode{1,2,3}_bucket``) are what ``als_step`` calls:
+they take the bucket itself, which is where the router reads its format.
+The *array-level* methods (``mode1`` / ``mode2_compact`` / ``mode3`` /
+``ykv`` on explicit Yc/Vg arrays) are the jnp CC contraction contract, and
+every route reduces its [Kb, R, R] Y_k V products through them.
 
 The backend layer is also the single place the ``"subjects"`` logical-axis
 sharding constraints (:func:`repro.dist.sharding.shard`) are applied: every
@@ -60,17 +55,11 @@ Kb-leading input and output passes through :meth:`MttkrpBackend.shard_subjects`
 uniformly, instead of ad-hoc ``shard`` calls scattered through the math. The
 memory-bound :meth:`MttkrpBackend.mode2_scatter` (the sum into J-space of
 each column's compact rows, :mod:`repro.kernels.segsum`) is a shared stage
-every backend reuses; :meth:`MttkrpBackend.ykv` (the Y_k V product the ALS
-step computes once per bucket and feeds to the mode-1/mode-3 reuse entry
-points and the fit) dispatches per backend like the modes do.
-
-Select via ``Parafac2Options(backend=...)`` or ``--backend`` on the launchers
-and benchmarks. See docs/ARCHITECTURE.md (stage 4½ and the SCOO stage).
+every route reuses. See docs/ARCHITECTURE.md (stage 4½ and the SCOO stage).
 """
 from __future__ import annotations
 
-import abc
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,13 +68,12 @@ from repro.core import spartan
 from repro.core.irregular import SparseBucket
 from repro.core.spans import _tick, dispatch_tally
 from repro.dist.sharding import shard
+from repro.kernels import fused, scoo
 from repro.kernels.common import (PRECISIONS, accum_dtype, compute_cast,
                                   fold_subject_mask)
 
 __all__ = [
     "MttkrpBackend",
-    "JnpBackend",
-    "PallasBackend",
     "SparseBackend",
     "FusedBackend",
     "AutoBackend",
@@ -95,14 +83,17 @@ __all__ = [
 ]
 
 
-class MttkrpBackend(abc.ABC):
-    """The three SPARTan MTTKRP contractions, per bucket.
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+class MttkrpBackend:
+    """The three SPARTan MTTKRP contractions, per bucket: the ``jnp`` route,
+    and the base the other routes override stage by stage.
 
     Per-bucket shapes (Kb subjects, C kept-cols padded, rank R):
       Yc [Kb, R, C] compressed slices; Vg [Kb, C, R] gathered V rows;
       Wb [Kb, R] W rows; masks 1.0 = real, 0.0 = padding.
-    Subclasses implement ``_mode1`` / ``_mode2_compact`` / ``_mode3``; the
-    public methods add the uniform subject-axis sharding constraints.
 
     ``precision`` ("f32" default) below f32 stages the large streamed
     operands half-width via :func:`repro.kernels.common.compute_cast` while
@@ -110,7 +101,7 @@ class MttkrpBackend(abc.ABC):
     identical to the unconfigured backend.
     """
 
-    name: str = "?"
+    name: str = "jnp"
 
     def __init__(self, precision: str = "f32"):
         if precision not in PRECISIONS:
@@ -125,8 +116,8 @@ class MttkrpBackend(abc.ABC):
         return compute_cast(x, self.precision)
 
     def route(self, b, R: int) -> str:
-        """Name of the implementation that runs bucket ``b``'s slab-streaming
-        stages at rank ``R`` (the per-bucket route the drivers report)."""
+        """Name of the route that runs bucket ``b``'s slab-streaming stages
+        at rank ``R`` (the per-bucket route the drivers report)."""
         return self.name
 
     # -- uniform sharding ---------------------------------------------------
@@ -147,11 +138,10 @@ class MttkrpBackend(abc.ABC):
     mode2_scatter = staticmethod(spartan.mode2_scatter)
 
     # -- bucket-level stages (the als_step contract) ------------------------
-    # These take the bucket itself so an implementation can pick a per-format
-    # representation. The dense route below (CC buckets, and SCOO buckets
-    # under the jnp/pallas backends, whose SparseBucket.project is an O(nnz)
-    # segment-sum into the same compact Yc layout) materializes Yc [Kb,R,C];
-    # SparseBackend overrides carry Q instead and never build Yc.
+    # These take the bucket itself so a route can pick its representation.
+    # The jnp route materializes Yc [Kb,R,C] (on a SCOO bucket through
+    # SparseBucket.project, an O(nnz) segment sum into the same compact
+    # layout); the SCOO and fused routes carry Q instead and never build Yc.
 
     def xkv_bucket(self, b, V: jax.Array,
                    Vg: Optional[jax.Array] = None) -> jax.Array:
@@ -168,7 +158,7 @@ class MttkrpBackend(abc.ABC):
                             V: jax.Array, Vg: Optional[jax.Array] = None):
         """Step-1 pair for one bucket: (XkV [Kb,I,R], B [Kb,I,R]) with
         B_k = (X_k V * w_k) H^T — the Procrustes input. The staged default
-        is xkv + a small einsum; the fused backend forms both in one slab
+        is xkv + a small einsum; the fused route forms both in one slab
         pass."""
         _tick("procrustes_b")
         XkV = self.xkv_bucket(b, V, Vg)
@@ -179,8 +169,8 @@ class MttkrpBackend(abc.ABC):
                          Wb: jax.Array) -> jax.Array:
         """Partial M1 [R,R] via the mode-1 reuse identity
         Y_k V = Q_k^T (X_k V) — no slab pass, but the [Kb,I,R] operands
-        stream. The fused backend reduces M1 in the same dispatch that
-        forms the per-subject YkV, which is never written back."""
+        stream. The fused route reduces M1 in the same dispatch that forms
+        the per-subject YkV, which is never written back."""
         _tick("mode1")
         YkV = jnp.einsum("kir,kil->krl", Q, XkV)
         return self.mode1(None, None, Wb, b.subject_mask, YkV=YkV)
@@ -197,7 +187,7 @@ class MttkrpBackend(abc.ABC):
 
     def project_bucket(self, b, Q: jax.Array):
         """Per-bucket projected representation consumed by the *_bucket
-        stages below: the compact Yc [Kb, R, C] on the dense route (staged
+        stages below: the compact Yc [Kb, R, C] on the jnp route (staged
         half-width when ``precision`` is below f32)."""
         _tick("project")
         if self.precision != "f32" and not isinstance(b, SparseBucket):
@@ -233,32 +223,25 @@ class MttkrpBackend(abc.ABC):
     # -- per-bucket contractions --------------------------------------------
     def mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None) -> jax.Array:
         """Partial M1 [R, R] = sum_k (Y_k V) * W(k,:). With ``YkV`` cached
-        (mode1_reuse), Vg may be None and the gather+matmul is skipped."""
+        (mode1_reuse), Yc and Vg may be None and the gather+matmul is
+        skipped."""
         Yc, Vg, Wb, subject_mask, YkV = map(
             self.shard_subjects, (Yc, Vg, Wb, subject_mask, YkV))
-        return self._mode1(Yc, Vg, Wb, subject_mask, YkV=YkV)
+        return spartan.mode1_bucket(Yc, Vg, Wb, subject_mask, YkV=YkV)
 
     def mode2_compact(self, Yc, H, Wb, col_mask, subject_mask) -> jax.Array:
         """Compact A [Kb, C, R] = (Y_k^T H) * W(k,:); masked rows are 0."""
         Yc, Wb, col_mask, subject_mask = map(
             self.shard_subjects, (Yc, Wb, col_mask, subject_mask))
-        return self.shard_subjects(
-            self._mode2_compact(Yc, H, Wb, col_mask, subject_mask))
+        return self.shard_subjects(spartan.mode2_bucket_compact(
+            Yc, H, Wb, col_mask, subject_mask))
 
     def mode3(self, Yc, Vg, H, subject_mask, *, YkV=None) -> jax.Array:
         """Per-subject M3 rows [Kb, R] = coldot(H, Y_k V)."""
         Yc, Vg, subject_mask, YkV = map(
             self.shard_subjects, (Yc, Vg, subject_mask, YkV))
-        return self.shard_subjects(self._mode3(Yc, Vg, H, subject_mask, YkV=YkV))
-
-    @abc.abstractmethod
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None) -> jax.Array: ...
-
-    @abc.abstractmethod
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask) -> jax.Array: ...
-
-    @abc.abstractmethod
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None) -> jax.Array: ...
+        return self.shard_subjects(
+            spartan.mode3_bucket(Yc, Vg, H, subject_mask, YkV=YkV))
 
     # -- whole-tensor helpers (the one callsite shape per mode) -------------
     def mttkrp_mode1(self, buckets: Sequence, Ycs: Sequence[jax.Array],
@@ -289,140 +272,33 @@ class MttkrpBackend(abc.ABC):
         return M3
 
 
-class JnpBackend(MttkrpBackend):
-    """The :mod:`repro.core.spartan` math — today's numerics, exactly."""
-
-    name = "jnp"
-
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None):
-        return spartan.mode1_bucket(Yc, Vg, Wb, subject_mask, YkV=YkV)
-
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask):
-        return spartan.mode2_bucket_compact(Yc, H, Wb, col_mask, subject_mask)
-
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None):
-        return spartan.mode3_bucket(Yc, Vg, H, subject_mask, YkV=YkV)
-
-
-class PallasBackend(MttkrpBackend):
-    """Routes through the Pallas kernels (:mod:`repro.kernels.ops`).
-
-    Mosaic on TPU; interpret mode elsewhere. Kernel accumulators are f32, so
-    outputs come back f32 regardless of input dtype; f64 inputs are demoted
-    to f32 on the way in (use ``jnp`` for f64 algebra).
-    """
-
-    name = "pallas"
-
-    @staticmethod
-    def _k32(x: Optional[jax.Array]) -> Optional[jax.Array]:
-        if x is not None and x.dtype == jnp.float64:
-            return x.astype(jnp.float32)
-        return x
-
-    def ykv(self, Yc, Vg):
-        from repro.kernels import ops
-        return ops.ykv(self._k32(Yc), self._k32(Vg))
-
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None):
-        from repro.kernels import ops
-        return ops.mttkrp_mode1(
-            self._k32(Yc), self._k32(Vg), self._k32(Wb),
-            subject_mask=self._k32(subject_mask), YkV=self._k32(YkV))
-
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask):
-        from repro.kernels import ops
-        return ops.mttkrp_mode2_compact(
-            self._k32(Yc), self._k32(H), self._k32(Wb),
-            col_mask=self._k32(col_mask), subject_mask=self._k32(subject_mask))
-
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None):
-        from repro.kernels import ops
-        return ops.mttkrp_mode3(
-            self._k32(Yc), self._k32(Vg), self._k32(H),
-            subject_mask=self._k32(subject_mask), YkV=self._k32(YkV))
-
-    # SCOO buckets: the Pallas one-hot/scalar-prefetch segment-sum kernels
-    # produce X_k V and the compact Yc (kernels/scoo.py); the per-stage CC
-    # kernels then consume Yc exactly as for a CC bucket.
-    def xkv_bucket(self, b, V, Vg=None):
-        if isinstance(b, SparseBucket):
-            from repro.kernels import scoo
-            Vg = b.gather_v(V) if Vg is None else Vg
-            return self.shard_subjects(scoo.xk_times_v(
-                self._pc(self._k32(b.vals)), b.rows, b.lcols,
-                self._pc(self._k32(Vg)), b.i_pad,
-                nnz_counts=b.nnz_counts, use_pallas=True))
-        return super().xkv_bucket(b, V, Vg)
-
-    def project_bucket(self, b, Q):
-        if isinstance(b, SparseBucket):
-            from repro.kernels import scoo
-            _tick("project")
-            return self.shard_subjects(scoo.project(
-                self._pc(self._k32(b.vals)), b.rows, b.lcols,
-                self._k32(Q), b.c_pad,
-                nnz_counts=b.nnz_counts, use_pallas=True))
-        return super().project_bucket(b, Q)
-
-
 class SparseBackend(MttkrpBackend):
-    """The O(nnz) SCOO-native route (:mod:`repro.kernels.scoo`).
+    """The ``scoo`` route: the O(nnz) stages of a SCOO bucket
+    (:mod:`repro.kernels.scoo`).
 
-    On SCOO buckets the projected slices are never materialized:
-    ``project_bucket`` returns Q itself and every downstream stage contracts
-    the flat COO triplets directly (gather + segment-sum / outer-product
-    accumulation). CC buckets — present in a mixed-format Bucketed from
-    ``bucketize(format="auto")`` — delegate to the inner dense backend
-    (``jnp`` by default), as do the array-level CC contraction methods.
+    The projected slices are never materialized: ``project_bucket`` returns
+    Q itself and every downstream stage contracts the flat COO triplets
+    directly (gather + segment-sum / outer-product accumulation). X_k V and
+    the mode-1 reduction from it are the base stages: X_k V is
+    ``SparseBucket.xk_times_v``, and Y_k V = Q_k^T (X_k V) is reduced by
+    jnp, like mode-3's.
     """
 
     name = "scoo"
 
-    def __init__(self, inner: Optional[MttkrpBackend] = None,
-                 precision: str = "f32"):
-        super().__init__(precision)
-        self._inner = inner if inner is not None else JnpBackend(precision)
-
-    def route(self, b, R: int) -> str:
-        if isinstance(b, SparseBucket):
-            return self.name
-        return self._inner.route(b, R)
-
-    # -- array-level CC contract: delegate wholesale ------------------------
-    def ykv(self, Yc, Vg):
-        return self._inner.ykv(Yc, Vg)
-
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None):
-        return self._inner._mode1(Yc, Vg, Wb, subject_mask, YkV=YkV)
-
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask):
-        return self._inner._mode2_compact(Yc, H, Wb, col_mask, subject_mask)
-
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None):
-        return self._inner._mode3(Yc, Vg, H, subject_mask, YkV=YkV)
-
-    # -- bucket-level stages: SCOO-native, Yc-free --------------------------
     def _ykv_native(self, b: SparseBucket, Q, V):
-        from repro.kernels import scoo
         return scoo.ykv_scoo(self._pc(b.vals), b.rows, b.lcols,
                              self.shard_subjects(Q),
                              self._pc(b.gather_v(V)))
 
     def project_bucket(self, b, Q):
-        if not isinstance(b, SparseBucket):
-            return self._inner.project_bucket(b, Q)
         return self.shard_subjects(Q)   # carry Q; Yc is never built
 
     def ykv_bucket(self, b, proj, V):
-        if not isinstance(b, SparseBucket):
-            return self._inner.ykv_bucket(b, proj, V)
         _tick("ykv")
         return self._ykv_native(b, proj, V)
 
     def mode1_bucket(self, b, proj, Wb, V=None, *, YkV=None):
-        if not isinstance(b, SparseBucket):
-            return self._inner.mode1_bucket(b, proj, Wb, V, YkV=YkV)
         if YkV is None:
             _tick("mode1")
             YkV = self._ykv_native(b, proj, V)
@@ -431,9 +307,6 @@ class SparseBackend(MttkrpBackend):
         return self.mode1(None, None, Wb, b.subject_mask, YkV=YkV)
 
     def mode2_bucket(self, b, proj, H, Wb):
-        if not isinstance(b, SparseBucket):
-            return self._inner.mode2_bucket(b, proj, H, Wb)
-        from repro.kernels import scoo
         _tick("mode2")
         Q, Wb, col_mask, smask = map(
             self.shard_subjects, (proj, Wb, b.col_mask, b.subject_mask))
@@ -442,8 +315,6 @@ class SparseBackend(MttkrpBackend):
             cperm=b.cperm, col_ends=b.col_ends))
 
     def mode3_bucket(self, b, proj, H, V=None, *, YkV=None):
-        if not isinstance(b, SparseBucket):
-            return self._inner.mode3_bucket(b, proj, H, V, YkV=YkV)
         if YkV is None:
             _tick("mode3")
             YkV = self._ykv_native(b, proj, V)
@@ -451,57 +322,27 @@ class SparseBackend(MttkrpBackend):
 
 
 class FusedBackend(MttkrpBackend):
-    """The fused ALS megakernel route (:mod:`repro.kernels.fused`).
+    """The ``fused`` route: the fused ALS stages of a CC bucket
+    (:mod:`repro.kernels.fused`).
 
-    On CC buckets the four per-iteration streaming launches each pull the
-    subject's [I_pad, C_pad] slab through VMEM with double-buffered DMA and
-    write only the small results back; the projected slices are never
-    materialized — ``project_bucket`` carries Q itself, exactly like the
-    SCOO-native route (so the ``als_step`` contract is unchanged). SCOO
-    buckets delegate wholesale to :class:`SparseBackend`; the array-level
-    CC contraction methods (explicit Yc in hand) delegate to ``jnp``.
+    The four per-iteration streaming launches each pull the subject's
+    [I_pad, C_pad] slab through VMEM with double-buffered DMA and write only
+    the small results back; the projected slices are never materialized —
+    ``project_bucket`` carries Q itself, exactly like the SCOO route (so the
+    ``als_step`` contract is unchanged).
 
-    Unlike :class:`PallasBackend` there is no f64 demotion: f64 inputs
-    accumulate f64 (``accum_dtype``), which the interpret-mode parity tests
-    rely on. Real TPUs reject f64 Mosaic kernels — ``AutoBackend._fused_ok``
-    gates the automatic route to f32/bf16 there.
+    f64 inputs accumulate f64 (``accum_dtype``), which the interpret-mode
+    parity tests rely on. Real TPUs reject f64 Mosaic kernels —
+    ``AutoBackend._fused_ok`` gives this route only f32/bf16 buckets there.
     """
 
     name = "fused"
 
-    def __init__(self, precision: str = "f32"):
-        super().__init__(precision)
-        self._jnp = JnpBackend(precision)
-        self._sparse = SparseBackend(inner=self._jnp, precision=precision)
-
-    def route(self, b, R: int) -> str:
-        if isinstance(b, SparseBucket):
-            return self._sparse.route(b, R)
-        return self.name
-
     @staticmethod
     def _interp() -> bool:
-        from repro.kernels import fused
         return fused._interpret()
 
-    # -- array-level CC contract: delegate to jnp ---------------------------
-    def ykv(self, Yc, Vg):
-        return self._jnp.ykv(Yc, Vg)
-
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None):
-        return self._jnp._mode1(Yc, Vg, Wb, subject_mask, YkV=YkV)
-
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask):
-        return self._jnp._mode2_compact(Yc, H, Wb, col_mask, subject_mask)
-
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None):
-        return self._jnp._mode3(Yc, Vg, H, subject_mask, YkV=YkV)
-
-    # -- bucket-level stages: the four fused launches -----------------------
     def procrustes_b_bucket(self, b, H, Wb, V, Vg=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.procrustes_b_bucket(b, H, Wb, V, Vg)
-        from repro.kernels import fused
         _tick("procrustes_b")
         Vg = b.gather_v(V) if Vg is None else Vg
         XkV, B = fused.fused_procrustes_b(
@@ -509,36 +350,25 @@ class FusedBackend(MttkrpBackend):
         return self.shard_subjects(XkV), self.shard_subjects(B)
 
     def project_bucket(self, b, Q):
-        if isinstance(b, SparseBucket):
-            return self._sparse.project_bucket(b, Q)
         return self.shard_subjects(Q)   # carry Q; Yc is never built
 
     def mode1_xkv_bucket(self, b, Q, XkV, Wb):
-        from repro.kernels import fused
         _tick("mode1")
         Wb = fold_subject_mask(Wb, b.subject_mask)
         return fused.fused_mode1_xkv(Q, XkV, Wb, interpret=self._interp())
 
     def ykv_bucket(self, b, proj, V):
-        if isinstance(b, SparseBucket):
-            return self._sparse.ykv_bucket(b, proj, V)
-        from repro.kernels import fused
         _tick("ykv")
         return self.shard_subjects(fused.fused_ykv(
             self._pc(b.vals), proj, self._pc(b.gather_v(V)),
             interpret=self._interp()))
 
     def mode1_bucket(self, b, proj, Wb, V=None, *, YkV=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode1_bucket(b, proj, Wb, V, YkV=YkV)
         if YkV is None:
             YkV = self.ykv_bucket(b, proj, V)
         return self.mode1(None, None, Wb, b.subject_mask, YkV=YkV)
 
     def mode2_bucket(self, b, proj, H, Wb):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode2_bucket(b, proj, H, Wb)
-        from repro.kernels import fused
         _tick("mode2")
         Q, Wb_m, cm = map(self.shard_subjects,
                           (proj, fold_subject_mask(Wb, b.subject_mask),
@@ -547,8 +377,6 @@ class FusedBackend(MttkrpBackend):
             self._pc(b.vals), Q, H, Wb_m, cm, interpret=self._interp()))
 
     def mode3_bucket(self, b, proj, H, V=None, *, YkV=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode3_bucket(b, proj, H, V, YkV=YkV)
         if YkV is None:
             YkV = self.ykv_bucket(b, proj, V)
         # YkV in hand, mode-3 is the shared [R,R] coldot — no slab pass left
@@ -556,146 +384,69 @@ class FusedBackend(MttkrpBackend):
 
 
 class AutoBackend(MttkrpBackend):
-    """Per-platform, per-bucket dispatch between jnp, pallas, and scoo.
+    """The router: each bucket-level stage runs on the route
+    :meth:`route` names for its bucket.
 
-    The decision is made at trace time from static bucket types and
-    shapes/dtypes, so under jit each bucket compiles against exactly one
-    implementation. SCOO buckets always take the O(nnz) native route
-    (:class:`SparseBackend` — the format was chosen *because* the bucket is
-    sparse, so the dense kernels are never the right answer for it); CC
-    buckets the kernels handle poorly (odd R/C, f64, non-TPU platforms)
-    fall back to jnp.
+    The decision is made at trace time from the static bucket type, dtype
+    and shapes, the rank and the platform, so under jit each bucket
+    compiles against exactly one route, and every stage of an iteration
+    makes the same decision — the projected representation (Q on the SCOO
+    and fused routes, Yc on the jnp route) stays coherent across
+    ``project_bucket`` and its consumers. The array-level contract and the
+    whole-tensor helpers are the inherited jnp ones.
     """
 
     name = "auto"
 
     def __init__(self, precision: str = "f32"):
         super().__init__(precision)
-        self._jnp = JnpBackend(precision)
-        self._pallas = PallasBackend(precision)
-        self._sparse = SparseBackend(inner=self._jnp, precision=precision)
-        self._fused = FusedBackend(precision)
+        self._routes = {be.name: be for be in (
+            MttkrpBackend(precision), SparseBackend(precision),
+            FusedBackend(precision))}
 
-    def _fused_ok(self, b, R: int) -> bool:
-        """Route a CC bucket through the fused megakernel stages: TPU,
-        f32/bf16 (Mosaic rejects f64), and MXU-quantized geometry. The
-        predicate is a function of static bucket shape/dtype and R only, so
-        every stage of an iteration makes the SAME call — the projected
-        representation (Q on the fused route, Yc on the staged one) must
-        stay coherent across ``project_bucket`` and its consumers."""
-        return (not isinstance(b, SparseBucket)
-                and jax.default_backend() == "tpu"
+    @staticmethod
+    def _fused_ok(b, R: int) -> bool:
+        """A CC bucket takes the fused route on a TPU, at f32/bf16 (Mosaic
+        rejects f64), with MXU-quantized geometry."""
+        return (not isinstance(b, SparseBucket) and _on_tpu()
                 and b.vals.dtype != jnp.float64
                 and R % 8 == 0 and b.c_pad % 128 == 0)
 
     def route(self, b, R: int) -> str:
         if isinstance(b, SparseBucket):
-            return self._sparse.route(b, R)
-        if self._fused_ok(b, R):
-            return self._fused.route(b, R)
-        return self._jnp.route(b, R)
+            return "scoo"
+        return "fused" if self._fused_ok(b, R) else "jnp"
 
-    # -- bucket-level: SCOO -> native sparse; friendly CC on TPU -> fused ---
+    def _route(self, b, R: int) -> MttkrpBackend:
+        return self._routes[self.route(b, R)]
+
     def xkv_bucket(self, b, V, Vg=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.xkv_bucket(b, V, Vg)
-        return super().xkv_bucket(b, V, Vg)
+        return self._route(b, V.shape[-1]).xkv_bucket(b, V, Vg)
 
     def procrustes_b_bucket(self, b, H, Wb, V, Vg=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.procrustes_b_bucket(b, H, Wb, V, Vg)
-        if self._fused_ok(b, H.shape[0]):
-            return self._fused.procrustes_b_bucket(b, H, Wb, V, Vg)
-        return super().procrustes_b_bucket(b, H, Wb, V, Vg)
+        return self._route(b, H.shape[0]).procrustes_b_bucket(b, H, Wb, V, Vg)
 
     def mode1_xkv_bucket(self, b, Q, XkV, Wb):
-        if not isinstance(b, SparseBucket) and self._fused_ok(b, Q.shape[-1]):
-            return self._fused.mode1_xkv_bucket(b, Q, XkV, Wb)
-        return super().mode1_xkv_bucket(b, Q, XkV, Wb)
+        return self._route(b, Q.shape[-1]).mode1_xkv_bucket(b, Q, XkV, Wb)
 
     def project_bucket(self, b, Q):
-        if isinstance(b, SparseBucket):
-            return self._sparse.project_bucket(b, Q)
-        if self._fused_ok(b, Q.shape[-1]):
-            return self._fused.project_bucket(b, Q)
-        return super().project_bucket(b, Q)
+        return self._route(b, Q.shape[-1]).project_bucket(b, Q)
 
     def ykv_bucket(self, b, proj, V):
-        if isinstance(b, SparseBucket):
-            return self._sparse.ykv_bucket(b, proj, V)
-        if self._fused_ok(b, V.shape[-1]):
-            return self._fused.ykv_bucket(b, proj, V)
-        return super().ykv_bucket(b, proj, V)
+        return self._route(b, V.shape[-1]).ykv_bucket(b, proj, V)
 
     def mode1_bucket(self, b, proj, Wb, V=None, *, YkV=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode1_bucket(b, proj, Wb, V, YkV=YkV)
-        if self._fused_ok(b, Wb.shape[-1]):
-            return self._fused.mode1_bucket(b, proj, Wb, V, YkV=YkV)
-        return super().mode1_bucket(b, proj, Wb, V, YkV=YkV)
+        return self._route(b, Wb.shape[-1]).mode1_bucket(b, proj, Wb, V,
+                                                         YkV=YkV)
 
     def mode2_bucket(self, b, proj, H, Wb):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode2_bucket(b, proj, H, Wb)
-        if self._fused_ok(b, H.shape[0]):
-            return self._fused.mode2_bucket(b, proj, H, Wb)
-        return super().mode2_bucket(b, proj, H, Wb)
+        return self._route(b, H.shape[0]).mode2_bucket(b, proj, H, Wb)
 
     def mode3_bucket(self, b, proj, H, V=None, *, YkV=None):
-        if isinstance(b, SparseBucket):
-            return self._sparse.mode3_bucket(b, proj, H, V, YkV=YkV)
-        if self._fused_ok(b, H.shape[0]):
-            return self._fused.mode3_bucket(b, proj, H, V, YkV=YkV)
-        return super().mode3_bucket(b, proj, H, V, YkV=YkV)
-
-    @staticmethod
-    def _platform_ok(probe: Optional[jax.Array]) -> bool:
-        return (probe is not None and jax.default_backend() == "tpu"
-                and probe.dtype != jnp.float64)
-
-    @classmethod
-    def _kernel_friendly(cls, probe: Optional[jax.Array]) -> bool:
-        """Full C-contraction kernels: want R on the sublane quantum and the
-        kept-column count C on the lane quantum (col_align=128 default)."""
-        if not cls._platform_ok(probe):
-            return False
-        R, C = probe.shape[-2], probe.shape[-1]
-        return R % 8 == 0 and C % 128 == 0
-
-    @classmethod
-    def _reuse_friendly(cls, YkV: Optional[jax.Array]) -> bool:
-        """YkV-cached kernels only touch [Kb,R,R] tiles (VPU reductions), so
-        only the sublane quantum matters — Mosaic lane-pads the small R."""
-        if not cls._platform_ok(YkV):
-            return False
-        return YkV.shape[-1] % 8 == 0
-
-    def _pick(self, probe, *, reuse: bool = False) -> MttkrpBackend:
-        ok = self._reuse_friendly(probe) if reuse else self._kernel_friendly(probe)
-        return self._pallas if ok else self._jnp
-
-    def ykv(self, Yc, Vg):
-        return self._pick(Yc).ykv(Yc, Vg)
-
-    def _mode1(self, Yc, Vg, Wb, subject_mask, *, YkV=None):
-        if YkV is not None:
-            return self._pick(YkV, reuse=True)._mode1(
-                Yc, Vg, Wb, subject_mask, YkV=YkV)
-        return self._pick(Yc)._mode1(Yc, Vg, Wb, subject_mask, YkV=None)
-
-    def _mode2_compact(self, Yc, H, Wb, col_mask, subject_mask):
-        return self._pick(Yc)._mode2_compact(Yc, H, Wb, col_mask, subject_mask)
-
-    def _mode3(self, Yc, Vg, H, subject_mask, *, YkV=None):
-        if YkV is not None:
-            return self._pick(YkV, reuse=True)._mode3(
-                Yc, Vg, H, subject_mask, YkV=YkV)
-        return self._pick(Yc)._mode3(Yc, Vg, H, subject_mask, YkV=None)
+        return self._route(b, H.shape[0]).mode3_bucket(b, proj, H, V, YkV=YkV)
 
 
-BACKENDS = {"jnp": JnpBackend(), "pallas": PallasBackend(),
-            "scoo": SparseBackend(), "fused": FusedBackend(),
-            "auto": AutoBackend()}
+BACKENDS = {"jnp": MttkrpBackend(), "auto": AutoBackend()}
 
 # configured (non-f32 precision) instances, cached per (name, precision) so
 # repeated get_backend calls hand jit the SAME backend object (stable tracing)
@@ -703,11 +454,11 @@ _CONFIGURED: Dict[Tuple[str, str], MttkrpBackend] = {}
 
 
 def get_backend(name, precision: Optional[str] = None) -> MttkrpBackend:
-    """Resolve a backend by name ("jnp" | "pallas" | "scoo" | "fused" |
-    "auto") or pass an :class:`MttkrpBackend` instance through unchanged.
-    ``precision`` (None/"f32" default) returns a configured instance that
-    stages streamed operands at that compute precision (see the class docs);
-    the f32 singletons in ``BACKENDS`` are untouched."""
+    """Resolve a backend by name ("jnp" | "auto") or pass an
+    :class:`MttkrpBackend` instance through unchanged. ``precision``
+    (None/"f32" default) returns a configured instance that stages streamed
+    operands at that compute precision (see the class docs); the f32
+    singletons in ``BACKENDS`` are untouched."""
     if isinstance(name, MttkrpBackend):
         return name
     if name not in BACKENDS:

@@ -1,5 +1,5 @@
-"""CP-ALS pieces: Gram utilities, column normalization, and a standalone
-dense CP-ALS (used as a reference implementation and by tests).
+"""CP-ALS pieces shared by the PARAFAC2 step: Gram utilities and column
+normalization.
 
 The PARAFAC2 inner step runs exactly ONE CP-ALS iteration on the intermediate
 tensor Y (Kiers et al.) — that iteration lives in repro.core.parafac2 and uses
@@ -7,14 +7,12 @@ the SPARTan MTTKRPs; here we keep the shared algebra.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.nnls import hals_nnls, ridge_solve
-
-__all__ = ["normalize_columns", "cp_gram", "cp_als_dense", "factor_update"]
+__all__ = ["normalize_columns", "cp_gram"]
 
 
 def normalize_columns(X: jax.Array, *, eps: float = 1e-12) -> Tuple[jax.Array, jax.Array]:
@@ -32,54 +30,3 @@ def cp_gram(*factors: jax.Array) -> jax.Array:
         G = FtF if G is None else G * FtF
     return G
 
-
-def factor_update(M: jax.Array, gram: jax.Array, prev: jax.Array, *,
-                  nonneg: bool, nnls_sweeps: int = 5) -> jax.Array:
-    """One ALS factor update from its MTTKRP M and Gram matrix."""
-    if nonneg:
-        return hals_nnls(M, gram, prev, sweeps=nnls_sweeps)
-    return ridge_solve(M, gram)
-
-
-class CPState(NamedTuple):
-    U: jax.Array
-    V: jax.Array
-    W: jax.Array
-    lam: jax.Array
-
-
-def cp_als_dense(
-    X: jax.Array,
-    rank: int,
-    *,
-    iters: int = 50,
-    nonneg: bool = False,
-    seed: int = 0,
-    dtype=jnp.float32,
-) -> CPState:
-    """Plain dense CP-ALS on an I x J x K array (reference / tests only)."""
-    I, J, K = X.shape
-    k0, k1 = jax.random.split(jax.random.PRNGKey(seed))
-    init = jax.random.uniform if nonneg else jax.random.normal
-    U = init(k0, (I, rank), dtype)
-    V = init(k1, (J, rank), dtype)
-    W = jnp.ones((K, rank), dtype)
-    X1 = X.reshape(I, J * K)                       # mode-1 unfolding (i, j*k)
-    X2 = jnp.transpose(X, (1, 0, 2)).reshape(J, I * K)
-    X3 = jnp.transpose(X, (2, 0, 1)).reshape(K, I * J)
-
-    def kr(A, B):  # Khatri-Rao
-        return (A[:, None, :] * B[None, :, :]).reshape(-1, A.shape[1])
-
-    def body(state, _):
-        U, V, W = state
-        U = factor_update(X1 @ kr(W, V), cp_gram(W, V), U, nonneg=nonneg)
-        U, _ = normalize_columns(U)
-        V = factor_update(X2 @ kr(W, U), cp_gram(W, U), V, nonneg=nonneg)
-        V, _ = normalize_columns(V)
-        W = factor_update(X3 @ kr(V, U), cp_gram(V, U), W, nonneg=nonneg)
-        return (U, V, W), None
-
-    (U, V, W), _ = jax.lax.scan(body, (U, V, W), None, length=iters)
-    W, lam = normalize_columns(W)
-    return CPState(U=U, V=V, W=W, lam=lam)

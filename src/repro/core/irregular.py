@@ -1,6 +1,6 @@
 """Device-side irregular-tensor formats.
 
-Three formats, all static-shape (XLA) and bucketed (see repro.sparse.bucketing):
+Two formats, both static-shape (XLA) and bucketed (see repro.sparse.bucketing):
 
 * **CC (compressed columns)** — each subject slice X_k (I_k x J) is stored
   *dense over its nonzero columns*: ``vals[k] in R^{I_pad x C_pad}`` plus the
@@ -20,10 +20,6 @@ Three formats, all static-shape (XLA) and bucketed (see repro.sparse.bucketing):
   is the format for genuinely sparse buckets (EHR-like ~1% intra-slice
   density), where CC's densified rectangle burns ~100x the FLOPs and HBM.
 
-* **BCC (block-compressed columns)** — CC with column indices quantized
-  to 128-wide blocks of J; this is the Pallas-kernel format (scalar-prefetch
-  block gathers). Conversion CC -> BCC is provided.
-
 ``bucketize(format=...)`` picks per bucket: ``"cc"`` / ``"scoo"`` force one
 format, ``"auto"`` routes each bucket by its measured density (nonzeros over
 the densified CC cell count) through :func:`repro.sparse.bucketing.
@@ -36,8 +32,7 @@ where these formats sit in the end-to-end data flow.
 from __future__ import annotations
 
 import dataclasses
-import warnings
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -50,9 +45,7 @@ from repro.sparse.bucketing import BucketPlan, plan_layout, route_formats
 from repro.sparse.bucketing import SCOO_DENSITY_THRESHOLD
 
 __all__ = ["Bucket", "SparseBucket", "Bucketed", "bucketize", "bucket_format",
-           "cc_bucket_like", "FORMATS", "LANE"]
-
-LANE = 128  # TPU lane width; BCC column-block quantum
+           "cc_bucket_like", "FORMATS"]
 
 FORMATS = ("cc", "scoo", "auto")  # bucketize(format=...) choices
 
@@ -120,16 +113,6 @@ class Bucket:
         if Vg is None:
             Vg = self.gather_v(V)
         return jnp.einsum("kic,kcr->kir", self.vals, Vg, preferred_element_type=self.vals.dtype)
-
-    def xk_times_v_bcc(self, bcc: "BlockBucket", V: jax.Array) -> jax.Array:
-        """X_k V through the Pallas BCC scalar-prefetch kernel (TPU path;
-        interpret=True off-TPU). V is zero-padded to a LANE multiple."""
-        from repro.kernels import ops
-
-        J, R = V.shape
-        J_pad = ((J + LANE - 1) // LANE) * LANE
-        V_pad = jnp.zeros((J_pad, R), V.dtype).at[:J].set(V) if J_pad != J else V
-        return ops.gather_matmul(bcc.vals, bcc.blk_ids, V_pad).astype(self.vals.dtype)
 
     def project(self, Q: jax.Array) -> jax.Array:
         """Y_k = Q_k^T X_k in CC format: [Kb, R, C_pad]; shares self.cols.
@@ -293,8 +276,7 @@ class SparseBucket:
 
 
 def bucket_format(b) -> str:
-    """Device-format tag of a bucket: "cc" | "scoo" (BCC buckets are a
-    kernel-side conversion, never stored in a Bucketed)."""
+    """Device-format tag of a bucket: "cc" | "scoo"."""
     return getattr(b, "format", "cc")
 
 
@@ -493,99 +475,3 @@ def bucketize(
         norm_sq=data.frobenius_sq(),
     )
 
-
-# ---------------------------------------------------------------------------
-# BCC: block-compressed columns (Pallas kernel layout)
-# ---------------------------------------------------------------------------
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass(frozen=True)
-class BlockBucket:
-    """BCC layout: columns quantized to LANE-wide blocks of J.
-
-    vals:     f[Kb, I_pad, NB, LANE]  dense values per kept column-block
-    blk_ids:  i32[Kb, NB]             global block index (j // LANE) (pad: 0)
-    blk_mask: f[Kb, NB]               1.0 for real blocks
-    """
-
-    vals: jax.Array
-    blk_ids: jax.Array
-    blk_mask: jax.Array
-    subject_ids: jax.Array
-    subject_mask: jax.Array
-
-    def tree_flatten(self):
-        return (self.vals, self.blk_ids, self.blk_mask, self.subject_ids, self.subject_mask), None
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children)
-
-    @property
-    def kb(self):
-        return self.vals.shape[0]
-
-    @property
-    def i_pad(self):
-        return self.vals.shape[1]
-
-    @property
-    def n_blocks(self):
-        return self.vals.shape[2]
-
-
-def to_block_bucket(b: Bucket, J: int, *, max_blocks: Optional[int] = None,
-                    allow_truncate: bool = False) -> BlockBucket:
-    """Host-side CC -> BCC conversion (column ids quantized to LANE blocks).
-
-    ``max_blocks`` caps the per-subject block count; column-blocks beyond the
-    cap DROP their nonzeros. That is data loss, so by default it raises
-    ``ValueError`` with the dropped-nonzero count; pass
-    ``allow_truncate=True`` to accept the loss (a ``UserWarning`` with the
-    same count is emitted instead).
-    """
-    vals = np.asarray(b.vals)
-    cols = np.asarray(b.cols)
-    cmask = np.asarray(b.col_mask) > 0
-    kb, i_pad, _ = vals.shape
-    per_subject_blocks = []
-    for k in range(kb):
-        kept = cols[k][cmask[k]]
-        per_subject_blocks.append(np.unique(kept // LANE) if kept.size else np.zeros((0,), np.int64))
-    nb = max((blk.size for blk in per_subject_blocks), default=1)
-    nb = max(nb, 1)
-    if max_blocks is not None:
-        nb = min(nb, max_blocks)
-    out_vals = np.zeros((kb, i_pad, nb, LANE), dtype=vals.dtype)
-    blk_ids = np.zeros((kb, nb), dtype=np.int32)
-    blk_mask = np.zeros((kb, nb), dtype=vals.dtype)
-    dropped_nnz = 0
-    for k in range(kb):
-        blocks = per_subject_blocks[k][:nb]
-        pos = {int(bid): i for i, bid in enumerate(blocks)}
-        blk_ids[k, : blocks.size] = blocks
-        blk_mask[k, : blocks.size] = 1.0
-        kept_idx = np.nonzero(cmask[k])[0]
-        for ci in kept_idx:
-            gcol = int(cols[k, ci])
-            bslot = pos.get(gcol // LANE)
-            if bslot is None:
-                # column-block truncated by max_blocks: its nonzeros are lost
-                dropped_nnz += int(np.count_nonzero(vals[k, :, ci]))
-                continue
-            out_vals[k, :, bslot, gcol % LANE] = vals[k, :, ci]
-    if dropped_nnz:
-        msg = (f"to_block_bucket(max_blocks={max_blocks}) truncated "
-               f"{dropped_nnz} nonzeros (column-blocks beyond the cap); "
-               f"raise max_blocks or pass allow_truncate=True to accept "
-               f"the data loss")
-        if not allow_truncate:
-            raise ValueError(msg)
-        warnings.warn(msg, UserWarning, stacklevel=2)
-    return BlockBucket(
-        vals=jnp.asarray(out_vals),
-        blk_ids=jnp.asarray(blk_ids),
-        blk_mask=jnp.asarray(blk_mask),
-        subject_ids=b.subject_ids,
-        subject_mask=b.subject_mask,
-    )

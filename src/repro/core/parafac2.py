@@ -19,12 +19,12 @@ the leading bucket axis (the "subjects" rule in :mod:`repro.dist.sharding`;
 ``launch/dryrun.py::run_parafac2_cell`` lowers this step on a production
 mesh). ``mode1_reuse=True`` enables the beyond-paper optimization
 Y_k V = Q_k^T (X_k V) (cached from step 1). The three MTTKRPs dispatch
-through a pluggable compute backend (``opts.backend``: "jnp" | "pallas" |
-"scoo" | "auto" — see :mod:`repro.core.backend`), so the same ALS algebra
-runs the pure-jnp SPARTan math, the Pallas TPU kernels, or the O(nnz)
-SCOO-native segment-sum route — per bucket, since a ``bucketize(
-format="auto")`` dataset mixes CC and SCOO buckets. See docs/ARCHITECTURE.md
-(stages 3-5 and the SCOO stage) for the full data flow and sharding story.
+through the MTTKRP router (``opts.backend``: "auto" | "jnp" — see
+:mod:`repro.core.backend`), which runs each bucket on one route: the O(nnz)
+SCOO segment sums, the fused Pallas kernels of a CC bucket on a TPU, or the
+pure-jnp SPARTan math — per bucket, since a ``bucketize(format="auto")``
+dataset mixes CC and SCOO buckets. See docs/ARCHITECTURE.md (stages 3-5 and
+the SCOO stage) for the full data flow and sharding story.
 """
 from __future__ import annotations
 
@@ -102,15 +102,11 @@ class Parafac2Options:
     # retry after repeated numerical-health rollbacks.
     ridge: float = 0.0
     dtype: Any = jnp.float32
-    # MTTKRP compute backend: "jnp" (pure-jnp spartan math, exact reference),
-    # "pallas" (TPU kernels; interpret-mode emulation off-TPU), "scoo" (the
-    # O(nnz) SCOO-native route on SparseBucket data, jnp on CC buckets), or
-    # "pallas" (TPU kernels; interpret-mode emulation off-TPU), "scoo" (the
-    # O(nnz) SCOO-native route on SparseBucket data, jnp on CC buckets),
-    # "fused" (the fused ALS megakernel stages — four double-buffered slab
-    # passes per bucket per iteration, Y_k never materialized), or "auto"
-    # (scoo for SCOO buckets; fused on TPU for kernel-friendly CC bucket
-    # geometry, jnp otherwise). See repro.core.backend.
+    # MTTKRP backend: "auto" (each bucket on one route: SCOO buckets on the
+    # O(nnz) segment sums, CC buckets on the fused Pallas kernels on a TPU
+    # at kernel-friendly geometry, jnp otherwise) or "jnp" (the pure-jnp
+    # spartan math on every bucket, the exact reference). See
+    # repro.core.backend.
     backend: str = "auto"
     # Compute precision for the streamed operands: "f32" (default — bitwise
     # the historical behaviour), "bf16" or "f16" (slab values staged
@@ -257,7 +253,7 @@ def _procrustes_project(
 
     ``proj`` is the backend's per-bucket projected representation
     (:meth:`MttkrpBackend.project_bucket`): the compact Yc [Kb, R, C] on the
-    dense route, Q itself on the SCOO-native and fused routes (where Y_k is
+    jnp route, Q itself on the SCOO and fused routes (where Y_k is
     never materialized). ``als_step`` only ever hands it back to the same
     backend.
     """
@@ -266,7 +262,7 @@ def _procrustes_project(
         Vg = b.gather_v(V)                                   # [Kb, C, R]
         Wb = _w_rows(W, b, i)                                # [Kb, R]
         # B_k = X_k V S_k H^T  == (XkV * w_k) @ H^T — one fused slab pass on
-        # the fused route, xkv + a small einsum on the staged ones
+        # the fused route, xkv + a small einsum on the others
         XkV, B = be.procrustes_b_bucket(b, H, Wb, V, Vg)     # [Kb, I, R] x2
     with jax.named_scope("polar"):
         Q = solve_q(B, opts.procrustes)                      # [Kb, I, R]
@@ -331,7 +327,7 @@ def als_step(
             Wb = _w_rows(W, b, i)
             if opts.mode1_reuse:
                 # Y_k V = Q_k^T (X_k V): skip the gather+matmul on sparse
-                # data (fused backends reduce M1 in the same dispatch that
+                # data (the fused route reduces M1 in the same dispatch that
                 # forms YkV)
                 M1 = M1 + be.mode1_xkv_bucket(b, Q, XkV, Wb)
             else:

@@ -8,7 +8,7 @@ rule in repro.dist.sharding) they lower to all-reduces, which is the paper's
 "sum partial results in parallel".
 
 This module is pure math: the functions here are the ``jnp`` implementation
-behind :class:`repro.core.backend.JnpBackend`. Backend selection, the
+behind :class:`repro.core.backend.MttkrpBackend`. Route selection, the
 whole-tensor per-mode helpers, and the uniform subject-axis sharding
 constraints all live in :mod:`repro.core.backend` — the one layer the ALS
 driver talks to. See docs/ARCHITECTURE.md for the end-to-end data flow.

@@ -1,8 +1,7 @@
-"""Pallas TPU kernels for the SPARTan MTTKRP hot spots (+ jnp oracles).
+"""Pallas TPU kernels and jnp contractions for the SPARTan ALS hot spots.
 
-``ops``  — public jit'd wrappers (interpret=True off-TPU)
-``ref``  — pure-jnp oracles (the correctness contract)
+``fused``   the four fused slab-pass Pallas kernels of the CC fused route
+``scoo``    the O(nnz) jnp gathers and segment sums of the SCOO route
+``segsum``  the mode-2 segment sum (a Pallas segmented prefix sum on a TPU)
+``sketch``  the randomized range-finder sketch of the compression stage
 """
-from repro.kernels import ops, ref
-
-__all__ = ["ops", "ref"]

@@ -39,9 +39,9 @@ I' ≈ r) — and on launch/round-trip overhead everywhere; with
 ``precision="bf16"`` the streamed slab bytes halve again while every dot
 still accumulates in f32 (``preferred_element_type`` = ``accum_dtype``).
 
-All wrappers accept f32/f64 (f64 accumulates f64 — unlike ``PallasBackend``
-there is no silent demotion; Mosaic rejects f64 on real TPUs, but the fused
-route is gated to f32/bf16 there by ``AutoBackend._fused_ok``) and bf16/f16
+All wrappers accept f32/f64 (f64 accumulates f64, with no silent
+demotion; Mosaic rejects f64 on real TPUs, but the fused route is gated to
+f32/bf16 there by ``AutoBackend._fused_ok``) and bf16/f16
 inputs (accumulate f32). ``interpret=True`` runs everywhere via the Pallas
 interpreter — the CI parity path on CPU.
 

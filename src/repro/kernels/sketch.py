@@ -8,8 +8,8 @@ for:
 
   1. **sketch**   Y_k = X_k Ω with a shared Gaussian test matrix Ω [J, S]:
      exactly :meth:`Bucket.xk_times_v` — a gather of Ω's kept-column rows
-     plus one tall-skinny [I_pad, C_pad] x [C_pad, S] matmul per subject, the
-     MXU-friendly shape ``kernels/gather_matmul.py`` targets. On SCOO buckets
+     plus one tall-skinny [I_pad, C_pad] x [C_pad, S] matmul per subject, an
+     MXU-friendly shape. On SCOO buckets
      the same call routes through the O(nnz) segment-sum kernels
      (:mod:`repro.kernels.scoo`), so sparse buckets are sketched WITHOUT ever
      densifying — the "SCOO-aware sketch".

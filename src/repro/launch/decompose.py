@@ -162,11 +162,11 @@ def main(argv=None) -> dict:
                          "randomized cores (rank r, default 2*rank; "
                          "oversampling p; q power iterations), runs the core "
                          "ALS, and expands exactly at the end")
-    ap.add_argument("--backend", default="auto",
-                    choices=["jnp", "pallas", "scoo", "fused", "auto"],
-                    help="MTTKRP compute backend for the ALS hot loop "
-                         "(see repro.core.backend; 'fused' runs the fused "
-                         "ALS megakernel stages — Y_k never materialized)")
+    ap.add_argument("--backend", default="auto", choices=["jnp", "auto"],
+                    help="MTTKRP backend for the ALS hot loop: 'auto' runs "
+                         "each bucket on its route (SCOO segment sums, the "
+                         "fused Pallas kernels on a TPU, or jnp), 'jnp' the "
+                         "plain jnp reference (see repro.core.backend)")
     ap.add_argument("--precision", default="f32",
                     choices=["f32", "bf16", "f16"],
                     help="compute precision for the streamed operands: "

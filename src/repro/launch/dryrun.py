@@ -437,7 +437,7 @@ def run_parafac2_cell(name: str, mesh: Mesh, mesh_name: str, hw=TPU_V5E,
         rec.update(terms)
         rec["t_memory_hlo"] = terms["t_memory"]
         rec["t_memory"] = rec["bytes_per_device"] / hw.hbm_bw
-        # flops per HLO byte accessed — the fused backend's whole point is
+        # flops per HLO byte accessed — the fused route's whole point is
         # raising this (Y_k never round-trips HBM between stages; bf16/f16
         # precision additionally halves every streamed slab byte)
         rec["arithmetic_intensity"] = (
@@ -461,19 +461,15 @@ def run_parafac2_cell(name: str, mesh: Mesh, mesh_name: str, hw=TPU_V5E,
         rec["model_flops_per_device"] = useful
         rec["useful_fraction"] = useful / terms["hlo_flops"] if terms["hlo_flops"] else 0.0
         # model-side streamed-slab traffic per iteration, precision-aware
-        # (bf16/f16 slabs move 2 bytes/cell, f32 moves 4). The staged route
-        # reads the vals slab twice (X_k V, projection) and round-trips the
-        # compact Yc three more times (write + mode-2 + ykv reads); the
-        # fused route re-reads vals three times and never materializes Yc —
-        # the arithmetic-intensity gap the megakernel exists for.
+        # (bf16/f16 slabs move 2 bytes/cell, f32 moves 4). The jnp route
+        # (every CC bucket off a TPU, where this lowers) reads the vals slab
+        # twice (X_k V, projection) and round-trips the compact Yc three
+        # more times (write + mode-2 + ykv reads).
         if format != "scoo":
             val_b = 2                       # parafac2_specs lowers bf16 vals
             slab_b = 2 if precision in ("bf16", "f16") else 4
             yc_cells = sum(kb * R * cp for kb, ip, cp, npad in geom)
-            if backend == "fused":
-                streamed = 3.0 * cells * val_b
-            else:
-                streamed = 2.0 * cells * val_b + 3.0 * yc_cells * slab_b
+            streamed = 2.0 * cells * val_b + 3.0 * yc_cells * slab_b
             rec["model_streamed_bytes_per_device"] = streamed / n_chips
             rec["model_arithmetic_intensity"] = (
                 useful / (streamed / n_chips) if streamed else 0.0)
@@ -506,11 +502,10 @@ def main(argv=None):
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
     ap.add_argument("--out", default=os.path.normpath(RESULTS_PATH))
     ap.add_argument("--parafac2", action="store_true", help="also run paper-workload cells")
-    ap.add_argument("--backend", default="jnp",
-                    choices=["jnp", "pallas", "scoo", "fused", "auto"],
-                    help="MTTKRP backend for the PARAFAC2 cells (the host "
-                         "placeholder mesh lowers pallas/fused in interpret "
-                         "mode)")
+    ap.add_argument("--backend", default="jnp", choices=["jnp", "auto"],
+                    help="MTTKRP backend for the PARAFAC2 cells (on the host "
+                         "placeholder mesh 'auto' runs SCOO buckets on their "
+                         "segment sums and CC buckets on jnp)")
     ap.add_argument("--precision", default="f32",
                     choices=["f32", "bf16", "f16"],
                     help="compute precision for the PARAFAC2 cells: bf16/f16 "

@@ -640,8 +640,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--constraint", default="", metavar="SPECS",
                     help="per-mode factor constraints (as in decompose.py); "
                          f"registered: {', '.join(available_constraints())}")
-    ap.add_argument("--backend", default="auto",
-                    choices=["jnp", "pallas", "scoo", "auto"])
+    ap.add_argument("--backend", default="auto", choices=["jnp", "auto"],
+                    help="MTTKRP backend: 'auto' routes each bucket, 'jnp' "
+                         "is the plain jnp reference (see repro.core.backend)")
     ap.add_argument("--format", default="auto", choices=["cc", "scoo", "auto"])
     ap.add_argument("--engine", default="host", choices=["host", "scan", "mesh"],
                     help="engine for the warm fit and refits")

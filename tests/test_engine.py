@@ -2,8 +2,9 @@
 
 The scan engine takes the data as an argument exactly like the host loop's jit,
 so its trajectory is bitwise host's; the mesh engine compiles under shard_map
-(different fusion), so it gets a small epsilon. Both jnp and pallas MTTKRP
-backends are covered (pallas in interpret mode on CPU — tiny cases only).
+(different fusion), so it gets a small epsilon. Both the jnp route and the
+fused Pallas route are covered (the fused kernels in interpret mode on CPU —
+tiny cases only).
 """
 import os
 import subprocess
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 from repro.data import choa_like
 from repro.sparse import random_parafac2
 from repro.core import ENGINES, Parafac2Options, bucketize, fit, init_state
+from repro.core import backend as backend_mod
 from repro.core import engine as als_engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -71,15 +73,19 @@ def test_mesh_bucketed_w_matches_host(choa_bt):
 
 
 @pytest.mark.parametrize("engine", ["scan", "mesh"])
-def test_engine_parity_pallas_backend(engine):
-    """Same-engine parity with the pallas backend (interpret mode on CPU —
-    keep it tiny). f32: the kernels accumulate in f32."""
+def test_engine_parity_pallas_backend(engine, monkeypatch):
+    """Engine parity on the fused Pallas route: the router's platform test
+    answers as on a TPU, so every bucket takes the fused kernels (interpret
+    mode on CPU — keep it tiny). f32: the kernels accumulate in f32."""
+    monkeypatch.setattr(backend_mod, "_on_tpu", lambda: True)
     data, _ = random_parafac2(n_subjects=12, n_cols=24, max_rows=12, rank=3,
                               density=1.0, seed=3)
     bt = bucketize(data, max_buckets=1, dtype=jnp.float32)
-    _, hh = _traj(bt, "host", backend="pallas", iters=4, dtype=jnp.float32)
-    _, he = _traj(bt, engine, backend="pallas", check_every=2, iters=4,
-                  dtype=jnp.float32)
+    assert {backend_mod.get_backend("auto").route(b, 8)
+            for b in bt.buckets} == {"fused"}
+    kw = dict(backend="auto", iters=4, rank=8, dtype=jnp.float32)
+    _, hh = _traj(bt, "host", **kw)
+    _, he = _traj(bt, engine, check_every=2, **kw)
     np.testing.assert_allclose(he, hh, rtol=0, atol=1e-5)
 
 

@@ -1,11 +1,10 @@
-"""CC/BCC format correctness: round-trips, bucketing invariants."""
+"""CC/SCOO format correctness: round-trips, bucketing invariants."""
 import numpy as np
-import pytest
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
 from repro.sparse import random_irregular, plan_buckets, from_dense_slices
-from repro.core import bucketize, to_block_bucket, LANE
+from repro.core import bucketize
 
 
 def test_cc_roundtrip_dense():
@@ -55,51 +54,12 @@ def test_property_bucket_partition(n, max_buckets, seed):
     assert 0.0 <= waste < 1.0
 
 
-def test_bcc_matches_cc_product():
-    """BCC X_k V must equal CC X_k V (the kernel-format conversion is lossless
-    when max_blocks is not truncating)."""
-    data = random_irregular(n_subjects=6, n_cols=300, max_rows=10,
-                            avg_nnz_per_subject=40, seed=2)
-    bt = bucketize(data, max_buckets=1, dtype=jnp.float64)
-    b = bt.buckets[0]
-    R = 4
-    rng = np.random.default_rng(0)
-    V = jnp.asarray(rng.standard_normal((data.n_cols, R)))
-    ref = b.xk_times_v(V)
-    bb = to_block_bucket(b, data.n_cols)
-    # BCC product: sum over blocks of vals[k,:,b,:] @ V[blk*LANE:(blk+1)*LANE]
-    J_pad = ((data.n_cols + LANE - 1) // LANE) * LANE
-    V_pad = jnp.zeros((J_pad, R), V.dtype).at[: data.n_cols].set(V)
-    V_blocks = V_pad.reshape(-1, LANE, R)
-    Vg = V_blocks[bb.blk_ids] * bb.blk_mask[..., None, None]   # [Kb, NB, LANE, R]
-    out = jnp.einsum("kinl,knlr->kir", bb.vals, Vg)
-    np.testing.assert_allclose(out, ref, atol=1e-10)
-
-
 def test_from_dense_slices():
     rng = np.random.default_rng(5)
     slices = [rng.random((4, 6)) * (rng.random((4, 6)) < 0.5) for _ in range(3)]
     data = from_dense_slices(slices)
     for s, X in zip(data.subjects, slices):
         np.testing.assert_allclose(s.to_dense(), X)
-
-
-def test_to_block_bucket_truncation_raises():
-    """max_blocks truncation drops nonzeros -> loud ValueError with the
-    dropped count by default; allow_truncate=True downgrades it to a
-    warning (the old behaviour was SILENT data loss)."""
-    # two subjects whose columns span 3 distinct LANE blocks each
-    data = random_irregular(n_subjects=2, n_cols=3 * LANE, max_rows=4,
-                            avg_nnz_per_subject=30, seed=9)
-    bt = bucketize(data, max_buckets=1, dtype=jnp.float64)
-    b = bt.buckets[0]
-    # untruncated conversion is clean (no exception, no warning)
-    to_block_bucket(b, data.n_cols)
-    with pytest.raises(ValueError, match=r"truncated \d+ nonzeros"):
-        to_block_bucket(b, data.n_cols, max_blocks=1)
-    with pytest.warns(UserWarning, match=r"truncated \d+ nonzeros"):
-        bb = to_block_bucket(b, data.n_cols, max_blocks=1, allow_truncate=True)
-    assert bb.vals.shape[2] == 1   # the cap was applied
 
 
 def test_bucketize_dtype_sweep():

@@ -1,10 +1,13 @@
-"""Per-kernel allclose validation: Pallas (interpret=True on CPU) vs ref.py
-oracles, swept over shapes and dtypes."""
+"""Per-kernel allclose validation of the four fused Pallas kernels
+(interpret=True on CPU) against the jnp SPARTan math of core/spartan.py,
+swept over shapes and dtypes."""
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels import ref, ops
+from repro.core import spartan
+from repro.kernels import fused
+from repro.kernels.common import fold_subject_mask
 
 
 def _rand(shape, dtype, seed):
@@ -12,93 +15,70 @@ def _rand(shape, dtype, seed):
     return jnp.asarray(rng.standard_normal(shape), dtype=dtype)
 
 
-SHAPES = [  # (K, R, C)
-    (1, 8, 128),
-    (3, 8, 128),
-    (4, 16, 256),
-    (2, 40, 384),
-    (5, 8, 640),   # multiple C tiles with block_c=512
+SHAPES = [  # (K, R, C, I_pad)
+    (1, 8, 128, 8),
+    (3, 8, 128, 24),
+    (4, 16, 256, 8),
+    (2, 40, 384, 168),
+    (5, 8, 640, 24),   # multiple C tiles with block_c=512
 ]
 DTYPES = [jnp.float32, jnp.bfloat16]
+KERNELS = ["procrustes_b", "mode1_xkv", "mode2_compact", "ykv"]
 
 
-@pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_mode1_kernel(shape, dtype):
-    K, R, C = shape
-    Yc = _rand((K, R, C), dtype, 0)
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _operands(K, R, C, I, dtype):
+    """One bucket's operands: the vals slab, gathered V rows, Q, H, W rows,
+    a column mask with padding and a subject mask whose last slot is a
+    padding subject (for K > 1)."""
+    vals = _rand((K, I, C), dtype, 0)
     Vg = _rand((K, C, R), dtype, 1)
-    Wb = _rand((K, R), dtype, 2)
-    out = ops.mttkrp_mode1(Yc, Vg, Wb)
-    want = ref.mode1_ref(Yc, Vg, Wb)
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(out, want, rtol=tol, atol=tol * np.abs(want).max())
+    Q = _rand((K, I, R), dtype, 2)
+    H = _rand((R, R), dtype, 3)
+    Wb = _rand((K, R), dtype, 4)
+    col_mask = jnp.asarray(np.arange(C) < C - 5, dtype)[None].repeat(K, 0)
+    smask = jnp.ones((K,), dtype)
+    if K > 1:
+        smask = smask.at[-1].set(0)
+    return vals, Vg, Q, H, Wb, col_mask, smask
 
 
+def _run(kernel, vals, Vg, Q, H, Wb, col_mask, smask):
+    """-> (kernel output, the spartan reference on the same inputs in f32)."""
+    Wm = fold_subject_mask(Wb, smask)
+    XkV = jnp.einsum("kic,kcr->kir", _f32(vals), _f32(Vg))
+    Yc = jnp.einsum("kir,kic->krc", _f32(Q), _f32(vals))
+    if kernel == "procrustes_b":
+        xkv, B = fused.fused_procrustes_b(vals, Vg, Wb, H, interpret=True)
+        want_B = jnp.einsum("kir,lr->kil", XkV * _f32(Wb)[:, None, :],
+                            _f32(H))
+        return jnp.concatenate([xkv, B], 1), jnp.concatenate([XkV, want_B], 1)
+    if kernel == "mode1_xkv":
+        out = fused.fused_mode1_xkv(Q, XkV.astype(Q.dtype), Wm,
+                                    interpret=True)
+        YkV = jnp.einsum("kir,kil->krl", _f32(Q), _f32(XkV.astype(Q.dtype)))
+        return out, spartan.mode1_bucket(None, None, _f32(Wb), _f32(smask),
+                                         YkV=YkV)
+    if kernel == "mode2_compact":
+        out = fused.fused_mode2_compact(vals, Q, H, Wm, col_mask,
+                                        interpret=True)
+        return out, spartan.mode2_bucket_compact(
+            Yc, _f32(H), _f32(Wb), _f32(col_mask), _f32(smask))
+    # Y_k V, the product spartan.mode1_bucket / mode3_bucket form from Yc
+    out = fused.fused_ykv(vals, Q, Vg, interpret=True)
+    return out, jnp.einsum("krc,kcl->krl", Yc, _f32(Vg))
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_mode2_kernel(shape, dtype):
-    K, R, C = shape
-    Yc = _rand((K, R, C), dtype, 3)
-    H = _rand((R, R), dtype, 4)
-    Wb = _rand((K, R), dtype, 5)
-    out = ops.mttkrp_mode2_compact(Yc, H, Wb)
-    want = ref.mode2_compact_ref(Yc, H, Wb)
+def test_fused_kernel(kernel, shape, dtype):
+    K, R, C, I = shape
+    out, want = _run(kernel, *_operands(K, R, C, I, dtype))
+    assert out.dtype == jnp.float32 and out.shape == want.shape
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(out, want, rtol=tol, atol=tol * np.abs(want).max())
-
-
-@pytest.mark.parametrize("shape", SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_mode3_kernel(shape, dtype):
-    K, R, C = shape
-    Yc = _rand((K, R, C), dtype, 6)
-    Vg = _rand((K, C, R), dtype, 7)
-    H = _rand((R, R), dtype, 8)
-    out = ops.mttkrp_mode3(Yc, Vg, H)
-    want = ref.mode3_ref(Yc, Vg, H)
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(out, want, rtol=tol, atol=tol * np.abs(want).max())
-
-
-@pytest.mark.parametrize("K,I,NB,nblocks_v", [(2, 8, 2, 4), (3, 16, 3, 8), (1, 8, 1, 2)])
-@pytest.mark.parametrize("dtype", [jnp.float32])
-def test_gather_matmul_kernel(K, I, NB, nblocks_v, dtype):
-    L, R = 128, 8
-    rng = np.random.default_rng(9)
-    vals = jnp.asarray(rng.standard_normal((K, I, NB, L)), dtype)
-    blk_ids = jnp.asarray(rng.integers(0, nblocks_v, (K, NB)), jnp.int32)
-    V = jnp.asarray(rng.standard_normal((nblocks_v * L, R)), dtype)
-    out = ops.gather_matmul(vals, blk_ids, V)
-    want = ref.gather_matmul_ref(vals, blk_ids, V)
-    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-4)
-
-
-def test_kernels_agree_with_spartan_path():
-    """The Pallas kernels, fed masked bucket tensors, reproduce the pure-JAX
-    SPARTan MTTKRP used by the ALS driver (end-to-end integration)."""
-    from repro.sparse import random_irregular
-    from repro.core import bucketize
-    from repro.core import spartan
-
-    data = random_irregular(n_subjects=7, n_cols=40, max_rows=10,
-                            avg_nnz_per_subject=25, seed=21)
-    R = 8
-    bt = bucketize(data, max_buckets=1, dtype=jnp.float32)
-    b = bt.buckets[0]
-    rng = np.random.default_rng(0)
-    V = jnp.asarray(rng.standard_normal((data.n_cols, R)), jnp.float32)
-    W = jnp.asarray(rng.standard_normal((data.n_subjects, R)), jnp.float32)
-    H = jnp.asarray(rng.standard_normal((R, R)), jnp.float32)
-    Q = jnp.asarray(rng.standard_normal((b.kb, b.i_pad, R)), jnp.float32)
-    Yc = b.project(Q)
-    Vg = b.gather_v(V)
-    Wb = jnp.take(W, b.subject_ids, axis=0)
-    # mask-premultiplied inputs for the kernels
-    Yc_m = Yc * b.subject_mask[:, None, None]
-    m1_kernel = ops.mttkrp_mode1(Yc_m, Vg, Wb)
-    m1_jax = spartan.mode1_bucket(Yc, Vg, Wb, b.subject_mask)
-    np.testing.assert_allclose(m1_kernel, m1_jax, rtol=1e-5, atol=1e-4)
-    m3_kernel = ops.mttkrp_mode3(Yc_m, Vg, H)
-    m3_jax = spartan.mode3_bucket(Yc, Vg, H, b.subject_mask)
-    np.testing.assert_allclose(m3_kernel, m3_jax, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(out, want, rtol=tol,
+                               atol=tol * np.abs(want).max())

@@ -3,8 +3,7 @@ performance/memory knob. The same bucket plan is materialized in both device
 formats and every per-iteration stage — X_k V, the projection Y_k = Q^T X_k,
 all three MTTKRP modes, ykv, and whole decompositions under every engine and
 an ADMM-routed constraint — must agree (f64, tight tolerances; host-vs-scan
-bitwise on SCOO). Also covers the CC-vs-SCOO auto-router's decisions and the
-Pallas scalar-prefetch kernels in interpret mode."""
+bitwise on SCOO). Also covers the CC-vs-SCOO auto-router's decisions."""
 import numpy as np
 import pytest
 import jax
@@ -13,7 +12,6 @@ import jax.numpy as jnp
 from repro.core import (
     Parafac2Options, SparseBucket, Bucket, bucket_format, bucketize, fit)
 from repro.core import spartan
-from repro.core.backend import get_backend
 from repro.kernels import scoo as kscoo
 from repro.data import choa_like
 from repro.sparse import (
@@ -48,6 +46,15 @@ def _edge_data(n_cols=29):
     return IrregularCOO(subjects=subs, n_cols=n_cols)
 
 
+def _r40_data(n_rows, n_cols, nnz, n_subjects, seed):
+    """Subjects of the benchmark plans' extreme SCOO buckets: tall (CHOA's
+    168-row, 648-triplet bucket), wide (MovieLens's kept-column spans of
+    768 and more) or a single subject (Kb = 1)."""
+    rng = np.random.default_rng(seed)
+    return IrregularCOO(subjects=[_subject(rng, n_rows, n_cols, nnz)
+                                  for _ in range(n_subjects)], n_cols=n_cols)
+
+
 DATASETS = {
     "edge": _edge_data,
     "random-odd": lambda: random_irregular(
@@ -56,6 +63,10 @@ DATASETS = {
     "random-padded": lambda: random_irregular(
         n_subjects=11, n_cols=50, max_rows=12, avg_nnz_per_subject=25,
         seed=3),
+    # rank-40 stage parity at the plans' extreme bucket shapes
+    "tall-r40": lambda: _r40_data(166, 300, 650, 3, seed=11),
+    "wide-r40": lambda: _r40_data(19, 2_000, 1_300, 2, seed=12),
+    "single-r40": lambda: _r40_data(24, 400, 120, 1, seed=13),
 }
 
 
@@ -77,7 +88,14 @@ def test_stage_parity(name):
     data = DATASETS[name]()
     cc, sc = _pair(data)
     rng = np.random.default_rng(1)
-    R = 5
+    R = 40 if name.endswith("-r40") else 5
+    bs0 = sc.buckets[0]
+    if name == "tall-r40":
+        assert bs0.n_pad >= 648 and bs0.i_pad >= 166
+    elif name == "wide-r40":
+        assert bs0.c_pad >= 768
+    elif name == "single-r40":
+        assert len(sc.buckets) == 1 and bs0.kb == 1
     V = jnp.asarray(rng.standard_normal((data.n_cols, R)))
     H = jnp.asarray(rng.standard_normal((R, R)))
     for bc, bs in zip(cc.buckets, sc.buckets):
@@ -146,7 +164,7 @@ def choa_small():
     return choa_like(scale=5e-5, seed=0)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "scoo", "auto"])
+@pytest.mark.parametrize("backend", ["jnp", "auto"])
 def test_fit_parity_backends(choa_small, backend):
     """Whole-decomposition parity: SCOO final fit within 1e-8 of the CC/jnp
     reference (f64, the acceptance-criterion command shape)."""
@@ -271,85 +289,12 @@ def test_mixed_format_fit_runs(choa_small):
     assert len(hist) == 5 and np.isfinite(hist).all()
 
 
-# ---------------------------------------------------------------------------
-# Pallas kernels (interpret mode on CPU)
-# ---------------------------------------------------------------------------
-
-def test_pallas_scoo_kernels_interpret():
-    data = DATASETS["edge"]()
-    _, sc = _pair(data, dtype=jnp.float32)
-    rng = np.random.default_rng(5)
-    R = 4
-    V = jnp.asarray(rng.standard_normal((data.n_cols, R)), jnp.float32)
-    for b in sc.buckets:
-        Vg = b.gather_v(V)
-        Q = jnp.asarray(rng.standard_normal((b.kb, b.i_pad, R)), jnp.float32)
-        ref_x = kscoo.xk_times_v(b.vals, b.rows, b.lcols, Vg, b.i_pad,
-                                 row_ends=b.row_ends)
-        pal_x = kscoo.xk_times_v(b.vals, b.rows, b.lcols, Vg, b.i_pad,
-                                 use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(pal_x), np.asarray(ref_x),
-                                   rtol=1e-4, atol=1e-4)
-        ref_p = kscoo.project(b.vals, b.rows, b.lcols, Q, b.c_pad,
-                              cperm=b.cperm, col_ends=b.col_ends)
-        pal_p = kscoo.project(b.vals, b.rows, b.lcols, Q, b.c_pad,
-                              use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(pal_p), np.asarray(ref_p),
-                                   rtol=1e-4, atol=1e-4)
-
-
-def test_pallas_backend_scoo_buckets():
-    """PallasBackend's bucket-level stages route SCOO buckets through the
-    Pallas segment-sum kernels and agree with the jnp route (f32)."""
-    data = DATASETS["random-padded"]()
-    _, sc = _pair(data, dtype=jnp.float32)
-    rng = np.random.default_rng(6)
-    R = 4
-    V = jnp.asarray(rng.standard_normal((data.n_cols, R)), jnp.float32)
-    pal, ref = get_backend("pallas"), get_backend("jnp")
-    for b in sc.buckets:
-        np.testing.assert_allclose(np.asarray(pal.xkv_bucket(b, V)),
-                                   np.asarray(ref.xkv_bucket(b, V)),
-                                   rtol=1e-4, atol=1e-4)
-        Q = jnp.asarray(rng.standard_normal((b.kb, b.i_pad, R)), jnp.float32)
-        np.testing.assert_allclose(np.asarray(pal.project_bucket(b, Q)),
-                                   np.asarray(ref.project_bucket(b, Q)),
-                                   rtol=1e-4, atol=1e-4)
-
-
 def test_nnz_offsets_uniform():
     _, sc = _pair(DATASETS["edge"]())
     for b in sc.buckets:
         np.testing.assert_array_equal(
             np.asarray(b.nnz_offsets),
             np.arange(b.kb, dtype=np.int32) * b.n_pad)
-
-
-def test_pallas_block_skip_explicit_zero_values():
-    """Explicit zero-VALUED triplets are legal; the Pallas block-skip must
-    key on the true nnz_counts (or skip nothing), never on vals != 0 —
-    counting values would drop real entries that follow a stored zero."""
-    Kb, N, I, C, R = 1, 4, 4, 4, 2
-    vals = jnp.asarray([[0.0, 0.0, 2.0, 3.0]], jnp.float32)
-    rows = jnp.asarray([[0, 0, 1, 2]], jnp.int32)
-    lcols = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
-    Vg = jnp.ones((Kb, C, R), jnp.float32)
-    ref = kscoo.xk_times_v(vals, rows, lcols, Vg, I)
-    assert float(jnp.abs(ref).sum()) > 0
-    for nnz_counts in (None, jnp.asarray([4], jnp.int32)):
-        out = kscoo.xk_times_v(vals, rows, lcols, Vg, I,
-                               nnz_counts=nnz_counts, use_pallas=True,
-                               interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-6)
-    Q = jnp.ones((Kb, I, R), jnp.float32)
-    ref_p = kscoo.project(vals, rows, lcols, Q, C)
-    for nnz_counts in (None, jnp.asarray([4], jnp.int32)):
-        out_p = kscoo.project(vals, rows, lcols, Q, C,
-                              nnz_counts=nnz_counts, use_pallas=True,
-                              interpret=True)
-        np.testing.assert_allclose(np.asarray(out_p), np.asarray(ref_p),
-                                   rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

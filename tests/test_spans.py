@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import Parafac2Options, bucketize, init_state
-from repro.core.backend import dispatch_tally
+from repro.core import backend as backend_mod
+from repro.core.backend import dispatch_tally, get_backend
 from repro.core.engine import als_chunk_fn, fit_device
 from repro.core.parafac2 import STAGES, als_step
 from repro.core.spans import reset_spans, span, span_totals
@@ -77,11 +78,18 @@ def test_every_stage_scope_reaches_the_compiled_chunk(fmt, backend, stages):
         (op, n) for op, n in work if not _STAGE.search(n)]
 
 
-@pytest.mark.parametrize("fmt,backend", [
-    ("cc", "jnp"), ("cc", "pallas"), ("cc", "fused"), ("scoo", "scoo")])
-def test_dispatch_tally_counts_stages_by_their_names(fmt, backend):
+@pytest.mark.parametrize("fmt,route", [
+    ("cc", "jnp"), ("cc", "fused"), ("scoo", "scoo")])
+def test_dispatch_tally_counts_stages_by_their_names(fmt, route, monkeypatch):
     bt = bucketize(_cohort(), max_buckets=2, format=fmt, dtype=jnp.float32)
-    opts = Parafac2Options(rank=4, backend=backend, dtype=jnp.float32)
+    # the fused route: the router's platform test answers as on a TPU, at
+    # a rank on the sublane quantum
+    monkeypatch.setattr(backend_mod, "_on_tpu", lambda: True)
+    rank = 8 if route == "fused" else 4
+    opts = Parafac2Options(rank=rank, dtype=jnp.float32,
+                           backend="jnp" if route == "jnp" else "auto")
+    assert {get_backend(opts.backend).route(b, rank)
+            for b in bt.buckets} == {route}
     s0 = init_state(bt, opts, 0)
     with dispatch_tally() as tally:
         jax.eval_shape(lambda s: als_step(bt, s, opts), s0)

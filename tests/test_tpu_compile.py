@@ -1,18 +1,23 @@
-"""Compile every Pallas kernel the backends can route to for a described TPU
-v5e, at the CHOA rank-40 widths, with no chip attached.
+"""Compile every Pallas kernel the router can send work to for a described
+TPU v5e, at the CHOA rank-40 widths, with no chip attached.
 
 Interpret mode (the CPU parity tests) cannot see what Mosaic refuses, such
 as a block whose last two dims are neither (8, 128)-aligned nor the whole
 array. These tests lower each kernel with ``interpret=False`` against a
 described ``v5e:2x2`` topology, compile it with the TPU compiler, and check
-that the Mosaic kernel is in the compiled HLO (``tpu_custom_call``). One ALS
-step on the fused backend is compiled the same way.
+that the Mosaic kernel is in the compiled HLO (``tpu_custom_call``): the
+fused kernels at two CHOA bucket shapes and at every bucket shape of the
+``choa-r40.cc`` plan. One ALS step on the fused route is compiled the same
+way, and one routed step per SCOO bucket shape of the ``choa-r40.auto`` and
+``movielens-r40.auto`` plans, whose mode-1 stage must hold no Pallas call.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the test workers all import
 this file.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,18 +25,27 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache as cc
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import Parafac2Options, als_step, init_state
-from repro.core.irregular import Bucket, Bucketed
-from repro.kernels import fused, scoo, segsum
-from repro.kernels.mttkrp_mode1 import mode1_pallas, mode1_reuse_pallas
-from repro.kernels.mttkrp_mode2 import mode2_compact_pallas
-from repro.kernels.mttkrp_mode3 import mode3_pallas, mode3_reuse_pallas
-from repro.kernels.ykv import ykv_pallas
+from repro.core import Parafac2Options, als_step, bucketize, init_state
+from repro.core import backend
+from repro.core.irregular import Bucket, Bucketed, SparseBucket
+from repro.kernels import fused, segsum
+from repro.sparse import random_irregular
 
 R = 40                     # the paper's CHOA rank
 K = 64                     # subjects per compiled bucket (the grid length)
 GEOMS = [(32, 128), (168, 256)]        # (I_pad, C_pad) of CHOA buckets
-SCOO_GEOMS = [(32, 128, 256), (168, 256, 1024)]   # (I_pad, C_pad, N_pad)
+# (Kb, I_pad, C_pad) of every bucket of the choa-r40.cc plan (PERF.md §4)
+CC_PLAN = [(22_586, 8, 128), (19_874, 16, 128), (3_738, 48, 128),
+           (292, 168, 128)]
+# (I_pad, C_pad, N_pad) of every SCOO bucket of the choa-r40.auto and
+# movielens-r40.auto plans (PERF.md §4), with each config's J
+SCOO_PLANS = [
+    ("choa", 1_328, [(8, 128, 32), (24, 128, 96), (56, 128, 224),
+                     (168, 128, 648)]),
+    ("ml", 26_096, [(8, 256, 256), (8, 768, 768), (8, 1_920, 1_920),
+                    (24, 6_656, 6_544)]),
+]
+SCOO_KB = 8                # subjects per compiled SCOO bucket
 f32, i32 = jnp.float32, jnp.int32
 
 
@@ -71,56 +85,32 @@ def _compile_for_chip(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _fused_cases(Kb, I, C, tag):
+    """The four fused kernels of one CC bucket [Kb, I, C] at rank R."""
+    return [
+        (f"fused_procrustes_b-{tag}I{I}-C{C}",
+         lambda v, g, w, h: fused.fused_procrustes_b(v, g, w, h),
+         [((Kb, I, C), f32), ((Kb, C, R), f32), ((Kb, R), f32),
+          ((R, R), f32)]),
+        (f"fused_mode1_xkv-{tag}I{I}",
+         lambda q, x, w: fused.fused_mode1_xkv(q, x, w),
+         [((Kb, I, R), f32), ((Kb, I, R), f32), ((Kb, R), f32)]),
+        (f"fused_mode2_compact-{tag}I{I}-C{C}",
+         lambda v, q, h, w, m: fused.fused_mode2_compact(v, q, h, w, m),
+         [((Kb, I, C), f32), ((Kb, I, R), f32), ((R, R), f32),
+          ((Kb, R), f32), ((Kb, C), f32)]),
+        (f"fused_ykv-{tag}I{I}-C{C}",
+         lambda v, q, g: fused.fused_ykv(v, q, g),
+         [((Kb, I, C), f32), ((Kb, I, R), f32), ((Kb, C, R), f32)]),
+    ]
+
+
 def _kernel_cases():
     cases = []
     for I, C in GEOMS:
-        cases += [
-            (f"fused_procrustes_b-I{I}-C{C}",
-             lambda v, g, w, h: fused.fused_procrustes_b(v, g, w, h),
-             [((K, I, C), f32), ((K, C, R), f32), ((K, R), f32), ((R, R), f32)]),
-            (f"fused_mode1_xkv-I{I}",
-             lambda q, x, w: fused.fused_mode1_xkv(q, x, w),
-             [((K, I, R), f32), ((K, I, R), f32), ((K, R), f32)]),
-            (f"fused_mode2_compact-I{I}-C{C}",
-             lambda v, q, h, w, m: fused.fused_mode2_compact(v, q, h, w, m),
-             [((K, I, C), f32), ((K, I, R), f32), ((R, R), f32),
-              ((K, R), f32), ((K, C), f32)]),
-            (f"fused_ykv-I{I}-C{C}",
-             lambda v, q, g: fused.fused_ykv(v, q, g),
-             [((K, I, C), f32), ((K, I, R), f32), ((K, C, R), f32)]),
-        ]
-    for _, C in GEOMS:
-        cases += [
-            (f"mode1-C{C}", lambda y, g, w, m: mode1_pallas(y, g, w, m),
-             [((K, R, C), f32), ((K, C, R), f32), ((K, R), f32), ((K,), f32)]),
-            (f"mode2_compact-C{C}",
-             lambda y, h, w, cm, m: mode2_compact_pallas(y, h, w, cm, m),
-             [((K, R, C), f32), ((R, R), f32), ((K, R), f32), ((K, C), f32),
-              ((K,), f32)]),
-            (f"mode3-C{C}", lambda y, g, h, m: mode3_pallas(y, g, h, m),
-             [((K, R, C), f32), ((K, C, R), f32), ((R, R), f32), ((K,), f32)]),
-            (f"ykv-C{C}", lambda y, g: ykv_pallas(y, g),
-             [((K, R, C), f32), ((K, C, R), f32)]),
-        ]
-    cases += [
-        ("mode1_reuse", lambda y, w, m: mode1_reuse_pallas(y, w, m),
-         [((K, R, R), f32), ((K, R), f32), ((K,), f32)]),
-        ("mode3_reuse", lambda y, h, m: mode3_reuse_pallas(y, h, m),
-         [((K, R, R), f32), ((R, R), f32), ((K,), f32)]),
-    ]
-    for I, C, N in SCOO_GEOMS:
-        cases += [
-            (f"scoo_xk_times_v-I{I}-N{N}",
-             lambda v, r, c, g, n, I=I: scoo.xk_times_v_pallas(
-                 v, r, c, g, I, nnz_counts=n),
-             [((K, N), f32), ((K, N), i32), ((K, N), i32), ((K, C, R), f32),
-              ((K,), i32)]),
-            (f"scoo_project-C{C}-N{N}",
-             lambda v, r, c, q, n, C=C: scoo.project_pallas(
-                 v, r, c, q, C, nnz_counts=n),
-             [((K, N), f32), ((K, N), i32), ((K, N), i32), ((K, I, R), f32),
-              ((K,), i32)]),
-        ]
+        cases += _fused_cases(K, I, C, "")
+    for Kb, I, C in CC_PLAN:
+        cases += _fused_cases(Kb, I, C, f"K{Kb}-")
     for _, C in GEOMS:
         # the mode-2 segment sum over a bucket's K * C sorted compact rows,
         # and over a count that leaves the last block short
@@ -161,6 +151,7 @@ def test_mode2_scatter_kernel_compiles_under_f32_matmuls(one_chip,
 def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
     """One whole ALS step on the fused route, both CHOA bucket geometries:
     the four fused kernels plus the eigh/solve glue compile together."""
+    monkeypatch.setattr(backend, "_on_tpu", lambda: True)
     monkeypatch.setattr(fused, "_interpret", lambda: False)
 
     def spec(shape, dt=f32):
@@ -173,10 +164,58 @@ def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
                  for I, C in GEOMS],
         n_subjects=K * len(GEOMS), n_cols=1_328, norm_sq=1.0)
     opts = Parafac2Options(rank=R, constraints={"v": "nonneg", "w": "nonneg"},
-                           backend="fused")
+                           backend="auto")
+    assert {backend.get_backend("auto").route(b, R)
+            for b in data.buckets} == {"fused"}
     state = jax.eval_shape(lambda: init_state(data, opts, 0))
     state = jax.tree_util.tree_map(lambda x: spec(x.shape, x.dtype), state)
     text = jax.jit(lambda d, s: als_step(d, s, opts)).lower(
         data, state).compile().as_text()
     # F1-F4 per bucket: procrustes_b, mode1_xkv, mode2_compact, ykv
     assert text.count('custom_call_target="tpu_custom_call"') >= 4 * len(GEOMS)
+
+
+def _scoo_bucket(template, I, C, N, sharding):
+    """A SparseBucket of shape specs [Kb = SCOO_KB] at (I_pad, C_pad, N_pad),
+    each leaf with the dtype ``bucketize`` gives it."""
+    dims = {"vals": (SCOO_KB, N), "rows": (SCOO_KB, N),
+            "lcols": (SCOO_KB, N), "cperm": (SCOO_KB, N),
+            "row_ends": (SCOO_KB, I), "col_ends": (SCOO_KB, C),
+            "cols": (SCOO_KB, C), "col_mask": (SCOO_KB, C)}
+    leaves = {f.name: getattr(template, f.name)
+              for f in dataclasses.fields(template) if f.name != "n_rows_pad"}
+    return SparseBucket(n_rows_pad=I, **{
+        name: jax.ShapeDtypeStruct(dims.get(name, (SCOO_KB,)), leaf.dtype,
+                                   sharding=sharding)
+        for name, leaf in leaves.items()})
+
+
+_SCOO_CASES = [(f"{cfg}-I{I}-C{C}-N{N}", J, (I, C, N))
+               for cfg, J, shapes in SCOO_PLANS for I, C, N in shapes]
+_CUSTOM_CALL = re.compile(r'custom_call_target="tpu_custom_call".*'
+                          r'op_name="[^"]*als/mode1/')
+
+
+@pytest.mark.parametrize("name", [c[0] for c in _SCOO_CASES])
+def test_scoo_als_step_compiles_for_v5e(name, one_chip, monkeypatch):
+    """One routed ALS step on a SCOO bucket of a benchmark plan, the router
+    answering as on a TPU: the step compiles, and its mode-1 stage is jnp
+    (no Mosaic kernel under ``als/mode1``), as its mode-3 stage is."""
+    _, J, (I, C, N) = next(c for c in _SCOO_CASES if c[0] == name)
+    monkeypatch.setattr(backend, "_on_tpu", lambda: True)
+    template = bucketize(random_irregular(n_subjects=4, n_cols=30, max_rows=5,
+                                          avg_nnz_per_subject=10, seed=0),
+                         max_buckets=1, format="scoo").buckets[0]
+    b = _scoo_bucket(template, I, C, N, one_chip)
+    data = Bucketed(buckets=[b], n_subjects=SCOO_KB, n_cols=J, norm_sq=1.0)
+    opts = Parafac2Options(rank=R, constraints={"v": "nonneg", "w": "nonneg"},
+                           backend="auto")
+    assert backend.get_backend("auto").route(b, R) == "scoo"
+    state = jax.eval_shape(lambda: init_state(data, opts, 0))
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        state)
+    text = jax.jit(lambda d, s: als_step(d, s, opts)).lower(
+        data, state).compile().as_text()
+    assert "als/mode1/" in text
+    assert not _CUSTOM_CALL.search(text)
