@@ -109,7 +109,7 @@ def main(argv=None):
                 M2 = jnp.zeros((J, R), H.dtype)
                 for b, p in zip(buckets, projs):
                     A = be.mode2_bucket(b, p, H, jnp.take(W, b.subject_ids, 0))
-                    M2 = M2 + be.mode2_scatter(A, b.cols, J).astype(M2.dtype)
+                    M2 = M2 + be.mode2_scatter(A, b.scatter).astype(M2.dtype)
                 return M2
 
             def run_mode3(H, V):

@@ -1,8 +1,9 @@
 """Device time and accuracy of the mode-2 segment sum, by implementation.
 
 ``core/spartan.py::mode2_scatter`` sums each bucket's compact mode-2 rows
-A [Kb, C, R] into M2 [J, R] by column id: a stable argsort of the flat ids,
-a permutation gather of the rows, and a segment sum over the sorted rows.
+A [Kb, C, R] into M2 [J, R] by column id: a gather of the real rows in the
+column order the layout fixed once (the bucket's ``ScatterIndex``), and a
+segment sum over the gathered rows.
 This times, on a TPU, at the bucket shapes of the benchmark cells (the
 ``choa-r40`` and ``movielens-r40`` cohorts as ``chipbench`` generates and
 buckets them, ``--format auto``), each of these segment sums:
@@ -14,7 +15,7 @@ buckets them, ``--format auto``), each of these segment sums:
   sizes);
 * ``segment_sum``: ``jax.ops.segment_sum`` over the sorted rows.
 
-For each it reports the ms of the whole scatter (sort, gather, sum; host
+For each it reports the ms of the whole scatter (gather, sum; host
 clock around ``block_until_ready``), the compiled program's temporary
 bytes, and the
 relative error of M2 against a float64 sum on the host: over all rows, and
@@ -54,30 +55,31 @@ def _at_ends(y, ends):
                      0.0)
 
 
+def _ids(g, ends):
+    """Each sorted row's column; rows past the last column get J."""
+    return jnp.searchsorted(ends, jnp.arange(g.shape[0], dtype=ends.dtype),
+                            side="right")
+
+
 def _sums(J):
-    """{name: (sorted rows g, sorted ids, ends) -> M2}."""
+    """{name: (sorted rows g, starts, ends) -> M2}."""
     return {
-        "cumsum": lambda g, ids, ends: _cumsum_diff(g, ends),
-        "pallas": lambda g, ids, ends: _at_ends(
-            segsum.segmented_cumsum_pallas(g, segsum.segment_starts(ids)),
-            ends),
-        "pallas_t256": lambda g, ids, ends: _at_ends(
-            segsum.segmented_cumsum_pallas(g, segsum.segment_starts(ids),
-                                           tile=256), ends),
-        "pallas_b4096": lambda g, ids, ends: _at_ends(
-            segsum.segmented_cumsum_pallas(g, segsum.segment_starts(ids),
-                                           block=4096), ends),
-        "segment_sum": lambda g, ids, ends: jax.ops.segment_sum(
-            g, ids, num_segments=J, indices_are_sorted=True),
+        "cumsum": lambda g, starts, ends: _cumsum_diff(g, ends),
+        "pallas": lambda g, starts, ends: _at_ends(
+            segsum.segmented_cumsum_pallas(g, starts), ends),
+        "pallas_t256": lambda g, starts, ends: _at_ends(
+            segsum.segmented_cumsum_pallas(g, starts, tile=256), ends),
+        "pallas_b4096": lambda g, starts, ends: _at_ends(
+            segsum.segmented_cumsum_pallas(g, starts, block=4096), ends),
+        "segment_sum": lambda g, starts, ends: jax.ops.segment_sum(
+            g, _ids(g, ends), num_segments=J, indices_are_sorted=True),
     }
 
 
-def _sorted(A, cols, J):
-    flat_cols = cols.reshape(-1)
-    perm = jnp.argsort(flat_cols, stable=True)
-    ids = flat_cols[perm]
-    ends = jnp.searchsorted(ids, jnp.arange(1, J + 1, dtype=ids.dtype))
-    return A.reshape(-1, A.shape[-1])[perm], ids, ends
+def _sorted(A, index):
+    """The bucket's real rows in its index's column order (one shard)."""
+    g = A.reshape(-1, A.shape[-1])[index.perm[0]]
+    return g, index.starts, index.ends[0]
 
 
 def timed(fn, args, reps):
@@ -130,11 +132,10 @@ def main(argv=None):
         for bi, (b, st) in enumerate(zip(bt.buckets, stats)):
             if args.buckets and f"{name}:{bi}" not in args.buckets.split(","):
                 continue
-            cols = b.cols
             mask = np.asarray(b.col_mask) * np.asarray(b.subject_mask)[:, None]
             A = (rng.standard_normal(mask.shape + (R,)).astype(np.float32)
                  * mask[..., None].astype(np.float32))
-            cols_h = np.asarray(cols).reshape(-1)
+            cols_h = np.asarray(b.cols).reshape(-1)
             real = mask.reshape(-1) > 0
             ref = np.zeros((J, R))
             np.add.at(ref, cols_h[real], A.reshape(-1, R)[real].astype(np.float64))
@@ -147,8 +148,8 @@ def main(argv=None):
                 key = f"{name}/b{bi}/{impl}"
                 try:
                     M2, full_ms, temp = timed(
-                        lambda a, c, fn=fn: fn(*_sorted(a, c, J)),
-                        (A_d, cols), args.reps)
+                        lambda a, ix, fn=fn: fn(*_sorted(a, ix)),
+                        (A_d, b.scatter), args.reps)
                 except Exception as e:     # a refused variant is a reading
                     out["cases"][key] = {"error": repr(e)[:400]}
                     print(f"{key}: {e!r}"[:400], flush=True)
@@ -157,7 +158,7 @@ def main(argv=None):
                 err = np.linalg.norm(M2 - ref) / np.linalg.norm(ref)
                 row = (np.linalg.norm(M2 - ref, axis=1)
                        / np.maximum(np.linalg.norm(ref, axis=1), 1e-300))
-                rec = {"rows": int(cols_h.size), "J": J, "kb": st.get("n_subjects"),
+                rec = {"rows": int(b.scatter.perm.size), "J": J, "kb": st.get("n_subjects"),
                        "c_pad": st["c_pad"], "full_ms": full_ms,
                        "temp_bytes": temp, "rel_err": float(err),
                        "rare_rel_err_max": float(row[rare].max()) if rare.any() else None,

@@ -1,6 +1,6 @@
 """Paper core: PARAFAC2 + SPARTan MTTKRP on bucketed compressed-column data."""
 from repro.core.irregular import (
-    Bucket, Bucketed, SparseBucket, bucketize, bucket_format,
+    Bucket, Bucketed, ScatterIndex, SparseBucket, bucketize, bucket_format,
     cc_bucket_like, FORMATS)
 from repro.core.backend import MttkrpBackend, get_backend
 from repro.core.compress import (
@@ -52,6 +52,7 @@ __all__ = [
     "make_als_while",
     "Bucket",
     "Bucketed",
+    "ScatterIndex",
     "SparseBucket",
     "bucketize",
     "bucket_format",

@@ -259,7 +259,7 @@ class MttkrpBackend:
         for b, Yc in zip(buckets, Ycs):
             A = self.mode2_compact(Yc, H, jnp.take(W, b.subject_ids, 0),
                                    b.col_mask, b.subject_mask)
-            M2 = M2 + self.mode2_scatter(A, b.cols, J).astype(M2.dtype)
+            M2 = M2 + self.mode2_scatter(A, b.scatter).astype(M2.dtype)
         return M2
 
     def mttkrp_mode3(self, buckets: Sequence, Ycs: Sequence[jax.Array],
@@ -287,9 +287,11 @@ class SparseBackend(MttkrpBackend):
     name = "scoo"
 
     def _ykv_native(self, b: SparseBucket, Q, V):
-        return scoo.ykv_scoo(self._pc(b.vals), b.rows, b.lcols,
-                             self.shard_subjects(Q),
-                             self._pc(b.gather_v(V)))
+        # each triplet's V row straight from V: the kept-column gather
+        # (gather_v, [Kb, C_pad, R]) would also fill every padded slot, and
+        # on CHOA's largest bucket it set the compiled step's peak
+        return scoo.ykv_scoo(self._pc(b.vals), b.rows, self.shard_subjects(Q),
+                             self._pc(b.triplet_v(V)))
 
     def project_bucket(self, b, Q):
         return self.shard_subjects(Q)   # carry Q; Yc is never built

@@ -86,6 +86,13 @@ def _check_divisible(data, state, n_shards: int) -> None:
                 f"engine='mesh' needs every bucket's subject count to divide "
                 f"the {n_shards} subject shards, but bucket {i} has Kb={b.kb}; "
                 f"re-bucketize with bucketize(subject_align={n_shards})")
+        runs = b.scatter.perm.shape[0]
+        if runs % n_shards:
+            raise ValueError(
+                f"engine='mesh' needs every bucket's scatter index laid out "
+                f"per subject shard, but bucket {i} has {runs} run(s) for "
+                f"{n_shards} shards; re-bucketize with "
+                f"bucketize(subject_align={n_shards})")
     if isinstance(state.W, tuple):
         for i, wb in enumerate(state.W):
             if wb.shape[0] % n_shards:
@@ -97,7 +104,8 @@ def _check_divisible(data, state, n_shards: int) -> None:
 def _mesh_specs(data, state, axes: Tuple[str, ...]):
     """(data_specs, state_specs) pytrees for shard_map over the subject axis.
 
-    Every Bucket leaf is Kb-leading → split over `axes`; H/V/fit (and a
+    Every Bucket leaf is Kb-leading (the scatter index leads with its
+    per-shard runs) → split over `axes`; H/V/fit (and a
     global [K,R] W) are replicated; a bucketed W tuple splits like the data.
     Constraint aux state (ADMM duals) follows its owning factor: the "w" aux
     of a bucketed W splits over the subject axes, everything else replicates.

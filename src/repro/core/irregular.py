@@ -40,14 +40,80 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.spans import span
+from repro.kernels.segsum import segment_bounds
 from repro.sparse.coo import IrregularCOO
 from repro.sparse.bucketing import BucketPlan, plan_layout, route_formats
 from repro.sparse.bucketing import SCOO_DENSITY_THRESHOLD
 
-__all__ = ["Bucket", "SparseBucket", "Bucketed", "bucketize", "bucket_format",
-           "cc_bucket_like", "FORMATS"]
+__all__ = ["Bucket", "SparseBucket", "Bucketed", "ScatterIndex", "bucketize",
+           "bucket_format", "cc_bucket_like", "scatter_index", "FORMATS"]
 
 FORMATS = ("cc", "scoo", "auto")  # bucketize(format=...) choices
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class ScatterIndex:
+    """A bucket's mode-2 scatter order, fixed by the layout
+    (:func:`scatter_index`): which compact rows the scatter gathers, in
+    column order, and where each column's run of them ends.
+
+    perm:   i32[S, n]   per subject shard (S = ``bucketize``'s
+                        ``subject_align``), the flat compact slots
+                        ``k * C_pad + c`` (k counted within the shard) of
+                        its real kept columns, stably sorted by column id;
+                        padded to the longest shard, the padding past
+                        ``ends[s, -1]``, where no column sums it
+    starts: bool[S * n] set on each column's first row in ``perm`` order
+    ends:   i32[S, J]   one past each column's last row within its shard
+
+    Every leaf has the S shards on its leading axis, so the subject shards
+    of the mesh engine split it like every other bucket leaf.
+    """
+
+    perm: jax.Array
+    starts: jax.Array
+    ends: jax.Array
+
+    def padded(self, n: int) -> "ScatterIndex":
+        """This index with each shard's run padded to ``n`` rows (at least
+        its own length): ``perm`` reads slot 0 there and every padded row
+        starts a run of its own, past ``ends``, which no column sums."""
+        S, m = self.perm.shape
+        pad = ((0, 0), (0, max(0, n - m)))
+        return ScatterIndex(
+            perm=jnp.pad(self.perm, pad),
+            starts=jnp.pad(self.starts.reshape(S, m), pad,
+                           constant_values=True).reshape(-1),
+            ends=self.ends)
+
+    def tree_flatten(self):
+        return (self.perm, self.starts, self.ends), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
+def scatter_index(cols: np.ndarray, col_mask: np.ndarray, n_cols: int,
+                  n_shards: int = 1) -> ScatterIndex:
+    """The :class:`ScatterIndex` (NumPy leaves) of a bucket's ``cols`` and
+    ``col_mask`` [Kb, C_pad] over ``n_cols`` columns, with the Kb subjects
+    split into ``n_shards`` equal contiguous shards. Only slots with
+    ``col_mask`` > 0 are kept. Each shard's run is padded to the longest,
+    and to at least one row, so that no shape is zero."""
+    shard_cols = np.asarray(cols).reshape(n_shards, -1)
+    shard_real = (np.asarray(col_mask) > 0).reshape(n_shards, -1)
+    slots = [np.flatnonzero(real) for real in shard_real]
+    n = max(1, max(s.size for s in slots))
+    perm = np.zeros((n_shards, n), np.int32)
+    ids = np.full((n_shards, n), n_cols, np.int64)   # padding: no column
+    for i, (c, s) in enumerate(zip(shard_cols, slots)):
+        order = np.argsort(c[s], kind="stable")
+        perm[i, :s.size] = s[order]
+        ids[i, :s.size] = c[s][order]
+    starts, ends = segment_bounds(ids, n_cols)
+    return ScatterIndex(perm=perm, starts=starts, ends=ends)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -61,6 +127,8 @@ class Bucket:
     subject_ids: i32[Kb]              global subject index (row into W)
     subject_mask:f[Kb]                1.0 real subject, 0.0 padding subject
     row_counts:  i32[Kb]              true I_k (informational; padded rows are 0)
+    scatter:     ScatterIndex         the mode-2 scatter's order of the real
+                                      compact rows
     """
 
     vals: jax.Array
@@ -69,6 +137,7 @@ class Bucket:
     subject_ids: jax.Array
     subject_mask: jax.Array
     row_counts: jax.Array
+    scatter: ScatterIndex
 
     format = "cc"  # class tag, not a field (see bucket_format)
 
@@ -81,6 +150,7 @@ class Bucket:
             self.subject_ids,
             self.subject_mask,
             self.row_counts,
+            self.scatter,
         )
         return children, None
 
@@ -160,6 +230,7 @@ class SparseBucket:
     subject_mask:f[Kb]               1.0 real subject, 0.0 padding subject
     row_counts:  i32[Kb]             true I_k
     nnz_counts:  i32[Kb]             true nnz_k (<= N_pad; pad subjects 0)
+    scatter:     ScatterIndex        the mode-2 scatter's order (as CC)
     n_rows_pad:  int (static)        I_pad — the padded row space Q/XkV use
 
     Subject-aligned padding makes the per-subject flat offsets uniform
@@ -185,6 +256,7 @@ class SparseBucket:
     subject_mask: jax.Array
     row_counts: jax.Array
     nnz_counts: jax.Array
+    scatter: ScatterIndex
     n_rows_pad: int  # static aux (not derivable from the triplet shapes)
 
     format = "scoo"
@@ -195,7 +267,7 @@ class SparseBucket:
             self.vals, self.rows, self.lcols, self.row_ends, self.cperm,
             self.col_ends, self.cols, self.col_mask,
             self.subject_ids, self.subject_mask, self.row_counts,
-            self.nnz_counts,
+            self.nnz_counts, self.scatter,
         )
         return children, (self.n_rows_pad,)
 
@@ -232,6 +304,13 @@ class SparseBucket:
         Identical to CC — the kept-column metadata is shared."""
         Vg = jnp.take(V, self.cols, axis=0)
         return Vg * self.col_mask[..., None]
+
+    def triplet_v(self, V: jax.Array) -> jax.Array:
+        """Each triplet's own V row, by its global column: [Kb, N_pad, R].
+        Pad triplets (value 0) read some kept column's row. Unlike
+        :meth:`gather_v` it never fills the padded kept-column slots."""
+        return jnp.take(V, jnp.take_along_axis(self.cols, self.lcols, axis=1),
+                        axis=0)
 
     def xk_times_v(self, V: jax.Array, Vg: Optional[jax.Array] = None) -> jax.Array:
         """X_k V for every subject: [Kb, I_pad, R] — gather-from-V +
@@ -283,9 +362,10 @@ def bucket_format(b) -> str:
 def cc_bucket_like(b, vals: jax.Array,
                    row_counts: Optional[jax.Array] = None) -> Bucket:
     """A CC :class:`Bucket` holding ``vals`` [Kb, I', C_pad] under ``b``'s
-    column/subject metadata (``b`` may be CC or SCOO — the metadata contract
-    is shared). The row space I' may differ from ``b.i_pad``: this is how the
-    compression stage (:mod:`repro.core.compress`) wraps the small cores
+    column/subject metadata and scatter index (``b`` may be CC or SCOO —
+    the metadata contract is shared). The row space I' may differ from
+    ``b.i_pad``: this is how the compression stage
+    (:mod:`repro.core.compress`) wraps the small cores
     ``G_k = P_k^T X_k`` as an ordinary bucket the engines iterate on.
     """
     if vals.shape[0] != b.kb or vals.shape[2] != b.c_pad:
@@ -295,7 +375,8 @@ def cc_bucket_like(b, vals: jax.Array,
     return Bucket(
         vals=vals, cols=b.cols, col_mask=b.col_mask,
         subject_ids=b.subject_ids, subject_mask=b.subject_mask,
-        row_counts=b.row_counts if row_counts is None else row_counts)
+        row_counts=b.row_counts if row_counts is None else row_counts,
+        scatter=b.scatter)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -363,7 +444,8 @@ def bucketize(
     list (must match ``plan``'s bucket count).
 
     ``subject_align`` pads each bucket's subject count to a multiple (use the
-    data-parallel shard count so the leading axis divides evenly);
+    data-parallel shard count so the leading axis divides evenly), and lays
+    each bucket's :class:`ScatterIndex` out per shard of that many;
     ``nnz_align`` rounds SCOO buckets' per-subject N_pad.
     """
     if format not in FORMATS:
@@ -446,6 +528,7 @@ def bucketize(
                 sids[slot] = k
                 smask[slot] = 1.0
                 rows_n[slot] = s.n_rows
+            index = scatter_index(cols, cmask, data.n_cols, subject_align)
         with span("layout.to_device"):
             common = dict(
                 cols=jnp.asarray(cols),
@@ -453,6 +536,7 @@ def bucketize(
                 subject_ids=jnp.asarray(sids),
                 subject_mask=jnp.asarray(smask, dtype=dtype),
                 row_counts=jnp.asarray(rows_n),
+                scatter=jax.tree_util.tree_map(jnp.asarray, index),
             )
             if fmt == "cc":
                 buckets.append(Bucket(vals=jnp.asarray(vals, dtype=dtype), **common))

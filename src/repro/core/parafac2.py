@@ -354,7 +354,7 @@ def als_step(
             Wb = _w_rows(W, b, i)
             A = be.mode2_bucket(b, proj, H_new, Wb)
         with jax.named_scope("scatter"):
-            M2 = M2 + be.mode2_scatter(A, b.cols, J).astype(M2.dtype)
+            M2 = M2 + be.mode2_scatter(A, b.scatter).astype(M2.dtype)
     with jax.named_scope("scatter"):
         M2 = psum_subjects(M2)
     with jax.named_scope("v_solve"):

@@ -89,39 +89,41 @@ def mode2_bucket_compact(
     return A * (col_mask * subject_mask[:, None])[..., None]
 
 
-def mode2_scatter(A: jax.Array, cols: jax.Array, J: int) -> jax.Array:
-    """Sum the compact rows A [Kb, C, R] into M2 [J, R] by column id ``cols``
-    [Kb, C]. Padded entries are zero, so their landing in column 0 (or any
-    segment) is harmless; a column with no entries gets exact zeros.
+def mode2_scatter(A: jax.Array, index) -> jax.Array:
+    """Sum the compact rows A [Kb, C, R] into M2 [J, R] by column, in the
+    order the bucket's :class:`repro.core.irregular.ScatterIndex` fixes.
 
-    The flat column ids are stably sorted (``sort``), the rows are gathered
-    into that order (``gather``), and each column's run of sorted rows is
-    summed on its own (``segsum``:
-    :func:`repro.kernels.segsum.sorted_segment_sum`, a Pallas segmented
-    prefix sum on a TPU). Each M2 row is accumulated from its own terms
-    only, so its rounding error is bounded by c * eps * sum|its terms| for
-    a small c, however heavy the columns sorted before it. A difference of
-    one running total over all rows would be bounded by eps times that
-    total instead, and on a cohort with Zipf-popular columns a rare
-    column's row would lose most of its digits. On a TPU no XLA
-    scatter-add: on a v5e it made the whole scatter 9-12% slower at the
-    benchmark cohorts' bucket shapes (``benchmarks/segsum_micro.py``).
+    The index holds only the real compact rows (``col_mask`` 1), stably
+    sorted by column id when the layout was built: the padded rows, which
+    are exactly zero, are never read. The rows are gathered into that
+    order (``gather``), and each column's run of them is summed on its own
+    (``segsum``: :func:`repro.kernels.segsum.sorted_segment_sum`, a Pallas
+    segmented prefix sum on a TPU) at the boundaries the index also holds;
+    a column with no rows gets exact zeros. Each M2 row is accumulated
+    from its own terms only, so its rounding error is bounded by
+    c * eps * sum|its terms| for a small c, however heavy the columns
+    sorted before it. A difference of one running total over all rows
+    would be bounded by eps times that total instead, and on a cohort with
+    Zipf-popular columns a rare column's row would lose most of its
+    digits. On a TPU no XLA scatter-add: on a v5e it made the whole
+    scatter 9-12% slower at the benchmark cohorts' bucket shapes
+    (``benchmarks/segsum_micro.py``).
 
-    The sort depends only on the data, but the compiled engine loops run it
-    every iteration. Under shard_map each shard sorts its own subjects and
-    the partial M2 is psum'd like any other. Ticks ``scatter.segsum`` in
-    :func:`repro.core.spans.dispatch_tally` once per call.
+    Nothing here sorts or searches: the order depends only on the layout,
+    so it is built once, on the host, and the compiled loops only gather
+    and sum. The index has one run per subject shard; under shard_map each
+    shard sums its own run and the partial M2 is psum'd like any other.
+    Ticks ``scatter.segsum`` in :func:`repro.core.spans.dispatch_tally`
+    once per call.
     """
-    R = A.shape[-1]
+    S, R = index.perm.shape[0], A.shape[-1]
     _tick("scatter.segsum")
-    flat_cols = cols.reshape(-1)                               # [Kb*C]
-    with jax.named_scope("sort"):
-        perm = jnp.argsort(flat_cols, stable=True)
-        ids = flat_cols[perm]
     with jax.named_scope("gather"):
-        g = A.reshape(-1, R)[perm].astype(accum_dtype(A))
+        g = jnp.take_along_axis(A.reshape(S, -1, R), index.perm[..., None],
+                                axis=1)
+        g = g.reshape(-1, R).astype(accum_dtype(A))            # [S*n, R]
     with jax.named_scope("segsum"):
-        M2 = sorted_segment_sum(g, ids, J)                     # [J, R]
+        M2 = sorted_segment_sum(g, index.starts, index.ends)   # [J, R]
     return M2.astype(A.dtype)
 
 

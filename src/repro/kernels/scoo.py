@@ -15,7 +15,7 @@ format pays for (docs/ARCHITECTURE.md, SCOO stage):
 
   xk_times_v   (X_k V)[i,:]   = sum_{n: rows[n]=i}  vals[n] * Vg[lcols[n], :]
   project      (Q^T X_k)[:,c] = sum_{n: lcols[n]=c} vals[n] * Q[rows[n], :]
-  ykv          (Y_k V)[r,l]   = sum_n vals[n] * Q[rows[n], r] * Vg[lcols[n], l]
+  ykv          (Y_k V)[r,l]   = sum_n vals[n] * Q[rows[n], r] * V[cols[lcols[n]], l]
   mode2        A[c,:]         = sum_{n: lcols[n]=c} vals[n] * (Q H)[rows[n], :]
 
 The jnp path exploits the *sorted* in "sorted flat COO": with precomputed
@@ -38,10 +38,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = [
-    "xk_times_v", "project", "ykv_scoo", "mode1_scoo", "mode2_compact_scoo",
-    "mode3_scoo",
-]
+__all__ = ["xk_times_v", "project", "ykv_scoo", "mode2_compact_scoo"]
 
 
 from repro.kernels.common import accum_dtype as _acc  # shared accumulation policy
@@ -111,21 +108,15 @@ def project(vals, rows, lcols, Q, c_pad: int, *, cperm=None, col_ends=None):
     return jnp.swapaxes(out, 1, 2).astype(vals.dtype)     # [Kb, R, C]
 
 
-def ykv_scoo(vals, rows, lcols, Q, Vg):
+def ykv_scoo(vals, rows, Q, Vt):
     """Y_k V [Kb, R, R] natively from the triplets (never materializing Yc):
-    sum_n vals[n] * Q[rows[n], :] (x) Vg[lcols[n], :] — O(nnz * R^2)."""
+    sum_n vals[n] * Q[rows[n], :] (x) Vt[n, :] — O(nnz * R^2), with ``Vt``
+    [Kb, N, R] each triplet's own V row
+    (:meth:`repro.core.irregular.SparseBucket.triplet_v`)."""
     acc = _acc(vals.dtype)
     qg = _gather_n(Q.astype(acc), rows)                   # [Kb, N, R]
-    vg = _gather_n(Vg.astype(acc), lcols)                 # [Kb, N, R]
-    return jnp.einsum("knr,knl->krl", qg * vals.astype(acc)[..., None], vg)
-
-
-def mode1_scoo(vals, rows, lcols, Q, Vg, Wb, subject_mask):
-    """Partial M1 [R, R] natively: the ykv outer-product sum, row-Hadamard
-    with W(k,:), reduced over real subjects (matches spartan.mode1_bucket)."""
-    YkV = ykv_scoo(vals, rows, lcols, Q, Vg)
-    scaled = YkV * Wb.astype(YkV.dtype)[:, None, :]
-    return jnp.einsum("krl,k->rl", scaled, subject_mask.astype(YkV.dtype))
+    return jnp.einsum("knr,knl->krl", qg * vals.astype(acc)[..., None],
+                      Vt.astype(acc))
 
 
 def mode2_compact_scoo(vals, rows, lcols, Q, H, Wb, col_mask, subject_mask,
@@ -148,12 +139,3 @@ def mode2_compact_scoo(vals, rows, lcols, Q, H, Wb, col_mask, subject_mask,
     A = _segsum(contrib, idx, col_ends, c_pad)            # [Kb, C, R]
     A = A * Wb.astype(acc)[:, None, :]
     return A * (col_mask * subject_mask[:, None]).astype(acc)[..., None]
-
-
-def mode3_scoo(vals, rows, lcols, Q, Vg, H, subject_mask):
-    """Per-subject M3 rows [Kb, R] natively: coldot(H, Y_k V) with Y_k V from
-    the triplet outer-product sum (matches spartan.mode3_bucket)."""
-    YkV = ykv_scoo(vals, rows, lcols, Q, Vg)
-    rows_out = jnp.einsum("rl,krl->kl", H.astype(YkV.dtype), YkV)
-    return rows_out * subject_mask.astype(YkV.dtype)[:, None]
-

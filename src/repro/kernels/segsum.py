@@ -1,13 +1,16 @@
 """Segment sums over sorted rows, each accumulated from its own terms.
 
-``sorted_segment_sum(x, ids, num_segments)`` sums the rows of ``x`` [N, R],
-sorted by their segment ``ids`` [N], into [num_segments, R]; a segment with
-no rows gets exact zeros. Every segment's sum is accumulated from its own
-rows only, so its rounding error is bounded by c * eps * sum|own terms|,
-with c of the order of the tile size below. A difference of one running
-total over all rows, as ``cumsum`` followed by ``diff`` computes, is
-bounded instead by eps times the running total, which for a rare segment
-after a long run of heavy ones is many times the segment's own mass.
+``sorted_segment_sum(x, starts, ends)`` sums the rows of ``x`` [N, R],
+sorted by segment, into [J, R]; a segment with no rows gets exact zeros.
+The segments' boundaries (``starts`` per row, ``ends`` per segment) depend
+only on the sorted ids, so :func:`segment_bounds` builds them once on the
+host and the device never sorts or searches. Every segment's sum is
+accumulated from its own rows only, so its rounding error is bounded by
+c * eps * sum|own terms|, with c of the order of the tile size below. A
+difference of one running total over all rows, as ``cumsum`` followed by
+``diff`` computes, is bounded instead by eps times the running total,
+which for a rare segment after a long run of heavy ones is many times the
+segment's own mass.
 
 On a TPU the sum is a Pallas kernel, ``segmented_cumsum_pallas``: a
 segmented prefix sum over the rows, read at each segment's last row. It
@@ -20,7 +23,8 @@ the [T, R] tile, so every product is exact and only the f32 sums round.
 The kernel reads and writes each row once, in place of its input.
 
 Elsewhere (CPU, GPU) the same sums come from ``jax.ops.segment_sum`` over
-the sorted ids, which adds each segment's rows in order.
+the rows' segment ids, read back from ``ends``, which adds each segment's
+rows in order.
 """
 from __future__ import annotations
 
@@ -29,38 +33,68 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["segment_starts", "segmented_cumsum_pallas", "sorted_segment_sum"]
+__all__ = ["segment_bounds", "segment_starts", "segmented_cumsum_pallas",
+           "sorted_segment_sum"]
 
 _TILE = 128
 _BLOCK = 2048
 
 
-def sorted_segment_sum(x: jax.Array, ids: jax.Array,
-                       num_segments: int) -> jax.Array:
-    """Sum the rows x [N, R], sorted by ``ids`` [N], into [num_segments, R]
-    (see the module doc): the Pallas kernel on a TPU, ``segment_sum``
+def sorted_segment_sum(x: jax.Array, starts: jax.Array,
+                       ends: jax.Array) -> jax.Array:
+    """Sum the rows x [S * N, R] into [J, R] (see the module doc).
+
+    ``x`` holds S runs of N rows, each run sorted by segment; ``starts``
+    bool [S * N] is set on each segment's first row (a run's row 0
+    included) and ``ends`` int [S, J] holds one past each segment's last
+    row within its run (:func:`segment_bounds` builds both). Rows of a run
+    past its ``ends[s, -1]`` belong to no segment and are dropped. The sums
+    of the S runs are added. The Pallas kernel on a TPU, ``segment_sum``
     elsewhere."""
+    S, J = ends.shape
+    N = x.shape[0] // S
     if not _on_tpu():
-        return jax.ops.segment_sum(x, ids, num_segments,
-                                   indices_are_sorted=True)
-    y = segmented_cumsum_pallas(x, segment_starts(ids))
-    ends = jnp.searchsorted(ids, jnp.arange(1, num_segments + 1,
-                                            dtype=ids.dtype))
-    begins = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
-    return jnp.where((ends > begins)[:, None], y[jnp.maximum(ends - 1, 0)], 0)
+        # segment of each row, the runs' segments numbered apart; a run's
+        # rows past its last segment fall in its spare segment J
+        local = jax.vmap(lambda e: jnp.searchsorted(
+            e, jnp.arange(N, dtype=e.dtype), side="right"))(ends)
+        ids = (local + (J + 1) * jnp.arange(S, dtype=ends.dtype)[:, None])
+        out = jax.ops.segment_sum(x, ids.reshape(-1), S * (J + 1),
+                                  indices_are_sorted=True)
+        return out.reshape(S, J + 1, -1)[:, :J].sum(0)
+    y = segmented_cumsum_pallas(x, starts).reshape(S, N, -1)
+    begins = jnp.concatenate([jnp.zeros((S, 1), ends.dtype), ends[:, :-1]], 1)
+    last = jnp.take_along_axis(y, jnp.maximum(ends - 1, 0)[..., None], axis=1)
+    return jnp.where((ends > begins)[..., None], last, 0).sum(0)
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def segment_starts(ids: jax.Array) -> jax.Array:
-    """bool [N]: set on row 0 and wherever sorted ``ids`` change."""
-    return jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+def segment_starts(ids: np.ndarray) -> np.ndarray:
+    """bool [..., N]: set on row 0 and wherever sorted ``ids`` change along
+    the last axis. Host-side (NumPy), like :func:`segment_bounds`."""
+    ids = np.asarray(ids)
+    starts = np.ones(ids.shape, bool)
+    starts[..., 1:] = ids[..., 1:] != ids[..., :-1]
+    return starts
+
+
+def segment_bounds(ids: np.ndarray, num_segments: int):
+    """Host-side boundaries of S runs of sorted segment ids [S, N] for
+    :func:`sorted_segment_sum`: ``(starts`` bool [S * N], ``ends`` int32
+    [S, num_segments])``. Ids of ``num_segments`` or more mark rows that
+    belong to no segment; they must sort after every real id."""
+    ids = np.asarray(ids)
+    stops = np.arange(1, num_segments + 1)
+    ends = np.stack([np.searchsorted(run, stops) for run in ids])
+    return segment_starts(ids).reshape(-1), ends.astype(np.int32)
 
 
 def _segscan_kernel(flags_ref, x_ref, out_ref, carry_ref, *, tile: int,

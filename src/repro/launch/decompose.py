@@ -137,6 +137,10 @@ def build_buckets(data, *, format: str, engine: str, max_buckets: int):
         rec["device_bytes"] = int(sum(
             leaf.size * leaf.dtype.itemsize
             for leaf in jax.tree_util.tree_leaves(b)))
+        # the mode-2 scatter gathers the real compact rows of its index,
+        # out of the bucket's Kb * C_pad compact slots
+        rec["scatter_rows"] = int(np.asarray(b.scatter.ends)[:, -1].sum())
+        rec["scatter_slots"] = b.kb * b.c_pad
     return bt, bucket_stats, shard_balance
 
 

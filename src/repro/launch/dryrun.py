@@ -18,7 +18,8 @@ from repro.analysis.roofline import (
     TPU_V5E, calibrate_flops_convention, model_flops, roofline_terms)
 from repro.configs import SHAPES, applicable_shapes, get_config, list_archs
 from repro.core import (
-    Bucket, Bucketed, Parafac2Options, Parafac2State, SparseBucket, als_step)
+    Bucket, Bucketed, Parafac2Options, Parafac2State, ScatterIndex,
+    SparseBucket, als_step)
 from repro.dist.sharding import LM_RULES, SP_RULES, axis_rules, param_shardings
 from repro.launch.mesh import make_production_mesh
 from repro.models import build
@@ -272,12 +273,18 @@ def parafac2_specs(K: int, J: int, R: int, geometry, dp: int,
     buckets = []
     for kb, ip, cp, npad in geometry:
         kb = ((kb + dp - 1) // dp) * dp
+        # the scatter index per DP shard: only shapes are known here, so
+        # every compact slot of the shard counts as a real row
+        n = kb // dp * cp
         common = dict(
             cols=sds((kb, cp), i32),
             col_mask=sds((kb, cp), f32),
             subject_ids=sds((kb,), i32),
             subject_mask=sds((kb,), f32),
             row_counts=sds((kb,), i32),
+            scatter=ScatterIndex(perm=sds((dp, n), i32),
+                                 starts=sds((dp * n,), jnp.bool_),
+                                 ends=sds((dp, J), i32)),
         )
         if format == "scoo":
             buckets.append(SparseBucket(

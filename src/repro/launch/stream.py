@@ -469,8 +469,11 @@ class StreamService:
             IrregularCOO(subjects=merged, n_cols=self.n_cols), plan=plan,
             formats=[fmt], subject_align=self.batch_slots,
             dtype=self.opts.dtype)
-        # pin the Bucketed aux metadata so every flush shares one jit entry
-        batch = Bucketed(buckets=batch.buckets, n_subjects=self.batch_slots,
+        # pin the Bucketed aux metadata, and the scatter index's length at
+        # every compact slot, so every flush shares one jit entry
+        buckets = [dataclasses.replace(b, scatter=b.scatter.padded(
+            b.kb // self.batch_slots * b.c_pad)) for b in batch.buckets]
+        batch = Bucketed(buckets=buckets, n_subjects=self.batch_slots,
                          n_cols=self.n_cols, norm_sq=0.0)
 
         np_dt = np.dtype(jnp.dtype(self.opts.dtype).name)

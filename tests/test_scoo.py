@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from repro.core import (
     Parafac2Options, SparseBucket, Bucket, bucket_format, bucketize, fit)
 from repro.core import spartan
+from repro.core.backend import SparseBackend
 from repro.kernels import scoo as kscoo
 from repro.data import choa_like
 from repro.sparse import (
@@ -98,6 +99,7 @@ def test_stage_parity(name):
         assert len(sc.buckets) == 1 and bs0.kb == 1
     V = jnp.asarray(rng.standard_normal((data.n_cols, R)))
     H = jnp.asarray(rng.standard_normal((R, R)))
+    sparse_be = SparseBackend()
     for bc, bs in zip(cc.buckets, sc.buckets):
         assert isinstance(bs, SparseBucket) and isinstance(bc, Bucket)
         assert (bs.kb, bs.i_pad, bs.c_pad) == (bc.kb, bc.i_pad, bc.c_pad)
@@ -112,15 +114,15 @@ def test_stage_parity(name):
         Yc_cc = bc.project(Q)
         np.testing.assert_allclose(np.asarray(bs.project(Q)),
                                    np.asarray(Yc_cc), **TOL)
-        # native (Yc-free) MTTKRP stages vs the spartan reference on CC's Yc
+        # native (Yc-free) MTTKRP stages, as the SCOO route runs them, vs
+        # the spartan reference on CC's Yc
         Vg = bc.gather_v(V)
         ykv_ref = jnp.einsum("krc,kcl->krl", Yc_cc, Vg)
         np.testing.assert_allclose(
-            np.asarray(kscoo.ykv_scoo(bs.vals, bs.rows, bs.lcols, Q, Vg)),
+            np.asarray(sparse_be.ykv_bucket(bs, Q, V)),
             np.asarray(ykv_ref), **TOL)
         np.testing.assert_allclose(
-            np.asarray(kscoo.mode1_scoo(bs.vals, bs.rows, bs.lcols, Q, Vg,
-                                        Wb, bs.subject_mask)),
+            np.asarray(sparse_be.mode1_bucket(bs, Q, Wb, V)),
             np.asarray(spartan.mode1_bucket(Yc_cc, Vg, Wb, bc.subject_mask)),
             **TOL)
         np.testing.assert_allclose(
@@ -130,8 +132,7 @@ def test_stage_parity(name):
             np.asarray(spartan.mode2_bucket_compact(
                 Yc_cc, H, Wb, bc.col_mask, bc.subject_mask)), **TOL)
         np.testing.assert_allclose(
-            np.asarray(kscoo.mode3_scoo(bs.vals, bs.rows, bs.lcols, Q, Vg, H,
-                                        bs.subject_mask)),
+            np.asarray(sparse_be.mode3_bucket(bs, Q, H, V)),
             np.asarray(spartan.mode3_bucket(Yc_cc, Vg, H, bc.subject_mask)),
             **TOL)
 

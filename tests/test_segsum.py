@@ -3,9 +3,11 @@
 Each sum must be accumulated from its own segment's terms only."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import spartan
+from repro.core.irregular import scatter_index
 from repro.kernels import segsum
 
 F32_EPS = float(np.finfo(np.float32).eps)
@@ -50,8 +52,9 @@ def _draw(case, dtype, n=1000, r=6):
 def test_segment_sums_match_f64_oracle(monkeypatch, path, dtype, case):
     _route(monkeypatch, path)
     x, ids, J = _draw(case, dtype)
+    starts, ends = segsum.segment_bounds(ids[None], J)
     got = np.asarray(segsum.sorted_segment_sum(
-        jnp.asarray(x), jnp.asarray(ids, jnp.int32), J))
+        jnp.asarray(x), jnp.asarray(starts), jnp.asarray(ends)))
     assert got.dtype == dtype and got.shape == (J, x.shape[1])
     want = np.zeros((J, x.shape[1]))
     mass = np.zeros((J, x.shape[1]))
@@ -89,6 +92,11 @@ def test_pallas_segmented_prefix_matches_f64_oracle(case):
         assert np.all(y[ids == 0] == 0.0)
 
 
+def _index(cols, mask, J):
+    """The layout's scatter index of compact columns ``cols`` [Kb, C]."""
+    return jax.tree_util.tree_map(jnp.asarray, scatter_index(cols, mask, J))
+
+
 def _cumsum_and_difference_f32(x, ids, J):
     """What mode2_scatter computed before: one running f32 total over all
     sorted rows, differenced at the segment ends."""
@@ -114,7 +122,7 @@ def test_a_tiny_column_after_a_heavy_one_keeps_its_digits(path):
     old = _cumsum_and_difference_f32(x, ids, 2)
     assert np.max(np.abs(old[1] - want[1]) / np.abs(want[1])) > 1e-3
     got = np.asarray(spartan.mode2_scatter(
-        jnp.asarray(x)[None], jnp.asarray(ids, jnp.int32)[None], 2))
+        jnp.asarray(x)[None], _index(ids[None], np.ones((1, ids.size)), 2)))
     assert got.dtype == np.float32
     assert np.all(np.abs(got - want) / np.abs(want) <= 1e-6)
 
@@ -136,8 +144,8 @@ def test_mode2_scatter_matches_f64_oracle_on_zipf_columns(path):
     A = (rng.standard_normal((kb, c, r)) * mask[..., None]).astype(np.float32)
     want = np.zeros((J, r))
     np.add.at(want, cols.reshape(-1), A.reshape(-1, r).astype(np.float64))
-    got = np.asarray(spartan.mode2_scatter(jnp.asarray(A), jnp.asarray(cols),
-                                           J))
+    got = np.asarray(spartan.mode2_scatter(jnp.asarray(A),
+                                           _index(cols, mask, J)))
     assert got.dtype == np.float32 and got.shape == (J, r)
     mass = np.zeros((J, r))
     np.add.at(mass, cols.reshape(-1), np.abs(A.reshape(-1, r)))

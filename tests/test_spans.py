@@ -69,7 +69,7 @@ def test_every_stage_scope_reaches_the_compiled_chunk(fmt, backend, stages):
     found = {m.group(1) for _, n in ops for m in [_STAGE.search(n)] if m}
     assert found == set(stages)
     work = [(op, n) for op, n in ops if op in _WORK]
-    assert {op for op, _ in work} >= {"dot", "gather", "sort"}
+    assert {op for op, _ in work} >= {"dot", "gather"}
     # The CPU compiler rewrites a cumsum into reduce-windows that carry no
     # metadata, nor does their fusion: those are the only ops without a
     # name, and every op with a name lies in a stage.
@@ -109,16 +109,18 @@ def test_scatter_segsum_ticks_once_per_bucket(fmt, backend):
 
 
 def test_scatter_runs_its_sort_gather_and_segsum_in_their_scopes():
-    """The argsort, the permutation gather and the segment sum each lie in
-    their own scope under ``als/scatter``, which ``scatter_ms`` sums."""
+    """The gather of the real compact rows and the segment sum each lie in
+    their own scope under ``als/scatter``, which ``scatter_ms`` sums; the
+    sort ran once, in the layout, so no sort op is left there."""
     ops = _hlo_ops(_chunk("scoo", "auto").compile().as_text())
     subs = {}
     for op, n in ops:
         m = re.search(r"als/scatter/(\w+)/", n)
         if m:
             subs.setdefault(m.group(1), set()).add(op)
-    assert {"sort", "gather", "segsum"} <= set(subs)
-    assert "sort" in subs["sort"] and "gather" in subs["gather"]
+    assert set(subs) == {"gather", "segsum"}
+    assert "gather" in subs["gather"]
+    assert not any(op == "sort" and "als/scatter/" in n for op, n in ops)
 
 
 def test_span_totals_count_seconds_and_self_time():

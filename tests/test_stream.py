@@ -314,6 +314,24 @@ def test_service_rejects_bad_config():
                       format="csr")
 
 
+def test_flushes_of_one_geometry_share_one_compiled_dispatch():
+    """Sparse appends whose subjects keep different numbers of columns: the
+    batch's scatter index is pinned to the geometry's slot count, so every
+    flush of that geometry hits the one compiled update."""
+    data = _data(seed=9, n_subjects=16, max_rows=16, density=0.08)
+    assert len({s.nonzero_cols().size for s in data.subjects}) > 1
+    warm, payloads = synthetic_stream(data, warm_frac=0.5, touch_frac=0.0,
+                                      seed=9)
+    svc, _ = StreamService.warm_start(warm, _opts(), iters=3, seed=0,
+                                      batch_slots=2, drift_threshold=np.inf,
+                                      format="cc", row_align=32, col_align=64)
+    for p in payloads:
+        svc.submit(p)
+    svc.flush()
+    assert svc.stats()["batches"] > 1
+    assert svc._update._cache_size() == 1
+
+
 def test_padded_dispatch_reuses_one_geometry():
     """Appends with similar shapes share one compiled (geometry, format)
     entry — the jit-cache-stability property the service is built around."""

@@ -27,7 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import Parafac2Options, als_step, bucketize, init_state
 from repro.core import backend
-from repro.core.irregular import Bucket, Bucketed, SparseBucket
+from repro.core.irregular import Bucket, Bucketed, ScatterIndex, SparseBucket
 from repro.kernels import fused, segsum
 from repro.sparse import random_irregular
 
@@ -132,20 +132,48 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in _compile_for_chip(fn, shapes, one_chip)
 
 
+def _segsum_on_chip(monkeypatch):
+    """Route the mode-2 segment sum as on a TPU, to the compiled kernel."""
+    monkeypatch.setattr(segsum, "_on_tpu", lambda: True)
+    monkeypatch.setattr(segsum, "segmented_cumsum_pallas", functools.partial(
+        segsum.segmented_cumsum_pallas, interpret=False))
+
+
+def _index_spec(Kb, C, J, sharding):
+    """Shape specs of a bucket's scatter index, every compact slot real."""
+    n = Kb * C
+    return ScatterIndex(
+        perm=jax.ShapeDtypeStruct((1, n), i32, sharding=sharding),
+        starts=jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=sharding),
+        ends=jax.ShapeDtypeStruct((1, J), i32, sharding=sharding))
+
+
 def test_mode2_scatter_kernel_compiles_under_f32_matmuls(one_chip,
                                                          monkeypatch):
     """``als_step`` traces under f32 matmul precision; the segment-sum
     kernel's bf16 matmuls must still compile for the chip there."""
     from repro.core import spartan
 
-    monkeypatch.setattr(segsum, "_on_tpu", lambda: True)
-    monkeypatch.setattr(segsum, "segmented_cumsum_pallas", functools.partial(
-        segsum.segmented_cumsum_pallas, interpret=False))
+    _segsum_on_chip(monkeypatch)
+    n = K * 128
     with jax.default_matmul_precision("float32"):
         text = _compile_for_chip(
-            lambda a, c: spartan.mode2_scatter(a, c, 1_328),
-            [((K, 128, R), f32), ((K, 128), i32)], one_chip)
+            lambda a, p, st, e: spartan.mode2_scatter(
+                a, ScatterIndex(perm=p, starts=st, ends=e)),
+            [((K, 128, R), f32), ((1, n), i32), ((n,), jnp.bool_),
+             ((1, 1_328), i32)], one_chip)
     assert "tpu_custom_call" in text
+
+
+def _cc_bucket(Kb, I, C, J, sharding):
+    """A CC Bucket of shape specs [Kb, I_pad, C_pad] over J columns."""
+    def spec(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    return Bucket(vals=spec((Kb, I, C)), cols=spec((Kb, C), i32),
+                  col_mask=spec((Kb, C)), subject_ids=spec((Kb,), i32),
+                  subject_mask=spec((Kb,)), row_counts=spec((Kb,), i32),
+                  scatter=_index_spec(Kb, C, J, sharding))
 
 
 def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
@@ -158,10 +186,7 @@ def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     data = Bucketed(
-        buckets=[Bucket(vals=spec((K, I, C)), cols=spec((K, C), i32),
-                        col_mask=spec((K, C)), subject_ids=spec((K,), i32),
-                        subject_mask=spec((K,)), row_counts=spec((K,), i32))
-                 for I, C in GEOMS],
+        buckets=[_cc_bucket(K, I, C, 1_328, one_chip) for I, C in GEOMS],
         n_subjects=K * len(GEOMS), n_cols=1_328, norm_sq=1.0)
     opts = Parafac2Options(rank=R, constraints={"v": "nonneg", "w": "nonneg"},
                            backend="auto")
@@ -175,19 +200,40 @@ def test_fused_als_step_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count('custom_call_target="tpu_custom_call"') >= 4 * len(GEOMS)
 
 
-def _scoo_bucket(template, I, C, N, sharding):
-    """A SparseBucket of shape specs [Kb = SCOO_KB] at (I_pad, C_pad, N_pad),
-    each leaf with the dtype ``bucketize`` gives it."""
+def _scoo_bucket(template, I, C, N, J, sharding):
+    """A SparseBucket of shape specs [Kb = SCOO_KB] at (I_pad, C_pad, N_pad)
+    over J columns, each leaf with the dtype ``bucketize`` gives it."""
     dims = {"vals": (SCOO_KB, N), "rows": (SCOO_KB, N),
             "lcols": (SCOO_KB, N), "cperm": (SCOO_KB, N),
             "row_ends": (SCOO_KB, I), "col_ends": (SCOO_KB, C),
             "cols": (SCOO_KB, C), "col_mask": (SCOO_KB, C)}
     leaves = {f.name: getattr(template, f.name)
-              for f in dataclasses.fields(template) if f.name != "n_rows_pad"}
-    return SparseBucket(n_rows_pad=I, **{
-        name: jax.ShapeDtypeStruct(dims.get(name, (SCOO_KB,)), leaf.dtype,
-                                   sharding=sharding)
-        for name, leaf in leaves.items()})
+              for f in dataclasses.fields(template)
+              if f.name not in ("n_rows_pad", "scatter")}
+    return SparseBucket(
+        n_rows_pad=I, scatter=_index_spec(SCOO_KB, C, J, sharding), **{
+            name: jax.ShapeDtypeStruct(dims.get(name, (SCOO_KB,)), leaf.dtype,
+                                       sharding=sharding)
+            for name, leaf in leaves.items()})
+
+
+def _scoo_template():
+    return bucketize(random_irregular(n_subjects=4, n_cols=30, max_rows=5,
+                                      avg_nnz_per_subject=10, seed=0),
+                     max_buckets=1, format="scoo").buckets[0]
+
+
+def _compile_step_for_chip(data, sharding) -> str:
+    """One routed ALS step over ``data`` (shape specs), compiled for the
+    described chip."""
+    opts = Parafac2Options(rank=R, constraints={"v": "nonneg", "w": "nonneg"},
+                           backend="auto")
+    state = jax.eval_shape(lambda: init_state(data, opts, 0))
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        state)
+    return jax.jit(lambda d, s: als_step(d, s, opts)).lower(
+        data, state).compile().as_text()
 
 
 _SCOO_CASES = [(f"{cfg}-I{I}-C{C}-N{N}", J, (I, C, N))
@@ -203,19 +249,37 @@ def test_scoo_als_step_compiles_for_v5e(name, one_chip, monkeypatch):
     (no Mosaic kernel under ``als/mode1``), as its mode-3 stage is."""
     _, J, (I, C, N) = next(c for c in _SCOO_CASES if c[0] == name)
     monkeypatch.setattr(backend, "_on_tpu", lambda: True)
-    template = bucketize(random_irregular(n_subjects=4, n_cols=30, max_rows=5,
-                                          avg_nnz_per_subject=10, seed=0),
-                         max_buckets=1, format="scoo").buckets[0]
-    b = _scoo_bucket(template, I, C, N, one_chip)
+    b = _scoo_bucket(_scoo_template(), I, C, N, J, one_chip)
     data = Bucketed(buckets=[b], n_subjects=SCOO_KB, n_cols=J, norm_sq=1.0)
-    opts = Parafac2Options(rank=R, constraints={"v": "nonneg", "w": "nonneg"},
-                           backend="auto")
     assert backend.get_backend("auto").route(b, R) == "scoo"
-    state = jax.eval_shape(lambda: init_state(data, opts, 0))
-    state = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        state)
-    text = jax.jit(lambda d, s: als_step(d, s, opts)).lower(
-        data, state).compile().as_text()
+    text = _compile_step_for_chip(data, one_chip)
     assert "als/mode1/" in text
     assert not _CUSTOM_CALL.search(text)
+
+
+_SCATTER_SORT = re.compile(r' sort\(.*op_name="[^"]*als/scatter/')
+
+
+@pytest.mark.parametrize("cell", ["choa-r40.auto", "choa-r40.cc"])
+def test_routed_als_step_sorts_nothing_in_the_scatter(cell, one_chip,
+                                                      monkeypatch):
+    """The routed ALS step over every bucket shape of the cell's plan,
+    compiled for the chip with the segment sum on its kernel: the mode-2
+    scatter gathers and sums in the order the layout fixed, so no sort op
+    sits under ``als/scatter`` (``eigh`` sorts its eigenvalues elsewhere)."""
+    monkeypatch.setattr(backend, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused, "_interpret", lambda: False)
+    _segsum_on_chip(monkeypatch)
+    J = 1_328
+    if cell == "choa-r40.cc":
+        buckets = [_cc_bucket(Kb, I, C, J, one_chip) for Kb, I, C in CC_PLAN]
+    else:
+        template = _scoo_template()
+        buckets = [_scoo_bucket(template, I, C, N, J, one_chip)
+                   for cfg, _, shapes in SCOO_PLANS if cfg == "choa"
+                   for I, C, N in shapes]
+    data = Bucketed(buckets=buckets, n_subjects=sum(b.kb for b in buckets),
+                    n_cols=J, norm_sq=1.0)
+    text = _compile_step_for_chip(data, one_chip)
+    assert "als/scatter/gather" in text and "tpu_custom_call" in text
+    assert not _SCATTER_SORT.search(text)
